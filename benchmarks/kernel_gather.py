@@ -48,6 +48,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.kernels import (
     chunk_gather_matmul_dma,
@@ -149,7 +150,6 @@ def run(rows: Rows, smoke: bool = False) -> None:
     for depth in depths:
         t0 = time.perf_counter()
         y = sparse_mlp_fused(wg, wu, wd, x, lanes_s, lanes_z,
-                             max_chunk_rows=KERNEL_MAX_CHUNK_ROWS,
                              prefetch_depth=depth)
         y.block_until_ready()
         wall = time.perf_counter() - t0
@@ -162,8 +162,7 @@ def run(rows: Rows, smoke: bool = False) -> None:
     # same oracle (the two paths must agree bit-for-policy)
     h = sparse_swiglu(wg, wu, x, lanes_s[0], lanes_z[0],
                       max_chunk_rows=KERNEL_MAX_CHUNK_ROWS)
-    y_ps = sparse_matmul_dma(wd, h, lanes_s[1], lanes_z[1],
-                             max_chunk_rows=KERNEL_MAX_CHUNK_ROWS)
+    y_ps = sparse_matmul_dma(wd, h, lanes_s[1], lanes_z[1])
     err_ps = float(jnp.max(jnp.abs(y_ps - yref))) / scale
     assert err_ps < 1e-5, f"per-site path diverged from oracle: {err_ps}"
     rows.add("kernel/per_site_mlp_parity", 0.0, f"rel_err={err_ps:.2e}")
@@ -177,7 +176,6 @@ def run(rows: Rows, smoke: bool = False) -> None:
         y0 = chunk_gather_matmul_ref(w_o, x_o, kstarts[io_], ksizes[io_])
         for depth in (0, 1, 2):
             y = sparse_matmul_dma(w_o, x_o, kstarts[io_], ksizes[io_],
-                                  max_chunk_rows=KERNEL_MAX_CHUNK_ROWS,
                                   prefetch_depth=depth)
             err = float(jnp.max(jnp.abs(y - y0))) / (float(jnp.max(jnp.abs(y0))) + 1.0)
             assert err < 1e-5
@@ -213,7 +211,7 @@ def bench_tile_sweep(rows: Rows, sparse, kstarts, ksizes, rng, batch: int,
     reps = 1 if smoke else 5
     for tile in tiles:
         y = sparse_matmul_dma(w_o, x_o, kstarts[io_], ksizes[io_],
-                              tile_d=tile, max_chunk_rows=KERNEL_MAX_CHUNK_ROWS)
+                              tile_d=tile)
         y.block_until_ready()  # compile + warm
         err = float(jnp.max(jnp.abs(y - y0))) / scale
         assert err < 1e-5, f"tile_d={tile} diverged from oracle: {err}"
@@ -222,7 +220,6 @@ def bench_tile_sweep(rows: Rows, sparse, kstarts, ksizes, rng, batch: int,
             t0 = time.perf_counter()
             sparse_matmul_dma(w_o, x_o, kstarts[io_], ksizes[io_],
                               tile_d=tile,
-                              max_chunk_rows=KERNEL_MAX_CHUNK_ROWS,
                               ).block_until_ready()
             walls.append(time.perf_counter() - t0)
         rows.add(f"kernel/tile_d{tile}", float(np.median(walls)) * 1e6,
@@ -261,7 +258,6 @@ def bench_quantized_gather(rows: Rows, sparse, kstarts, ksizes, rng,
         t0 = time.perf_counter()
         y = chunk_gather_matmul_dma(q_o, x_o, kstarts[io_], ksizes[io_], s_o,
                                     block_rows=KERNEL_BLOCK_ROWS,
-                                    max_chunk_rows=KERNEL_MAX_CHUNK_ROWS,
                                     prefetch_depth=depth, interpret=True)
         y.block_until_ready()
         wall = time.perf_counter() - t0
@@ -297,7 +293,6 @@ def bench_quantized_gather(rows: Rows, sparse, kstarts, ksizes, rng,
         y = chunk_gather_mlp_dma(qg, qu, qd, x, lanes_s, lanes_z,
                                  scales=(sg, su, sd),
                                  block_rows=KERNEL_BLOCK_ROWS,
-                                 max_chunk_rows=KERNEL_MAX_CHUNK_ROWS,
                                  prefetch_depth=depth, interpret=True)
         y.block_until_ready()
         wall = time.perf_counter() - t0
@@ -388,6 +383,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     args = build_parser().parse_args()
     rows = Rows()
     print("name,us_per_call,derived")

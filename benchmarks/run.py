@@ -13,10 +13,13 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import Rows
 
 
 def main() -> None:
+    enable_compile_cache()
     from . import (
         appg_reorder,
         appk_token_density,

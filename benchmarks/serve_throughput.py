@@ -93,6 +93,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.models import build_model
@@ -991,6 +992,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     args = build_parser().parse_args()
     rows = Rows()
     print("name,us_per_call,derived")

@@ -23,8 +23,8 @@ sparse projections through one of two implementations selected by
     for gate/up/down, SwiGLU intermediate resident in VMEM) and
     ``chunk_gather_matmul_dma`` serves the single-site projections (q/k/v
     off the ``hidden_attn`` site, attn_out's ``wo``, both matrices of the
-    non-gated gelu MLP — the full decode hot path). Interpret mode in
-    CI / on CPU, compiled on real TPU (``interpret=None`` auto).
+    non-gated gelu MLP — the full decode hot path). Compiled on TPU,
+    interpret mode on the CPU test platform (``resolve_interpret``).
 
 Both implementations compute the SAME masked-matmul semantics of paper
 App. B.2 — the backend only changes how the arithmetic is realized, never
@@ -34,6 +34,7 @@ switch with byte-identity as its acceptance gate.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -91,38 +92,58 @@ def blocked_masked_matmul(
     pinned by tests/test_backend.py).
 
     With ``scales`` (the quantized chunk format): ``w`` is the int8 payload
-    and each block is dequantized ``q.astype(f32) * scale`` before the
-    identical contraction — elementwise the same multiply the kernel's
-    in-VMEM dequant performs, keeping the twins bitwise equal at 8 bits."""
+    and each block's f32 partial product ``xm_blk @ q_blk.astype(f32)`` is
+    multiplied by the block's scale inside the accumulation step,
+    ``acc + part * scale`` — the kernel's exact expression, so whatever the
+    compiler makes of it (XLA:CPU fuses it into one FMA) it makes of both,
+    keeping the twins bitwise equal at 8 bits.
+
+    The contraction runs at ``Precision.HIGHEST``, as the kernel's does: on
+    TPU the default f32 matmul precision is a single bf16 pass."""
     b, n = xm.shape
     if n % block_rows:
         raise ValueError(f"N={n} must be a multiple of block_rows={block_rows}")
     nb = n // block_rows
     xb = xm.astype(jnp.float32).reshape(b, nb, block_rows)
     wb = w.astype(jnp.float32).reshape(nb, block_rows, w.shape[1])
-    if scales is not None:
-        wb = wb * scales.astype(jnp.float32)[:, None, None]
     parts = jnp.einsum("bkr,krd->kbd", xb, wb,
-                       preferred_element_type=jnp.float32)
+                       preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)
+    sc = None if scales is None else scales.astype(jnp.float32)
 
     def body(k, acc):
-        return acc + parts[k]
+        return acc + (parts[k] if sc is None else parts[k] * sc[k])
 
     return jax.lax.fori_loop(
         0, nb, body, jnp.zeros((b, w.shape[1]), jnp.float32)
     )
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Pallas mode for the gather kernels: an explicit bool is kept; None
+    compiles them on TPU and interprets them on the CPU test platform. Any
+    other platform raises — the kernels are written for the TPU, and no
+    path quietly swaps the device for the interpreter."""
+    if interpret is not None:
+        return interpret
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas gather kernels compile for TPU and are interpreted only "
+        f"on the CPU test platform; JAX's default backend is {platform!r}"
+    )
 
 
 @dataclasses.dataclass(frozen=True)
 class ExecutionBackend:
     """Dispatch object carried by ``SparseExecution`` into the model blocks.
 
-    ``interpret``: None = auto (interpret off-TPU, compiled on TPU) —
-    resolved at construction so the jit caches stay stable.
+    ``interpret``: None = compiled on TPU, interpreted on the CPU test
+    platform, an error elsewhere (``resolve_interpret``) — resolved at
+    construction so the jit caches stay stable.
     ``prefetch_depth``: the DMA kernels' VMEM slot count − 1; numerics are
     depth-invariant (the schedule only re-times the same fetches), so
     tokens stay byte-identical at every depth.
@@ -140,13 +161,23 @@ class ExecutionBackend:
     sharded rows and reassociate the partial sums — measurably not
     bitwise-stable. Also pins the kernel path's operand layout (pallas
     calls need replicated operands on host meshes anyway).
+
+    Both paths pass their activations and results through
+    ``jax.lax.optimization_barrier``, so the call is a fusion boundary on
+    either backend. A Pallas call always is one; the twin's jnp ops are
+    not, and XLA keeps bf16 intermediates in f32 wherever a fusion lets it
+    (``xla_allow_excess_precision``, on by default). Without the fence the
+    code AROUND the backend (norms, residual adds, the q/k/v casts) was
+    fused, and so rounded, differently in the two decode programs: on a
+    v5e the first decode step's logits differed by up to 7e-3 relative and
+    the greedy tokens parted after two steps, though every backend op
+    agreed bitwise on its own.
     """
 
     name: str = "reference"
     prefetch_depth: int = 1
     interpret: bool = True
     block_rows: int = 8
-    max_chunk_rows: int = 512
     tile_cap: int = 128
     mesh: Optional[Mesh] = None
 
@@ -156,7 +187,6 @@ class ExecutionBackend:
         prefetch_depth: int = 1,
         interpret: Optional[bool] = None,
         block_rows: int = 8,
-        max_chunk_rows: int = 512,
         tile_cap: int = 128,
         mesh: Optional[Mesh] = None,
     ) -> "ExecutionBackend":
@@ -166,9 +196,8 @@ class ExecutionBackend:
         return ExecutionBackend(
             name=name,
             prefetch_depth=prefetch_depth,
-            interpret=not _on_tpu() if interpret is None else interpret,
+            interpret=resolve_interpret(interpret),
             block_rows=block_rows,
-            max_chunk_rows=max_chunk_rows,
             tile_cap=tile_cap,
             mesh=mesh,
         )
@@ -187,6 +216,18 @@ class ExecutionBackend:
             w, NamedSharding(self.mesh, PartitionSpec())
         )
 
+    def _kernel_call(self, kernel, *operands):
+        """Dispatch a Pallas kernel. Under a serve mesh it runs as one
+        whole-operand program per device (``shard_map`` with every operand
+        and output replicated): Mosaic kernels are not partitioned
+        automatically, and the replicated fold is the gather-then-ordered-
+        sum the class docstring describes."""
+        if self.mesh is None:
+            return kernel(*operands)
+        return jax.shard_map(kernel, mesh=self.mesh, in_specs=PartitionSpec(),
+                             out_specs=PartitionSpec(),
+                             check_vma=False)(*operands)
+
     # -- single-site projection (attn_out wo; gelu MLP fc/proj) -------------
     def project(
         self,
@@ -202,24 +243,28 @@ class ExecutionBackend:
         EXACT mask for both backends, so the kernel's outward block rounding
         gathers only zeroed extra rows — masked-matmul semantics hold and
         the two implementations agree bitwise. With ``scales`` (8-bit chunk
-        storage) both backends dequantize per block before the identical
-        f32 contraction, preserving the bitwise twin property. With
-        ``checksums`` the kernel path fetches each block's integrity word
-        through a third DMA lane (verification happens at the selection
+        storage) both backends scale each block's f32 partial product as
+        it accumulates, preserving the bitwise twin property. With
+        ``checksums`` the kernel path carries each block's integrity word
+        into SMEM with the call (verification happens at the selection
         boundary); the reference path — whose operands never leave device
         memory — ignores it. Output is bit-identical either way."""
+        x, mask = jax.lax.optimization_barrier((x, mask))
         xm = (x * mask.astype(x.dtype)).astype(jnp.float32)
         w, scales = self._gather(w), self._gather(scales)
         if self.is_kernel:
-            return chunk_gather_matmul_dma(
-                w, xm, starts, sizes, scales, self._gather(checksums),
+            kernel = functools.partial(
+                chunk_gather_matmul_dma,
                 block_rows=self.block_rows,
                 tile_d=pick_tile(w.shape[1], self.tile_cap),
-                max_chunk_rows=self.max_chunk_rows,
                 prefetch_depth=self.prefetch_depth,
                 interpret=self.interpret,
             )
-        return blocked_masked_matmul(xm, w, self.block_rows, scales)
+            y = self._kernel_call(kernel, w, xm, starts, sizes, scales,
+                                  self._gather(checksums))
+        else:
+            y = blocked_masked_matmul(xm, w, self.block_rows, scales)
+        return jax.lax.optimization_barrier(y)
 
     # -- fused multi-site SwiGLU MLP -----------------------------------------
     def swiglu_mlp(
@@ -241,8 +286,11 @@ class ExecutionBackend:
         must be the pre-mask value on both backends. ``scales`` switches
         all three weights to the quantized chunk format (int8 payloads +
         per-block scale lanes), dequantized identically on both backends.
-        ``checksums`` adds the kernel path's per-block integrity-word DMA
-        lanes (fetch-only; see ``project``) — bit-identical either way."""
+        ``checksums`` adds the kernel path's per-block integrity-word lanes
+        (carried, not verified; see ``project``) — bit-identical either
+        way."""
+        x, hidden_mask, ffn_mask = jax.lax.optimization_barrier(
+            (x, hidden_mask, ffn_mask))
         xm = (x * hidden_mask.astype(x.dtype)).astype(jnp.float32)
         fm = ffn_mask.astype(jnp.float32)
         w_gate, w_up, w_down = (
@@ -253,16 +301,18 @@ class ExecutionBackend:
         if checksums is not None:
             checksums = tuple(self._gather(c) for c in checksums)
         if self.is_kernel:
-            return chunk_gather_mlp_dma(
-                w_gate, w_up, w_down, xm, starts, sizes, fm, scales, checksums,
+            kernel = functools.partial(
+                chunk_gather_mlp_dma,
                 block_rows=self.block_rows,
                 tile_f=pick_tile(w_gate.shape[1], self.tile_cap),
                 tile_d=pick_tile(w_down.shape[1], self.tile_cap),
-                max_chunk_rows=self.max_chunk_rows,
                 prefetch_depth=self.prefetch_depth,
                 interpret=self.interpret,
                 return_h=True,
             )
+            return jax.lax.optimization_barrier(self._kernel_call(
+                kernel, w_gate, w_up, w_down, xm, starts, sizes, fm, scales,
+                checksums))
         sg, su, sd = scales if scales is not None else (None, None, None)
         g = blocked_masked_matmul(xm, w_gate, self.block_rows, sg)
         u = blocked_masked_matmul(xm, w_up, self.block_rows, su)
@@ -270,4 +320,4 @@ class ExecutionBackend:
         # a different, numerically-stable formulation — bitwise matters here)
         h = g * (1.0 / (1.0 + jnp.exp(-g))) * u
         y = blocked_masked_matmul(h * fm[None, :], w_down, self.block_rows, sd)
-        return y, h
+        return jax.lax.optimization_barrier((y, h))

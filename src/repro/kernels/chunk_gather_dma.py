@@ -12,7 +12,9 @@ classic in-kernel pipeline —
 
 so chunk-block k+1's HBM→VMEM transfer is in flight while the MXU contracts
 block k (depth 1 = double buffering; depth 0 degenerates to fetch-then-compute
-serial, the baseline the overlap is benchmarked against). This is the kernel
+serial, the baseline the overlap is benchmarked against). The steps are the
+blocks the chunk table covers, in ascending row order (``block_list``), so
+padded table entries cost nothing. This is the kernel
 realization of the host-side prefetch timeline in core/pipeline.py: the same
 ``prefetch_depth`` knob, the same hidden-fetch discipline, so the model and
 the kernel agree on what is hidden.
@@ -29,11 +31,23 @@ Two entry points:
     while resident, and the SwiGLU intermediate h stays in VMEM for the
     down-lane gather — no per-site re-dispatch, no h HBM round-trip.
 
-Interpret-mode note: this container is CPU-only; ``interpret=True`` executes
-the same slot rotation (make_async_copy is emulated as a synchronous copy),
-which validates the schedule's *numerics* — padded steps fetch nothing,
+On TPU the kernels compile through Mosaic. Off-TPU (the CPU test platform)
+``interpret=True`` executes the same slot rotation (make_async_copy is
+emulated as a synchronous copy), which validates the schedule's *numerics* —
 rotation never overwrites a live slot — while the overlap itself only exists
-on real TPU hardware.
+on the chip.
+
+Layout rules Mosaic enforces, and how the kernels meet them:
+
+  * a vector load's lane offset must be a multiple of the 128-lane tile, so
+    the ``block_rows``-wide column block of ``x`` / ``h`` / the ffn mask is
+    read through its aligned lane window and rotated into place
+    (``_column_block``);
+  * a DMA's row offset must provably sit on the HBM tiling, so block
+    offsets carry a ``pl.multiple_of(…, block_rows)`` hint;
+  * a one-word DMA is below a 1-D HBM array's tiling, so the per-block
+    scales and checksums lanes are SMEM-resident for the call and read one
+    word per block by scalar index.
 
 ``masks_to_block_tables`` is the jit-safe bridge from the batched selector's
 ``(n_sites, N)`` masks straight to these kernels' padded chunk tables (block
@@ -49,10 +63,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# JAX renamed TPUCompilerParams -> CompilerParams (jax>=0.5); support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-_ANY = pltpu.TPUMemorySpace.ANY
 
 
 # ---------------------------------------------------------------------------
@@ -118,28 +128,71 @@ def masks_to_block_tables(
 # ---------------------------------------------------------------------------
 
 
-def _chunk_step_offset(starts_ref, sizes_ref, step, blocks_per_chunk, block_rows,
-                       lane=None):
-    """Flat (chunk, block) step → (row offset, active). Padded chunks
-    (size 0) and blocks past a chunk's size are inactive: no DMA is issued
-    for them and their slot is simply skipped by the rotation."""
-    ci = step // blocks_per_chunk
-    bk = step - ci * blocks_per_chunk
-    if lane is None:
-        start, size = starts_ref[ci], sizes_ref[ci]
-    else:
-        start, size = starts_ref[lane, ci], sizes_ref[lane, ci]
-    return start + bk * block_rows, bk * block_rows < size
+@functools.partial(jax.jit, static_argnames=("n_rows", "block_rows"))
+def block_list(starts, sizes, n_rows: int, block_rows: int = 8):
+    """Chunk table → (offsets, count): the row offsets of the ``block_rows``
+    blocks the table covers, ascending and each once, padded to
+    ``n_rows // block_rows`` entries, and how many of them are real (a
+    (1,) int32). The kernels' pipelines walk this list, so a padded table
+    entry (size 0) costs no loop step, and blocks accumulate in the
+    ascending order of the reference twin whatever the table's order."""
+    nb = n_rows // block_rows
+    b0 = jnp.clip(starts // block_rows, 0, nb)
+    b1 = jnp.clip((starts + sizes) // block_rows, 0, nb)
+    # coverage by a difference array: +1 where a chunk starts, -1 past its
+    # end (a size-0 entry cancels itself)
+    diff = jnp.zeros((nb + 1,), jnp.int32).at[b0].add(1).at[b1].add(-1)
+    covered = jnp.cumsum(diff[:nb]) > 0
+    rank = jnp.where(covered, jnp.cumsum(covered) - 1, nb)
+    offs = (jnp.zeros((nb + 1,), jnp.int32)
+            .at[rank].set(jnp.arange(nb, dtype=jnp.int32) * block_rows))
+    return offs[:nb], jnp.sum(covered, dtype=jnp.int32)[None]
+
+
+def _lane_window(n: int, block_rows: int, cap: int = 128) -> int:
+    """Width of the aligned lane window a ``block_rows``-wide column block
+    is read through: the largest power of two ≤ ``cap`` dividing ``n``
+    (128, the lane width, at every real model width; smaller only for the
+    reduced CPU configs, which run in interpret mode)."""
+    w = cap
+    while n % w:
+        w //= 2
+    if w < block_rows:
+        raise ValueError(f"dim {n} has no lane window >= block_rows={block_rows}")
+    return w
+
+
+def _column_block(ref, off, block_rows: int, window: int):
+    """``ref[:, off:off + block_rows]`` for an ``off`` that is only
+    ``block_rows``-aligned. A TPU vector load needs a lane offset aligned to
+    the 128-lane tile, so load the aligned window holding the block, rotate
+    the block down to lane 0 and slice it statically — pure data movement,
+    bit-exact."""
+    base = pl.multiple_of((off // window) * window, window)
+    v = ref[:, pl.ds(base, window)]
+    v = pltpu.roll(v, (window - (off - base)) % window, axis=1)
+    return v[:, :block_rows]
+
+
+def _block_dot(xb, wb):
+    """One block's contribution: (B, block_rows) · (block_rows, T) in f32 at
+    full f32 precision — the reference twin's einsum asks for the same (on
+    the v5e both compile to the same bits, checked on the chip)."""
+    return jnp.dot(xb, wb, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def _pipelined_steps(total, n_slots, start_copy, wait_and_compute):
-    """Run the slot-rotation schedule: start copies ``n_slots - 1`` steps
-    ahead (prefetch_depth = n_slots - 1), wait + compute in order. With
-    n_slots == 1 (depth 0) each step starts its own copy then immediately
-    waits on it — the serial baseline schedule."""
+    """Run the slot-rotation schedule over ``total`` (traced) block steps:
+    start copies ``n_slots - 1`` steps ahead (prefetch_depth = n_slots - 1),
+    wait + compute in order. With n_slots == 1 (depth 0) each step starts
+    its own copy then immediately waits on it — the serial baseline
+    schedule."""
     depth = n_slots - 1
     for s in range(depth):  # warm-up (static: depth is a python int)
-        if s < total:
+
+        @pl.when(s < total)
+        def _():
             start_copy(jnp.int32(s), s % n_slots)
 
     def body(step, _):
@@ -161,124 +214,57 @@ def _pipelined_steps(total, n_slots, start_copy, wait_and_compute):
 
 
 def _matmul_dma_kernel(
-    starts_ref,  # scalar prefetch (K,)
-    sizes_ref,  # scalar prefetch (K,)
-    x_ref,  # (B, N) VMEM
+    offs_ref,  # scalar prefetch (N // block_rows,): covered block row offsets
+    count_ref,  # scalar prefetch (1,): how many of them are real
+    x_ref,  # (B, N) f32 VMEM
     w_hbm,  # (N, D) ANY/HBM — fetched by explicit DMA only
-    *rest,  # [s_hbm,] [c_hbm,] out_ref, wslots, [sslots,] [cslots,]
-    #         sems, [sems_s,] [sems_c]
+    *rest,  # [s_smem,] [c_smem,] out_ref, wslots, sems
     block_rows: int,
     tile_d: int,
-    blocks_per_chunk: int,
     n_slots: int,
+    window: int,
     quantized: bool,
     checksummed: bool,
 ):
-    idx = 0
-    s_hbm = c_hbm = sslots = cslots = sems_s = sems_c = None
-    if quantized:
-        s_hbm = rest[idx]
-        idx += 1
-    if checksummed:
-        c_hbm = rest[idx]
-        idx += 1
-    out_ref, wslots = rest[idx], rest[idx + 1]
-    idx += 2
-    if quantized:
-        sslots = rest[idx]
-        idx += 1
-    if checksummed:
-        cslots = rest[idx]
-        idx += 1
-    sems = rest[idx]
-    idx += 1
-    if quantized:
-        sems_s = rest[idx]
-        idx += 1
-    if checksummed:
-        sems_c = rest[idx]
-    dj = pl.program_id(0)
-    k = starts_ref.shape[0]
-    total = k * blocks_per_chunk
+    s_smem = rest[0] if quantized else None
+    out_ref, wslots, sems = rest[quantized + checksummed:]
+    col = pl.multiple_of(pl.program_id(0) * tile_d, tile_d)
 
     def offset(step):
-        return _chunk_step_offset(
-            starts_ref, sizes_ref, step, blocks_per_chunk, block_rows
+        # table entries are block multiples by contract; Mosaic must see
+        # that to tile the block_rows-row DMA
+        return pl.multiple_of(offs_ref[step], block_rows)
+
+    def copy(off, slot):
+        return pltpu.make_async_copy(
+            w_hbm.at[pl.ds(off, block_rows), pl.ds(col, tile_d)],
+            wslots.at[slot],
+            sems.at[slot],
         )
 
     def start_copy(step, slot):
-        off, active = offset(step)
-
-        @pl.when(active)
-        def _():
-            pltpu.make_async_copy(
-                w_hbm.at[pl.ds(off, block_rows), pl.ds(dj * tile_d, tile_d)],
-                wslots.at[slot],
-                sems.at[slot],
-            ).start()
-            if quantized:
-                # the scales lane rides the same slot rotation: one f32
-                # per block_rows block, fetched alongside its payload
-                pltpu.make_async_copy(
-                    s_hbm.at[pl.ds(off // block_rows, 1)],
-                    sslots.at[slot],
-                    sems_s.at[slot],
-                ).start()
-            if checksummed:
-                # the checksum lane rides the rotation the same way: one
-                # uint32 per block, fetched with the block it covers
-                pltpu.make_async_copy(
-                    c_hbm.at[pl.ds(off // block_rows, 1)],
-                    cslots.at[slot],
-                    sems_c.at[slot],
-                ).start()
+        copy(offset(step), slot).start()
 
     def wait_and_compute(step, slot):
-        off, active = offset(step)
-
-        @pl.when(active)
-        def _():
-            pltpu.make_async_copy(
-                w_hbm.at[pl.ds(off, block_rows), pl.ds(dj * tile_d, tile_d)],
-                wslots.at[slot],
-                sems.at[slot],
-            ).wait()
-            wb = wslots[slot].astype(jnp.float32)
-            if quantized:
-                pltpu.make_async_copy(
-                    s_hbm.at[pl.ds(off // block_rows, 1)],
-                    sslots.at[slot],
-                    sems_s.at[slot],
-                ).wait()
-                # upcast + dequantize in VMEM, accumulate in f32: one
-                # multiply per element before the identical dot, so the
-                # reference twin's elementwise dequant stays bitwise equal
-                wb = wb * sslots[slot][0]
-            if checksummed:
-                # checksums span full (block_rows, D) storage blocks while
-                # this kernel sees (block_rows, tile_d) tiles, so the word
-                # is fetched (charging the lane's DMA) but verified at the
-                # selection boundary where whole blocks are visible
-                pltpu.make_async_copy(
-                    c_hbm.at[pl.ds(off // block_rows, 1)],
-                    cslots.at[slot],
-                    sems_c.at[slot],
-                ).wait()
-            xb = pl.load(x_ref, (slice(None), pl.ds(off, block_rows)))
-            out_ref[...] += jnp.dot(
-                xb.astype(jnp.float32),
-                wb,
-                preferred_element_type=jnp.float32,
-            )
+        off = offset(step)
+        copy(off, slot).wait()
+        xb = _column_block(x_ref, off, block_rows, window)
+        part = _block_dot(xb, wslots[slot].astype(jnp.float32))
+        if quantized:
+            # dequantize after the contraction: one f32 multiply per output
+            # element by the block's scale, the same order the reference
+            # twin uses, so the two stay bitwise equal
+            part = part * s_smem[off // block_rows]
+        out_ref[...] += part
 
     out_ref[...] = jnp.zeros_like(out_ref)
-    _pipelined_steps(total, n_slots, start_copy, wait_and_compute)
+    _pipelined_steps(count_ref[0], n_slots, start_copy, wait_and_compute)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "block_rows", "tile_d", "max_chunk_rows", "prefetch_depth", "interpret"
+        "block_rows", "tile_d", "prefetch_depth", "interpret"
     ),
 )
 def chunk_gather_matmul_dma(
@@ -291,29 +277,27 @@ def chunk_gather_matmul_dma(
     *,
     block_rows: int = 8,
     tile_d: int = 128,
-    max_chunk_rows: int = 512,
     prefetch_depth: int = 1,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """y (B, D) f32 = Σ_chunks x_chunk @ W_chunk, fetched by an explicitly
-    ``prefetch_depth``-deep double-buffered DMA pipeline. Numerically
+    ``prefetch_depth``-deep double-buffered DMA pipeline over the table's
+    covered blocks (``block_list``), in ascending row order. Numerically
     identical at every depth (the schedule only re-times the same fetches) —
     matches ``chunk_gather_matmul_ref`` exactly like the BlockSpec kernel.
 
     With ``scales`` (the quantized chunk format, ``kernels/quantize.py``):
-    ``w`` is the int8 payload and each DMA step additionally fetches its
-    block's f32 scale through the same slot rotation, dequantizing in VMEM
-    (``q.astype(f32) * scale``) before the identical f32 accumulation —
-    matching ``blocked_masked_matmul(..., scales=...)`` bitwise.
+    ``w`` is the int8 payload, the per-block f32 scales sit in SMEM for the
+    whole call, and each block's f32 partial product is multiplied by its
+    scale before accumulation — matching
+    ``blocked_masked_matmul(..., scales=...)`` bitwise.
 
     With ``checksums`` (``kernels/quantize.block_checksums`` /
-    ``core/offload.pack_checksums``): each DMA step additionally fetches
-    its block's uint32 checksum through a third lane of the same slot
-    rotation, so integrity metadata travels with the payload it covers at
-    kernel granularity. The words are fetched and waited on but not
-    verified here — a checksum covers the full (block_rows, D) storage
-    block while the kernel fetches (block_rows, tile_d) tiles; the honest
-    re-verification happens at the selection boundary
+    ``core/offload.pack_checksums``): the per-block uint32 integrity words
+    travel into SMEM with the call, next to the payload they cover. They
+    are not verified here — a checksum covers the full (block_rows, D)
+    storage block while the kernel fetches (block_rows, tile_d) tiles; the
+    verification happens at the selection boundary
     (``serving/sparse_exec.refresh_layer``), identically on both backends.
     Output is bit-identical with and without the lane."""
     n, d = w.shape
@@ -324,8 +308,6 @@ def chunk_gather_matmul_dma(
         raise ValueError(f"D={d} must be a multiple of tile_d={tile_d}")
     if n % block_rows:
         raise ValueError(f"N={n} must be a multiple of block_rows={block_rows}")
-    if max_chunk_rows % block_rows:
-        raise ValueError("max_chunk_rows must be a multiple of block_rows")
     quantized = scales is not None
     if quantized and scales.shape != (n // block_rows,):
         raise ValueError(
@@ -337,44 +319,42 @@ def chunk_gather_matmul_dma(
             f"checksums must be ({n // block_rows},), got {checksums.shape}"
         )
     n_slots = prefetch_depth + 1
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [
         pl.BlockSpec((b, n), lambda dj, *_: (0, 0)),  # x resident in VMEM
-        pl.BlockSpec(memory_space=_ANY),  # w stays in HBM; DMA'd manually
+        pl.BlockSpec(memory_space=pl.ANY),  # w stays in HBM; DMA'd manually
     ]
-    operands = [starts, sizes, x, w]
-    slots = [pltpu.VMEM((n_slots, block_rows, tile_d), w.dtype)]
-    sem_lanes = [pltpu.SemaphoreType.DMA((n_slots,))]
+    offs, count = block_list(starts, sizes, n, block_rows)
+    operands = [offs, count, x.astype(jnp.float32), w]
     if quantized:
-        in_specs.append(pl.BlockSpec(memory_space=_ANY))  # scales lane in HBM
+        in_specs.append(smem)
         operands.append(scales.astype(jnp.float32))
-        slots.append(pltpu.VMEM((n_slots, 1), jnp.float32))  # sslots
-        sem_lanes.append(pltpu.SemaphoreType.DMA((n_slots,)))  # sems_s
     if checksummed:
-        in_specs.append(pl.BlockSpec(memory_space=_ANY))  # checksum lane
+        in_specs.append(smem)
         operands.append(checksums.astype(jnp.uint32))
-        slots.append(pltpu.VMEM((n_slots, 1), jnp.uint32))  # cslots
-        sem_lanes.append(pltpu.SemaphoreType.DMA((n_slots,)))  # sems_c
-    scratch = slots + sem_lanes
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(d // tile_d,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((b, tile_d), lambda dj, *_: (0, dj)),
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            pltpu.VMEM((n_slots, block_rows, tile_d), w.dtype),
+            pltpu.SemaphoreType.DMA((n_slots,)),
+        ],
     )
     return pl.pallas_call(
         functools.partial(
             _matmul_dma_kernel,
             block_rows=block_rows,
             tile_d=tile_d,
-            blocks_per_chunk=max_chunk_rows // block_rows,
             n_slots=n_slots,
+            window=_lane_window(n, block_rows),
             quantized=quantized,
             checksummed=checksummed,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
 
@@ -385,147 +365,69 @@ def chunk_gather_matmul_dma(
 
 
 def _mlp_dma_kernel(
-    starts_ref,  # scalar prefetch (2, K): lane 0 = hidden_mlp, lane 1 = ffn
-    sizes_ref,  # scalar prefetch (2, K)
-    x_ref,  # (B, N) VMEM
+    offs_ref,  # scalar prefetch (2, nb): block lists, 0 = hidden_mlp, 1 = ffn
+    count_ref,  # scalar prefetch (2,): real entries per lane
+    x_ref,  # (B, N) f32 VMEM
     wg_hbm,  # (N, F) ANY
     wu_hbm,  # (N, F) ANY
     wd_hbm,  # (F, D) ANY
     fmask_ref,  # (1, F) VMEM f32 — exact ffn row mask (all-ones = table only)
-    *rest,  # [sg/su/sd_hbm,] [cg/cu/cd_hbm,] out_ref, h?, slots...,
-    #         [scale slots,] [ck slots,] sems..., [scale sems,] [ck sems]
+    *rest,  # [sg, su, sd SMEM,] [cg, cu, cd SMEM,] out_ref, h, slots, accs, sems
     block_rows: int,
     tile_f: int,
     tile_d: int,
-    blocks_per_chunk: int,
     n_slots: int,
     n_f_tiles: int,
     n_d_tiles: int,
+    x_window: int,
+    h_window: int,
     quantized: bool,
     checksummed: bool,
 ):
-    idx = 0
-    sg_hbm = su_hbm = sd_hbm = gsc = usc = dsc = None
-    sems_gs = sems_us = sems_ds = None
-    cg_hbm = cu_hbm = cd_hbm = gck = uck = dck = None
-    sems_gc = sems_uc = sems_dc = None
-    if quantized:
-        sg_hbm, su_hbm, sd_hbm = rest[idx:idx + 3]
-        idx += 3
-    if checksummed:
-        cg_hbm, cu_hbm, cd_hbm = rest[idx:idx + 3]
-        idx += 3
-    out_ref, h_ref, gslots, uslots, dslots = rest[idx:idx + 5]
-    idx += 5
-    if quantized:
-        gsc, usc, dsc = rest[idx:idx + 3]
-        idx += 3
-    if checksummed:
-        gck, uck, dck = rest[idx:idx + 3]
-        idx += 3
-    acc_g, acc_u, sems_g, sems_u, sems_d = rest[idx:idx + 5]
-    idx += 5
-    if quantized:
-        sems_gs, sems_us, sems_ds = rest[idx:idx + 3]
-        idx += 3
-    if checksummed:
-        sems_gc, sems_uc, sems_dc = rest[idx:idx + 3]
-    k = starts_ref.shape[1]
-    total = k * blocks_per_chunk
+    sg, su, sd = rest[:3] if quantized else (None, None, None)
+    (out_ref, h_ref, gslots, uslots, dslots, acc_g, acc_u,
+     sems_g, sems_u, sems_d) = rest[3 * (quantized + checksummed):]
 
     def offset(lane, step):
-        return _chunk_step_offset(
-            starts_ref, sizes_ref, step, blocks_per_chunk, block_rows, lane=lane
-        )
+        return pl.multiple_of(offs_ref[lane, step], block_rows)
 
     # -- phase 1: gate/up over the hidden lane, one f-tile at a time --------
     def gate_up_tile(fj):
-        def start_copy(step, slot):
-            off, active = offset(0, step)
+        col = pl.multiple_of(fj * tile_f, tile_f)
 
-            @pl.when(active)
-            def _():
-                # one chunk block, fetched once, feeds BOTH gate and up
+        def copies(off, slot):
+            # one chunk block, fetched once, feeds BOTH gate and up
+            rows, cols = pl.ds(off, block_rows), pl.ds(col, tile_f)
+            return (
                 pltpu.make_async_copy(
-                    wg_hbm.at[pl.ds(off, block_rows), pl.ds(fj * tile_f, tile_f)],
-                    gslots.at[slot],
-                    sems_g.at[slot],
-                ).start()
+                    wg_hbm.at[rows, cols], gslots.at[slot], sems_g.at[slot]),
                 pltpu.make_async_copy(
-                    wu_hbm.at[pl.ds(off, block_rows), pl.ds(fj * tile_f, tile_f)],
-                    uslots.at[slot],
-                    sems_u.at[slot],
-                ).start()
-                if quantized:
-                    bk = off // block_rows
-                    pltpu.make_async_copy(
-                        sg_hbm.at[pl.ds(bk, 1)], gsc.at[slot], sems_gs.at[slot]
-                    ).start()
-                    pltpu.make_async_copy(
-                        su_hbm.at[pl.ds(bk, 1)], usc.at[slot], sems_us.at[slot]
-                    ).start()
-                if checksummed:
-                    bk = off // block_rows
-                    pltpu.make_async_copy(
-                        cg_hbm.at[pl.ds(bk, 1)], gck.at[slot], sems_gc.at[slot]
-                    ).start()
-                    pltpu.make_async_copy(
-                        cu_hbm.at[pl.ds(bk, 1)], uck.at[slot], sems_uc.at[slot]
-                    ).start()
+                    wu_hbm.at[rows, cols], uslots.at[slot], sems_u.at[slot]),
+            )
+
+        def start_copy(step, slot):
+            for c in copies(offset(0, step), slot):
+                c.start()
 
         def wait_and_compute(step, slot):
-            off, active = offset(0, step)
-
-            @pl.when(active)
-            def _():
-                pltpu.make_async_copy(
-                    wg_hbm.at[pl.ds(off, block_rows), pl.ds(fj * tile_f, tile_f)],
-                    gslots.at[slot],
-                    sems_g.at[slot],
-                ).wait()
-                pltpu.make_async_copy(
-                    wu_hbm.at[pl.ds(off, block_rows), pl.ds(fj * tile_f, tile_f)],
-                    uslots.at[slot],
-                    sems_u.at[slot],
-                ).wait()
-                gb = gslots[slot].astype(jnp.float32)
-                ub = uslots[slot].astype(jnp.float32)
-                if quantized:
-                    bk = off // block_rows
-                    pltpu.make_async_copy(
-                        sg_hbm.at[pl.ds(bk, 1)], gsc.at[slot], sems_gs.at[slot]
-                    ).wait()
-                    pltpu.make_async_copy(
-                        su_hbm.at[pl.ds(bk, 1)], usc.at[slot], sems_us.at[slot]
-                    ).wait()
-                    gb = gb * gsc[slot][0]
-                    ub = ub * usc[slot][0]
-                if checksummed:
-                    # fetched with the payload, verified at the selection
-                    # boundary (see chunk_gather_matmul_dma docstring)
-                    bk = off // block_rows
-                    pltpu.make_async_copy(
-                        cg_hbm.at[pl.ds(bk, 1)], gck.at[slot], sems_gc.at[slot]
-                    ).wait()
-                    pltpu.make_async_copy(
-                        cu_hbm.at[pl.ds(bk, 1)], uck.at[slot], sems_uc.at[slot]
-                    ).wait()
-                xb = pl.load(x_ref, (slice(None), pl.ds(off, block_rows)))
-                xb = xb.astype(jnp.float32)
-                acc_g[...] += jnp.dot(xb, gb,
-                                      preferred_element_type=jnp.float32)
-                acc_u[...] += jnp.dot(xb, ub,
-                                      preferred_element_type=jnp.float32)
+            off = offset(0, step)
+            for c in copies(off, slot):
+                c.wait()
+            xb = _column_block(x_ref, off, block_rows, x_window)
+            g = _block_dot(xb, gslots[slot].astype(jnp.float32))
+            u = _block_dot(xb, uslots[slot].astype(jnp.float32))
+            if quantized:
+                bk = off // block_rows
+                g = g * sg[bk]
+                u = u * su[bk]
+            acc_g[...] += g
+            acc_u[...] += u
 
         acc_g[...] = jnp.zeros_like(acc_g)
         acc_u[...] = jnp.zeros_like(acc_u)
-        _pipelined_steps(total, n_slots, start_copy, wait_and_compute)
+        _pipelined_steps(count_ref[0], n_slots, start_copy, wait_and_compute)
         g = acc_g[...]
-        pl.store(
-            h_ref,
-            (slice(None), pl.ds(fj * tile_f, tile_f)),
-            g * (1.0 / (1.0 + jnp.exp(-g))) * acc_u[...],
-        )
+        h_ref[:, pl.ds(col, tile_f)] = g * (1.0 / (1.0 + jnp.exp(-g))) * acc_u[...]
 
     def f_body(fj, _):
         gate_up_tile(fj)
@@ -535,68 +437,33 @@ def _mlp_dma_kernel(
 
     # -- phase 2: down over the ffn lane, consuming h straight from VMEM ----
     def down_tile(dj):
-        def start_copy(step, slot):
-            off, active = offset(1, step)
+        col = pl.multiple_of(dj * tile_d, tile_d)
 
-            @pl.when(active)
-            def _():
-                pltpu.make_async_copy(
-                    wd_hbm.at[pl.ds(off, block_rows), pl.ds(dj * tile_d, tile_d)],
-                    dslots.at[slot],
-                    sems_d.at[slot],
-                ).start()
-                if quantized:
-                    pltpu.make_async_copy(
-                        sd_hbm.at[pl.ds(off // block_rows, 1)],
-                        dsc.at[slot],
-                        sems_ds.at[slot],
-                    ).start()
-                if checksummed:
-                    pltpu.make_async_copy(
-                        cd_hbm.at[pl.ds(off // block_rows, 1)],
-                        dck.at[slot],
-                        sems_dc.at[slot],
-                    ).start()
+        def copy(off, slot):
+            return pltpu.make_async_copy(
+                wd_hbm.at[pl.ds(off, block_rows), pl.ds(col, tile_d)],
+                dslots.at[slot],
+                sems_d.at[slot],
+            )
+
+        def start_copy(step, slot):
+            copy(offset(1, step), slot).start()
 
         def wait_and_compute(step, slot):
-            off, active = offset(1, step)
+            off = offset(1, step)
+            copy(off, slot).wait()
+            # the exact ffn mask applies at the gather, NOT to the h output:
+            # block-rounding may pull in rows outside the selected mask, and
+            # those must contribute zero for the kernel to equal the
+            # masked-matmul reference exactly
+            hb = _column_block(h_ref, off, block_rows, h_window)
+            hb = hb * _column_block(fmask_ref, off, block_rows, h_window)
+            part = _block_dot(hb, dslots[slot].astype(jnp.float32))
+            if quantized:
+                part = part * sd[off // block_rows]
+            out_ref[:, pl.ds(col, tile_d)] += part
 
-            @pl.when(active)
-            def _():
-                pltpu.make_async_copy(
-                    wd_hbm.at[pl.ds(off, block_rows), pl.ds(dj * tile_d, tile_d)],
-                    dslots.at[slot],
-                    sems_d.at[slot],
-                ).wait()
-                db = dslots[slot].astype(jnp.float32)
-                if quantized:
-                    pltpu.make_async_copy(
-                        sd_hbm.at[pl.ds(off // block_rows, 1)],
-                        dsc.at[slot],
-                        sems_ds.at[slot],
-                    ).wait()
-                    db = db * dsc[slot][0]
-                if checksummed:
-                    pltpu.make_async_copy(
-                        cd_hbm.at[pl.ds(off // block_rows, 1)],
-                        dck.at[slot],
-                        sems_dc.at[slot],
-                    ).wait()
-                # the exact ffn mask applies at the gather, NOT to the h
-                # output: block-rounding may pull in rows outside the
-                # selected mask, and those must contribute zero for the
-                # kernel to equal the masked-matmul reference exactly
-                hb = pl.load(h_ref, (slice(None), pl.ds(off, block_rows)))
-                hb = hb * fmask_ref[0, pl.ds(off, block_rows)]
-                cur = pl.load(out_ref, (slice(None), pl.ds(dj * tile_d, tile_d)))
-                pl.store(
-                    out_ref,
-                    (slice(None), pl.ds(dj * tile_d, tile_d)),
-                    cur + jnp.dot(hb, db,
-                                  preferred_element_type=jnp.float32),
-                )
-
-        _pipelined_steps(total, n_slots, start_copy, wait_and_compute)
+        _pipelined_steps(count_ref[1], n_slots, start_copy, wait_and_compute)
 
     out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -610,8 +477,8 @@ def _mlp_dma_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "block_rows", "tile_f", "tile_d", "max_chunk_rows", "prefetch_depth",
-        "interpret", "return_h",
+        "block_rows", "tile_f", "tile_d", "prefetch_depth", "interpret",
+        "return_h",
     ),
 )
 def chunk_gather_mlp_dma(
@@ -628,7 +495,6 @@ def chunk_gather_mlp_dma(
     block_rows: int = 8,
     tile_f: int = 128,
     tile_d: int = 128,
-    max_chunk_rows: int = 512,
     prefetch_depth: int = 1,
     interpret: bool = False,
     return_h: bool = False,
@@ -656,15 +522,15 @@ def chunk_gather_mlp_dma(
     scratch occupy the same positional slot.
 
     With ``scales = (sg, su, sd)`` the three weights are int8 payloads of
-    the quantized chunk format; each lane's DMA step fetches its block's
-    f32 scale through the same slot rotation and dequantizes in VMEM
-    before the identical f32 accumulation (bitwise equal to the reference
-    backend's quantized schedule twin).
+    the quantized chunk format; the scale lanes sit in SMEM for the call
+    and each block's f32 partial product is multiplied by its scale before
+    accumulation (bitwise equal to the reference backend's quantized
+    schedule twin).
 
-    With ``checksums = (cg, cu, cd)`` each lane's DMA step additionally
-    fetches its block's uint32 checksum through the rotation —
-    fetch-and-wait only, verified at the selection boundary (see
-    ``chunk_gather_matmul_dma``); output is bit-identical either way."""
+    With ``checksums = (cg, cu, cd)`` the three lanes' per-block uint32
+    integrity words travel into SMEM with the call — verified at the
+    selection boundary (see ``chunk_gather_matmul_dma``); output is
+    bit-identical either way."""
     n, f = w_gate.shape
     fd, d = w_down.shape
     b = x.shape[0]
@@ -680,8 +546,6 @@ def chunk_gather_mlp_dma(
         raise ValueError(f"prefetch_depth must be >= 0, got {prefetch_depth}")
     if f % tile_f or d % tile_d or n % block_rows or f % block_rows:
         raise ValueError("alignment violation")
-    if max_chunk_rows % block_rows:
-        raise ValueError("max_chunk_rows must be a multiple of block_rows")
     if ffn_mask is None:
         fmask = jnp.ones((1, f), jnp.float32)
     else:
@@ -716,7 +580,8 @@ def chunk_gather_mlp_dma(
     # h (B, F) occupies the same positional kernel-ref slot either way:
     # second OUTPUT when the caller wants it, first SCRATCH when not (so a
     # return_h=False dispatch never writes the intermediate back to HBM)
-    vmem = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out_specs = (vmem, vmem) if return_h else vmem
     out_shape = jax.ShapeDtypeStruct((b, d), jnp.float32)
     if return_h:
@@ -724,24 +589,23 @@ def chunk_gather_mlp_dma(
     h_scratch = [] if return_h else [pltpu.VMEM((b, f), jnp.float32)]
     in_specs = [
         vmem,  # x
-        pl.BlockSpec(memory_space=_ANY),  # w_gate
-        pl.BlockSpec(memory_space=_ANY),  # w_up
-        pl.BlockSpec(memory_space=_ANY),  # w_down
+        pl.BlockSpec(memory_space=pl.ANY),  # w_gate
+        pl.BlockSpec(memory_space=pl.ANY),  # w_up
+        pl.BlockSpec(memory_space=pl.ANY),  # w_down
         vmem,  # ffn mask
     ]
-    operands = [starts, sizes, x, w_gate, w_up, w_down, fmask]
-    scale_slots, scale_sems = [], []
+    nb = max(n, f) // block_rows
+    lanes = [block_list(starts[i], sizes[i], m, block_rows)
+             for i, m in enumerate((n, f))]
+    offs = jnp.stack([jnp.pad(o, (0, nb - o.shape[0])) for o, _ in lanes])
+    count = jnp.concatenate([c for _, c in lanes])
+    operands = [offs, count, x.astype(jnp.float32), w_gate, w_up, w_down, fmask]
     if quantized:
-        in_specs += [pl.BlockSpec(memory_space=_ANY)] * 3  # scales lanes
+        in_specs += [smem] * 3  # scales lanes
         operands += [s.astype(jnp.float32) for s in (sg, su, sd)]
-        scale_slots = [pltpu.VMEM((n_slots, 1), jnp.float32)] * 3
-        scale_sems = [pltpu.SemaphoreType.DMA((n_slots,))] * 3
-    ck_slots, ck_sems = [], []
     if checksummed:
-        in_specs += [pl.BlockSpec(memory_space=_ANY)] * 3  # checksum lanes
+        in_specs += [smem] * 3  # checksum lanes
         operands += [c.astype(jnp.uint32) for c in (cg, cu, cd)]
-        ck_slots = [pltpu.VMEM((n_slots, 1), jnp.uint32)] * 3
-        ck_sems = [pltpu.SemaphoreType.DMA((n_slots,))] * 3
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(),
@@ -751,24 +615,24 @@ def chunk_gather_mlp_dma(
             pltpu.VMEM((n_slots, block_rows, tile_f), w_gate.dtype),
             pltpu.VMEM((n_slots, block_rows, tile_f), w_up.dtype),
             pltpu.VMEM((n_slots, block_rows, tile_d), w_down.dtype),
-        ] + scale_slots + ck_slots + [
             pltpu.VMEM((b, tile_f), jnp.float32),
             pltpu.VMEM((b, tile_f), jnp.float32),
             pltpu.SemaphoreType.DMA((n_slots,)),
             pltpu.SemaphoreType.DMA((n_slots,)),
             pltpu.SemaphoreType.DMA((n_slots,)),
-        ] + scale_sems + ck_sems,
+        ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
             _mlp_dma_kernel,
             block_rows=block_rows,
             tile_f=tile_f,
             tile_d=tile_d,
-            blocks_per_chunk=max_chunk_rows // block_rows,
             n_slots=n_slots,
             n_f_tiles=f // tile_f,
             n_d_tiles=d // tile_d,
+            x_window=_lane_window(n, block_rows),
+            h_window=_lane_window(f, block_rows),
             quantized=quantized,
             checksummed=checksummed,
         ),
@@ -776,4 +640,3 @@ def chunk_gather_mlp_dma(
         out_shape=out_shape,
         interpret=interpret,
     )(*operands)
-    return out

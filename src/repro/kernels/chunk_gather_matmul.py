@@ -31,9 +31,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.contiguity import mask_to_chunks_np
 
-# JAX renamed TPUCompilerParams -> CompilerParams (jax>=0.5); support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(
     starts_ref,  # scalar prefetch: (K,) block-aligned row starts
@@ -113,7 +110,7 @@ def chunk_gather_matmul(
         functools.partial(_kernel, block_rows=block_rows),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

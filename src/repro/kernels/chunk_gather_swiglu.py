@@ -15,9 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# JAX renamed TPUCompilerParams -> CompilerParams (jax>=0.5); support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(
     starts_ref,
@@ -109,7 +106,7 @@ def chunk_gather_swiglu(
         functools.partial(_kernel, block_rows=block_rows),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, f), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,26 +1,22 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``sparse_matmul`` / ``sparse_swiglu`` dispatch to the TPU kernel on TPU and
-to interpret mode elsewhere (this container is CPU-only: interpret executes
-the kernel body in Python for correctness validation — the BlockSpec tiling
-and scalar-prefetch structure are identical).
+``sparse_matmul`` / ``sparse_swiglu`` dispatch to the compiled kernel on TPU
+and to interpret mode on the CPU test platform (``resolve_interpret``:
+interpret executes the kernel body in Python for correctness validation —
+the BlockSpec tiling and scalar-prefetch structure are identical).
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.contiguity import mask_to_chunks_np
+from .backend import resolve_interpret
 from .chunk_gather_dma import chunk_gather_matmul_dma, chunk_gather_mlp_dma
 from .chunk_gather_matmul import align_chunk_table, chunk_gather_matmul
 from .chunk_gather_swiglu import chunk_gather_swiglu
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def sparse_matmul(
@@ -42,7 +38,7 @@ def sparse_matmul(
         block_rows=block_rows,
         tile_d=tile_d,
         max_chunk_rows=max_chunk_rows,
-        interpret=not _on_tpu(),
+        interpret=resolve_interpret(),
     )
 
 
@@ -66,7 +62,7 @@ def sparse_swiglu(
         block_rows=block_rows,
         tile_f=tile_f,
         max_chunk_rows=max_chunk_rows,
-        interpret=not _on_tpu(),
+        interpret=resolve_interpret(),
     )
 
 
@@ -78,7 +74,6 @@ def sparse_matmul_dma(
     *,
     block_rows: int = 8,
     tile_d: int = 128,
-    max_chunk_rows: int = 512,
     prefetch_depth: int = 1,
 ) -> jnp.ndarray:
     """``sparse_matmul`` through the explicitly double-buffered DMA kernel:
@@ -92,9 +87,8 @@ def sparse_matmul_dma(
         sizes,
         block_rows=block_rows,
         tile_d=tile_d,
-        max_chunk_rows=max_chunk_rows,
         prefetch_depth=prefetch_depth,
-        interpret=not _on_tpu(),
+        interpret=resolve_interpret(),
     )
 
 
@@ -110,7 +104,6 @@ def sparse_mlp_fused(
     block_rows: int = 8,
     tile_f: int = 128,
     tile_d: int = 128,
-    max_chunk_rows: int = 512,
     prefetch_depth: int = 1,
     return_h: bool = False,
 ) -> jnp.ndarray:
@@ -130,9 +123,8 @@ def sparse_mlp_fused(
         block_rows=block_rows,
         tile_f=tile_f,
         tile_d=tile_d,
-        max_chunk_rows=max_chunk_rows,
         prefetch_depth=prefetch_depth,
-        interpret=not _on_tpu(),
+        interpret=resolve_interpret(),
         return_h=return_h,
     )
 

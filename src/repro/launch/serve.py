@@ -14,11 +14,13 @@ Continuous-batching mode (Poisson arrivals over request slots):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..configs import ARCH_IDS, get_config
 from ..configs.base import InputShape
 from ..core.faults import CORRUPTION_PROFILES, FAULT_PROFILES
@@ -295,7 +297,41 @@ def resolve_mesh(spec: str, cfg, batch: int, streams: int) -> ServeMesh:
     return ServeMesh.create(data, model)
 
 
+def build_engine(args, cfg, mesh: ServeMesh, params=None) -> ServeEngine:
+    """The serve CLI's engine, built from its parsed ``args`` for ``cfg``.
+    ``params`` defaults to the seed-0 random init; pass them to build
+    several engines over one set of weights."""
+    model = build_model(cfg)
+    if params is None:
+        params = model.init(jax.random.key(0))
+    return ServeEngine(model, params, max_seq=args.max_seq, batch_size=args.batch,
+                       device=args.device, sparsity=args.sparsity,
+                       method=args.method,
+                       plan_refresh_interval=args.plan_refresh_interval,
+                       cache_mb=args.cache_mb, overlap=args.overlap,
+                       prefetch_depth=args.prefetch_depth,
+                       backend=args.backend, wbits=args.wbits, mesh=mesh,
+                       fault_profile=args.fault_profile,
+                       fault_seed=args.fault_seed, degrade=args.degrade,
+                       corruption_profile=args.corruption_profile,
+                       corruption_seed=args.corruption_seed,
+                       max_reread=args.max_reread, recover=args.recover,
+                       kv_page_tokens=args.kv_page_tokens)
+
+
+@dataclasses.dataclass
+class SingleStreamRun:
+    """What one single-stream run produced: the prompt batch, the prefill's
+    last-position logits (b, vocab) and the decoded tokens
+    (b, decode_tokens + 1), first token included."""
+
+    batch: dict
+    prefill_logits: jnp.ndarray
+    tokens: jnp.ndarray
+
+
 def main():
+    enable_compile_cache()
     ap = build_parser()
     args = ap.parse_args()
     validate_seed_flags(ap, args)
@@ -307,26 +343,16 @@ def main():
         ap.error("--kv-page-tokens requires --streams (paged KV is slot-mode "
                  "only: requests are admitted through the page allocator)")
     mesh = resolve_mesh(args.mesh, cfg, args.batch, args.streams)
-    model = build_model(cfg)
-    params = model.init(jax.random.key(0))
-    eng = ServeEngine(model, params, max_seq=args.max_seq, batch_size=args.batch,
-                      device=args.device, sparsity=args.sparsity,
-                      method=args.method,
-                      plan_refresh_interval=args.plan_refresh_interval,
-                      cache_mb=args.cache_mb, overlap=args.overlap,
-                      prefetch_depth=args.prefetch_depth,
-                      backend=args.backend, wbits=args.wbits, mesh=mesh,
-                      fault_profile=args.fault_profile,
-                      fault_seed=args.fault_seed, degrade=args.degrade,
-                      corruption_profile=args.corruption_profile,
-                      corruption_seed=args.corruption_seed,
-                      max_reread=args.max_reread, recover=args.recover,
-                      kv_page_tokens=args.kv_page_tokens)
-
+    eng = build_engine(args, cfg, mesh)
     if args.streams > 0:
-        _serve_streams(args, cfg, eng)
-        return
+        run_streams(args, cfg, eng)
+    else:
+        run_single_stream(args, cfg, eng)
 
+
+def run_single_stream(args, cfg, eng: ServeEngine) -> SingleStreamRun:
+    """Single-stream mode: prefill → ``--frames`` frame appends (VLMs) →
+    ``--decode-tokens`` greedy decode, printing one line per stage."""
     shape = InputShape("cli", args.prompt_len, args.batch, "train")
     batch = make_dummy_batch(cfg, shape)
     last = eng.prefill(batch)
@@ -344,7 +370,7 @@ def main():
                   f"io_sim {st.io_sim_s*1e3:.2f} ms")
     tok0 = jnp.argmax(last, -1)[:, None].astype(jnp.int32)
     decode = eng.decode_per_token if args.per_token else eng.decode
-    decode(tok0, args.decode_tokens)
+    tokens = decode(tok0, args.decode_tokens)
     dsteps = [s for s in eng.stats if s.kind == "decode"]
     mode = "per-token" if args.per_token else "fused-scan"
     print(f"[decode:{mode}] {args.decode_tokens} tokens  "
@@ -381,6 +407,7 @@ def main():
               f"min_throttle {fs['min_throttle_scale']:.2f}  "
               f"degrade_scale {fs['degrade_scale']:.2f}")
     _print_integrity(args, s)
+    return SingleStreamRun(batch=batch, prefill_logits=last, tokens=tokens)
 
 
 def _print_integrity(args, s) -> None:
@@ -397,8 +424,10 @@ def _print_integrity(args, s) -> None:
           f"reread {s['integrity_reread_s']*1e3:.2f} ms")
 
 
-def _serve_streams(args, cfg, eng):
-    """Continuous-batching mode: Poisson arrivals into request slots."""
+def run_streams(args, cfg, eng: ServeEngine) -> Scheduler:
+    """Continuous-batching mode: ``--streams`` Poisson-arriving requests
+    served to completion through ``--batch`` slots. Returns the drained
+    scheduler (finished requests and their tokens)."""
     rng = np.random.default_rng(0)
 
     def make_request(rid: int) -> Request:
@@ -454,6 +483,7 @@ def _serve_streams(args, cfg, eng):
               f"tighten {fs['degrade_tighten_steps']}  "
               f"relax {fs['degrade_relax_steps']}")
     _print_integrity(args, s)
+    return sched
 
 
 if __name__ == "__main__":

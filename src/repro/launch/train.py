@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs import ARCH_IDS, get_config
+from ..compile_cache import enable_compile_cache
 from ..data import DataConfig, lm_batches
 from ..models import build_model
 from ..sharding import MeshRules, use_rules
@@ -25,6 +26,7 @@ from .mesh import make_mesh
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true")

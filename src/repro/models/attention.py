@@ -148,7 +148,6 @@ def _want_seq_shard_map(n_heads: int, q_shape) -> bool:
 def _seq_sharded_attention(q, k, v, window):
     """Causal attention with the q-sequence explicitly sharded over 'model'
     (shard_map); kv replicated across the model axis (one all-gather)."""
-    import jax.experimental.shard_map as shmap
     from jax.sharding import PartitionSpec as P
 
     from ..sharding import current_rules
@@ -170,12 +169,12 @@ def _seq_sharded_attention(q, k, v, window):
         q_offset = shard * s_local  # absolute position of this shard's row 0
         return _blockwise_attention(qb, kb, vb, q_offset, True, window)
 
-    return shmap.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v)
 
 

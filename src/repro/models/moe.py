@@ -182,7 +182,6 @@ def moe_ffn_ep(
     """
     import math
 
-    import jax.experimental.shard_map as shmap
     from jax.sharding import PartitionSpec as P
 
     from ..sharding import current_rules
@@ -312,12 +311,12 @@ def moe_ffn_ep(
         return y, aux
 
     xt = x.reshape(t, d)
-    y, aux = shmap.shard_map(
+    y, aux = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(tok_spec, rep_spec, ew_spec, ew_spec, ew_spec),
         out_specs=(tok_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(xt, params[f"{p}router"], params[f"{p}we_gate"], params[f"{p}we_up"],
       params[f"{p}we_down"])
 

@@ -595,7 +595,9 @@ class ServeEngine:
             self.params, tokens, self.cache, n_tokens, self._plan
         )
         # ONE blocking host transfer for the whole scan (per-layer estimates
-        # + residency/integrity counters)
+        # + residency/integrity counters), after the tokens and cache are
+        # ready so the wall below covers the whole decode
+        jax.block_until_ready((toks, self.cache))
         ios, hits, misses, byts, sbyts, civ = jax.device_get(
             (ios, hits, misses, byts, sbyts, civ)
         )
@@ -786,6 +788,7 @@ class ServeEngine:
             )
         t0 = time.perf_counter()
         last, self.cache = self.model.prefill(self.params, batch, self.max_seq)
+        jax.block_until_ready((last, self.cache))
         wall = time.perf_counter() - t0
         n = int(batch["tokens"].shape[1])
         # prefill loads every matrix once, contiguously (weights streamed)
@@ -812,6 +815,7 @@ class ServeEngine:
             )
         t0 = time.perf_counter()
         hidden, self.cache, io = self._append(self.params, frame_embeds, self.cache)
+        jax.block_until_ready((hidden, self.cache))
         io = float(io)
         wall = time.perf_counter() - t0
         sim = self.simulator.measure_from_estimate(io, name="frame")

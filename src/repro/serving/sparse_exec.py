@@ -287,7 +287,8 @@ class SparseExecution:
         are bitwise identical; an ``ExecutionBackend`` instance may be
         passed directly. ``kernel_prefetch_depth`` is the DMA kernels' VMEM
         slot count − 1 (numerics are depth-invariant);
-        ``kernel_interpret=None`` auto-selects interpret mode off-TPU.
+        ``kernel_interpret=None`` compiles the kernels on TPU and interprets
+        them on the CPU test platform (``resolve_interpret``).
 
         ``cache_mb``: DRAM byte budget of the dynamic chunk residency
         cache (paper §5 "Leveraging Additional Memory Budget"). When > 0,
@@ -463,7 +464,6 @@ class SparseExecution:
                 prefetch_depth=kernel_prefetch_depth,
                 interpret=kernel_interpret,
                 block_rows=KERNEL_BLOCK_ROWS,
-                max_chunk_rows=KERNEL_MAX_CHUNK_ROWS,
                 mesh=self.mesh.mesh,
             )
         if self.backend.is_kernel:

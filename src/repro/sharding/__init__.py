@@ -7,6 +7,7 @@ from .serve import (
 )
 from .specs import (
     MeshRules,
+    auto_mesh,
     current_rules,
     logical_to_spec,
     set_rules,

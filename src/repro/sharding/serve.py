@@ -48,7 +48,7 @@ from ..kernels.quantize import (
     QUANT_SUFFIX_PAYLOAD,
     QUANT_SUFFIX_SCALE,
 )
-from .specs import MeshRules
+from .specs import MeshRules, auto_mesh
 
 MESH_AXES = ("data", "model")
 
@@ -136,7 +136,7 @@ class ServeMesh:
         validate_serve_mesh(data, model, n_devices=len(jax.devices()))
         if data * model == 1:
             return ServeMesh.single()
-        mesh = jax.make_mesh((data, model), MESH_AXES)
+        mesh = auto_mesh((data, model), MESH_AXES)
         return ServeMesh(data, model, mesh, MeshRules.for_mesh(mesh))
 
     @staticmethod

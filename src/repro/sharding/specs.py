@@ -31,10 +31,18 @@ from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Axis = Union[str, Tuple[str, ...], None]
 
+
+
+def auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the compiler propagates
+    shardings from the constraints these rules place (``jax.make_mesh``
+    defaults to ``Explicit`` axes, under which ops such as a gather over a
+    data-sharded index must name their output sharding)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class MeshRules:

@@ -1,55 +1,9 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
 see the real single CPU device; only launch/dryrun.py forces 512 devices
 (in its own process)."""
-import sys
-import types
-
 import jax
 import numpy as np
 import pytest
-
-# ---------------------------------------------------------------------------
-# hypothesis fallback: the package is not installable in every environment
-# this suite runs in. Property-based tests degrade to a skip instead of
-# failing the whole module at import time; everything else in those modules
-# still collects and runs.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    _hyp = types.ModuleType("hypothesis")
-    _st = types.ModuleType("hypothesis.strategies")
-
-    def _strategy(*_args, **_kwargs):
-        return None
-
-    def _st_getattr(_name):
-        return _strategy
-
-    _st.__getattr__ = _st_getattr  # PEP 562: st.integers / st.floats / ...
-
-    def _given(*_args, **_kwargs):
-        def deco(fn):
-            def _skipped():
-                pytest.skip("hypothesis not installed: property test skipped")
-
-            _skipped.__name__ = fn.__name__
-            _skipped.__doc__ = fn.__doc__
-            return _skipped
-
-        return deco
-
-    def _settings(*_args, **_kwargs):
-        def deco(fn):
-            return fn
-
-        return deco
-
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 
 @pytest.fixture(scope="session")

@@ -82,6 +82,49 @@ def test_swiglu_mlp_bitwise_parity_and_h():
     assert float(jnp.max(jnp.abs(np.asarray(h_ref)[:, off]))) > 0.0
 
 
+@pytest.mark.parametrize("name", ["reference", "kernel"])
+def test_backend_ops_are_fusion_boundaries(name):
+    """Both paths fence their activations and results with an optimization
+    barrier, so XLA compiles (and rounds) the code around the call the same
+    way on either backend — a Pallas call is opaque, the twin's jnp ops
+    would not be without it."""
+    be = ExecutionBackend.create(name, interpret=True if name == "kernel" else None)
+    n, f, b = 64, 96, 2
+    w = jnp.zeros((n, f), jnp.bfloat16)
+    wd = jnp.zeros((f, n), jnp.bfloat16)
+    x = jnp.zeros((b, n), jnp.bfloat16)
+    hm, fm = jnp.ones((n,), bool), jnp.ones((f,), bool)
+    st, sz = masks_to_block_tables(jnp.ones((2, f), bool))
+    s0, z0 = st[0], sz[0]
+    proj = jax.make_jaxpr(lambda x: be.project(w, x, hm, s0, z0))(x)
+    mlp = jax.make_jaxpr(lambda x: be.swiglu_mlp(w, w, wd, x, hm, fm, st, sz))(x)
+    for jaxpr in (proj, mlp):
+        eqns = jaxpr.jaxpr.eqns
+        assert eqns[0].primitive.name == "optimization_barrier"
+        assert eqns[-1].primitive.name == "optimization_barrier"
+
+
+@pytest.mark.parametrize("wdtype", ["bf16", "int8"])
+def test_blocked_matmul_keeps_f32_accuracy_on_bf16_storage(wdtype):
+    """An f32 input against bf16/int8 storage (the down projection's h)
+    keeps f32 accuracy, far inside one bf16 rounding of the input (~4e-3
+    relative), against an f64 product: the twin contracts at full f32
+    precision, not in one bf16 pass."""
+    rng = np.random.default_rng(6)
+    n, d = 256, 128
+    wf = rng.normal(0, 0.05, (n, d)).astype(np.float32)
+    if wdtype == "int8":
+        w, s = quantize_rows(jnp.asarray(wf), 8)
+        exact = np.asarray(dequantize_rows(w, s), np.float64)
+    else:
+        w, s = jnp.asarray(wf, jnp.bfloat16), None
+        exact = np.asarray(w.astype(jnp.float32), np.float64)
+    x = rng.normal(0, 1, (2, n)).astype(np.float32)
+    y = np.asarray(blocked_masked_matmul(jnp.asarray(x), w, 8, s), np.float64)
+    ref = x.astype(np.float64) @ exact
+    assert np.max(np.abs(y - ref)) / np.max(np.abs(ref)) < 1e-5
+
+
 def test_blocked_matmul_is_exact_masked_semantics():
     rng = np.random.default_rng(2)
     n, d = 32, 16

@@ -56,7 +56,7 @@ def test_matmul_dma_parity(n, d, b, depth, rng):
     s, z = plan_to_kernel_table(mask, block_rows=8, max_chunks=max(n // 8, 1),
                                 max_chunk_rows=64)
     y = sparse_matmul_dma(w, x, jnp.asarray(s), jnp.asarray(z),
-                          max_chunk_rows=64, prefetch_depth=depth)
+                          prefetch_depth=depth)
     yref = chunk_gather_matmul_ref(w, x, s, z)
     assert _rel_err(y, yref) < 1e-5
 
@@ -68,7 +68,7 @@ def test_matmul_dma_bf16(rng):
     mask = rng.random(n) < 0.5
     s, z = plan_to_kernel_table(mask, block_rows=8, max_chunks=n // 8,
                                 max_chunk_rows=64)
-    y = sparse_matmul_dma(w, x, jnp.asarray(s), jnp.asarray(z), max_chunk_rows=64)
+    y = sparse_matmul_dma(w, x, jnp.asarray(s), jnp.asarray(z))
     yref = chunk_gather_matmul_ref(w, x, s, z)
     assert _rel_err(y, yref) < 2e-2
 
@@ -81,7 +81,7 @@ def test_matmul_dma_all_padded(depth, rng):
     x = jnp.asarray(rng.normal(0, 1, (2, 64)), jnp.float32)
     s = jnp.zeros((5,), jnp.int32)
     z = jnp.zeros((5,), jnp.int32)
-    y = sparse_matmul_dma(w, x, s, z, max_chunk_rows=32, prefetch_depth=depth)
+    y = sparse_matmul_dma(w, x, s, z, prefetch_depth=depth)
     assert float(jnp.max(jnp.abs(y))) == 0.0
 
 
@@ -93,7 +93,7 @@ def test_matmul_dma_single_max_chunk(depth, rng):
     x = jnp.asarray(rng.normal(0, 1, (3, n)), jnp.float32)
     s = jnp.asarray([32], jnp.int32)
     z = jnp.asarray([64], jnp.int32)
-    y = sparse_matmul_dma(w, x, s, z, max_chunk_rows=64, prefetch_depth=depth)
+    y = sparse_matmul_dma(w, x, s, z, prefetch_depth=depth)
     yref = chunk_gather_matmul_ref(w, x, s, z)
     assert _rel_err(y, yref) < 1e-5
 
@@ -109,7 +109,7 @@ def test_matmul_dma_k_exceeds_real_chunks(rng):
     s[0], z[0] = 8, 16
     outs = [
         sparse_matmul_dma(w, x, jnp.asarray(s), jnp.asarray(z),
-                          max_chunk_rows=32, prefetch_depth=depth)
+                          prefetch_depth=depth)
         for depth in DEPTHS
     ]
     yref = chunk_gather_matmul_ref(w, x, s, z)
@@ -127,7 +127,7 @@ def test_matmul_dma_depth_deeper_than_steps(rng):
     x = jnp.asarray(rng.normal(0, 1, (1, 16)), jnp.float32)
     s = jnp.asarray([0], jnp.int32)
     z = jnp.asarray([8], jnp.int32)
-    y = sparse_matmul_dma(w, x, s, z, max_chunk_rows=8, prefetch_depth=7)
+    y = sparse_matmul_dma(w, x, s, z, prefetch_depth=7)
     yref = chunk_gather_matmul_ref(w, x, s, z)
     assert _rel_err(y, yref) < 1e-5
 
@@ -148,8 +148,7 @@ def test_mlp_fused_parity(depth, rng):
     th = plan_to_kernel_table(rng.random(n) < 0.7, 8, n // 8, 64)
     tf = plan_to_kernel_table(rng.random(f) < 0.3, 8, f // 8, 64)
     s2, z2 = _stack_lanes([th, tf], max(n, f) // 8)
-    y = sparse_mlp_fused(wg, wu, wd, x, s2, z2, max_chunk_rows=64,
-                         prefetch_depth=depth)
+    y = sparse_mlp_fused(wg, wu, wd, x, s2, z2, prefetch_depth=depth)
     yref = chunk_gather_mlp_ref(wg, wu, wd, x, s2, z2)
     assert _rel_err(y, yref) < 1e-5
 
@@ -168,7 +167,7 @@ def test_mlp_fused_empty_lane(empty_lane, rng):
     lanes = [full, full]
     lanes[empty_lane] = empty
     s2, z2 = _stack_lanes(lanes, n // 8)
-    y = sparse_mlp_fused(wg, wu, wd, x, s2, z2, max_chunk_rows=64)
+    y = sparse_mlp_fused(wg, wu, wd, x, s2, z2)
     assert float(jnp.max(jnp.abs(y))) == 0.0
 
 
@@ -180,7 +179,7 @@ def test_mlp_fused_full_lanes_equal_dense(rng):
     x = jnp.asarray(rng.normal(0, 1, (2, n)), jnp.float32)
     full = plan_to_kernel_table(np.ones(n, bool), 8, n // 8, 64)
     s2, z2 = _stack_lanes([full, full], n // 8)
-    y = sparse_mlp_fused(wg, wu, wd, x, s2, z2, max_chunk_rows=64)
+    y = sparse_mlp_fused(wg, wu, wd, x, s2, z2)
     g = x @ wg
     dense = (g * (1.0 / (1.0 + jnp.exp(-g))) * (x @ wu)) @ wd
     assert _rel_err(y, dense) < 1e-5
@@ -205,7 +204,7 @@ def test_quantized_matmul_kernel_vs_twin_bitwise(depth, rng):
     # the twin sees the block-rounded mask (what the kernel actually gathers)
     cov = np.asarray(chunk_table_to_mask(jnp.asarray(ks), jnp.asarray(kz), n))
     y = chunk_gather_matmul_dma(q, x, jnp.asarray(ks), jnp.asarray(kz), s,
-                                max_chunk_rows=64, prefetch_depth=depth,
+                                prefetch_depth=depth,
                                 interpret=True)
     y_twin = blocked_masked_matmul(x * cov.astype(np.float32), q, 8, s)
     assert bool(jnp.all(y == y_twin))
@@ -224,8 +223,7 @@ def test_quantized_zero_magnitude_chunk(depth, rng):
     x = jnp.asarray(rng.normal(0, 1, (2, n)), jnp.float32)
     ks = jnp.asarray([0, 32], jnp.int32)  # covers rows 0..32 incl. the zeros
     kz = jnp.asarray([32, 16], jnp.int32)
-    y = chunk_gather_matmul_dma(q, x, ks, kz, s, max_chunk_rows=32,
-                                prefetch_depth=depth, interpret=True)
+    y = chunk_gather_matmul_dma(q, x, ks, kz, s, prefetch_depth=depth, interpret=True)
     yref = chunk_gather_matmul_ref(dequantize_rows(q, s), x, ks, kz)
     assert _rel_err(y, yref) < 1e-6
     assert bool(jnp.all(jnp.isfinite(y)))
@@ -239,8 +237,7 @@ def test_quantized_all_padded_plan(depth, rng):
     q, s = quantize_rows(jnp.asarray(w), 8)
     x = jnp.asarray(rng.normal(0, 1, (2, 64)), jnp.float32)
     z = jnp.zeros((5,), jnp.int32)
-    y = chunk_gather_matmul_dma(q, x, z, z, s, max_chunk_rows=32,
-                                prefetch_depth=depth, interpret=True)
+    y = chunk_gather_matmul_dma(q, x, z, z, s, prefetch_depth=depth, interpret=True)
     assert float(jnp.max(jnp.abs(y))) == 0.0
 
 
@@ -256,7 +253,7 @@ def test_quantized_k_exceeds_real_chunks(rng):
     ks[0], kz[0] = 8, 16
     outs = [
         chunk_gather_matmul_dma(q, x, jnp.asarray(ks), jnp.asarray(kz), s,
-                                max_chunk_rows=32, prefetch_depth=depth,
+                                prefetch_depth=depth,
                                 interpret=True)
         for depth in DEPTHS
     ]
@@ -282,8 +279,7 @@ def test_quantized_saturation_extremes(depth):
     x = jnp.asarray(np.ones((1, n), np.float32))
     ks = jnp.asarray([0], jnp.int32)
     kz = jnp.asarray([32], jnp.int32)
-    y = chunk_gather_matmul_dma(q, x, ks, kz, s, max_chunk_rows=32,
-                                prefetch_depth=depth, interpret=True)
+    y = chunk_gather_matmul_dma(q, x, ks, kz, s, prefetch_depth=depth, interpret=True)
     yref = chunk_gather_matmul_ref(dequantize_rows(q, s), x, ks, kz)
     assert _rel_err(y, yref) < 1e-6
 
@@ -304,7 +300,7 @@ def test_quantized_mlp_fused_parity(depth, rng):
     tf = plan_to_kernel_table(rng.random(f) < 0.3, 8, f // 8, 64)
     s2, z2 = _stack_lanes([th, tf], max(n, f) // 8)
     y = chunk_gather_mlp_dma(qg, qu, qd, x, s2, z2, scales=(sg, su, sd),
-                             max_chunk_rows=64, prefetch_depth=depth,
+                             prefetch_depth=depth,
                              interpret=True)
     yref = chunk_gather_mlp_ref(
         dequantize_rows(qg, sg), dequantize_rows(qu, su),
@@ -355,6 +351,40 @@ def test_masks_to_block_tables_empty_and_full():
     # full mask: one run split into max_chunk_rows pieces covering all rows
     assert int(kz[1].sum()) == n
     assert int((kz[1] > 0).sum()) == n // 32
+
+
+@pytest.mark.parametrize("density", [0.0, 0.3, 1.0])
+def test_block_list_is_the_ascending_covered_blocks(density, rng):
+    """The kernels' step list: every block the table covers, ascending and
+    once, padded; the count says how many are real."""
+    from repro.kernels.chunk_gather_dma import block_list
+
+    n = 256
+    mask = rng.random(n) < density
+    ks, kz = masks_to_block_tables(jnp.asarray(mask)[None], 8, 32)
+    offs, count = block_list(ks[0], kz[0], n, 8)
+    cov = np.asarray(chunk_table_to_mask(ks[0], kz[0], n)).reshape(-1, 8)
+    want = np.flatnonzero(cov.any(axis=1)) * 8
+    assert offs.shape == (n // 8,) and count.shape == (1,)
+    assert int(count[0]) == len(want)
+    np.testing.assert_array_equal(np.asarray(offs)[: len(want)], want)
+
+
+@pytest.mark.parametrize("depth", DEPTHS)
+def test_matmul_dma_unsorted_table_matches_twin_bitwise(depth, rng):
+    """Table entry order does not change the arithmetic: blocks accumulate
+    in ascending row order, as in the reference twin, and padded entries
+    anywhere in the table are skipped."""
+    n, d, b = 128, 128, 2
+    w = jnp.asarray(rng.normal(0, 0.5, (n, d)), jnp.float32)
+    x = jnp.asarray(rng.normal(0, 1, (b, n)), jnp.float32)
+    starts = jnp.asarray([96, 0, 0, 40, 16], jnp.int32)
+    sizes = jnp.asarray([16, 8, 0, 24, 8], jnp.int32)
+    cov = chunk_table_to_mask(starts, sizes, n).astype(jnp.float32)
+    y = chunk_gather_matmul_dma(w, x, starts, sizes, prefetch_depth=depth,
+                                interpret=True)
+    y_twin = blocked_masked_matmul(x * cov, w, 8)
+    assert bool(jnp.all(y == y_twin))
 
 
 # ---------------------------------------------------------------------------
@@ -463,8 +493,7 @@ def test_fused_mlp_from_batched_selection_per_device(served, device, rng):
     x = jnp.asarray(rng.normal(0, 1, (2, n)), jnp.float32)
     s2 = jnp.stack([ks[ih], ks[i_f]])
     z2 = jnp.stack([kz[ih], kz[i_f]])
-    y = sparse_mlp_fused(wg, wu, wd, x, s2, z2,
-                         max_chunk_rows=KERNEL_MAX_CHUNK_ROWS)
+    y = sparse_mlp_fused(wg, wu, wd, x, s2, z2)
     yref = chunk_gather_mlp_ref(wg, wu, wd, x, s2, z2)
     assert _rel_err(y, yref) < 1e-5
     for lane, idx, n_site in ((0, ih, n), (1, i_f, f)):

@@ -4,8 +4,7 @@ backend x wbits, and cross-feature regressions with the fault/integrity
 stack.
 
 The allocator/pool tests are seeded randomized property tests (plain
-``np.random.default_rng`` — hypothesis is optional in this environment,
-see conftest.py) that run ``check()`` after every single operation:
+``np.random.default_rng``) that run ``check()`` after every single operation:
 free-list conservation, no double free, no page reachable from two
 tables unless its refcount covers both, COW-fork isolation, and
 eviction never reclaiming a live-referenced page.

@@ -1,0 +1,105 @@
+"""Compile the DMA gather kernels for a described TPU v5e, ahead of time.
+
+Mosaic refuses at compile time what interpret mode never checks: lane
+offsets off the 128-lane tile, DMA row offsets it cannot prove aligned,
+one-word DMAs below an HBM array's tiling, more VMEM than a kernel may use.
+These tests compile both decode-path kernels at the served models' real
+widths (TinyLlama-1.1B, InternVL2-76B), bf16 and int8 with scales, plus
+the checksum lane — a chip is described, not attached, so nothing runs.
+
+The topology is described inside module fixtures, never at import: only
+one process may hold the TPU library, and pytest-xdist workers all import
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import chunk_gather_matmul_dma, chunk_gather_mlp_dma
+
+# (name, d_model, d_ff) at published widths
+WIDTHS = (("tinyllama", 2048, 5632), ("internvl2", 8192, 28672))
+BATCH = 2  # the serve CLI's default slot count
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a described chip's executables cannot be read back from the persistent
+    # cache without one attached; keep these compiles out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _lanes(sharding, sizes, dtype):
+    return tuple(_spec(sharding, (m // 8,), dtype) for m in sizes)
+
+
+def _assert_kernel(compiled):
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+
+
+CASES = [(w, wbits, False) for w in WIDTHS for wbits in (16, 8)]
+CASES.append((WIDTHS[1], 8, True))  # InternVL2 int8 with the checksum lane
+
+
+def _case_id(case):
+    (name, _, _), wbits, ck = case
+    return f"{name}-w{wbits}" + ("-checksums" if ck else "")
+
+
+@pytest.mark.parametrize("case", CASES, ids=_case_id)
+def test_matmul_dma_compiles_for_v5e(one_chip, case):
+    (_, n, f), wbits, checksummed = case
+    wdt = jnp.bfloat16 if wbits == 16 else jnp.int8
+    k = max(n, f) // 8  # the serve plan's table width (n_max / block_rows)
+    args = (
+        _spec(one_chip, (n, n), wdt),  # attn_out wo at d_model width
+        _spec(one_chip, (BATCH, n), jnp.float32),
+        _spec(one_chip, (k,), jnp.int32),
+        _spec(one_chip, (k,), jnp.int32),
+        _lanes(one_chip, (n,), jnp.float32)[0] if wbits == 8 else None,
+        _lanes(one_chip, (n,), jnp.uint32)[0] if checksummed else None,
+    )
+    compiled = jax.jit(chunk_gather_matmul_dma).lower(*args).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("case", CASES, ids=_case_id)
+def test_mlp_dma_compiles_for_v5e(one_chip, case):
+    (_, n, f), wbits, checksummed = case
+    wdt = jnp.bfloat16 if wbits == 16 else jnp.int8
+    k = max(n, f) // 8
+    args = (
+        _spec(one_chip, (n, f), wdt),
+        _spec(one_chip, (n, f), wdt),
+        _spec(one_chip, (f, n), wdt),
+        _spec(one_chip, (BATCH, n), jnp.float32),
+        _spec(one_chip, (2, k), jnp.int32),
+        _spec(one_chip, (2, k), jnp.int32),
+        _spec(one_chip, (f,), jnp.float32),  # exact ffn mask
+        _lanes(one_chip, (n, n, f), jnp.float32) if wbits == 8 else None,
+        _lanes(one_chip, (n, n, f), jnp.uint32) if checksummed else None,
+    )
+    fn = jax.jit(lambda *a: chunk_gather_mlp_dma(*a, return_h=True))
+    _assert_kernel(fn.lower(*args).compile())

@@ -1,0 +1,168 @@
+"""The correctness check, driven through a whole run at a toy size on the
+CPU (the harness's look for a chip is skipped; the gather kernels' schedule
+twin stands in for the kernels): a sound run is correct, and the control
+and each fault the cells can have, planted under the timed path, come out
+not correct."""
+import json
+import shutil
+import time
+
+import numpy as np
+import pytest
+
+from bench import faults, harness, spec
+
+TOY = {"arch": "granite-3-2b", "reference": "decoder", "hidden_size": 128,
+       "intermediate_size": 256, "num_hidden_layers": 2, "num_attention_heads": 4,
+       "num_key_value_heads": 2, "head_dim": 32, "vocab_size": 512, "rope_theta": 10000.0,
+       "rms_norm_eps": 1e-05, "served_norm_eps": 1e-06, "hidden_act": "silu",
+       "tie_word_embeddings": True,
+       "reduced": ["hidden_size", "intermediate_size", "num_hidden_layers",
+                   "num_attention_heads", "num_key_value_heads", "head_dim", "vocab_size"]}
+BATCH = {"kind": "closed", "engine": {"method": "topk", "sparsity": 0.4,
+         "backend": "reference", "wbits": 16, "cache_mb": 0, "round_tokens": 1,
+         "slots": 4, "max_seq": 24, "plan_refresh_interval": 1},
+         "clients": 4, "prompt": {"frame_tokens": 8, "text_tokens": 8, "frames": 1},
+         "output_tokens": 8}
+CHAT = {"kind": "poisson", "engine": {"method": "dense_free", "sparsity": 0.0,
+        "backend": "reference", "wbits": 16, "cache_mb": 0, "round_tokens": 1,
+        "slots": 4, "max_seq": 48, "plan_refresh_interval": 1},
+        "rate_rps": 20.0, "prompt": {"frame_tokens": 8, "text_tokens": 8, "frames": [1, 2],
+                                     "frame_probs": [0.6, 0.4]},
+        "output_tokens": {"median": 6, "sigma": 0.5, "min": 2, "max": 16}, "base_seed": 0}
+# toy limits, from toy readings on the CPU: sound runs read logit_gap
+# 0.0005-0.0033, kv_rel_err 0.0073, kv0_rel_err 0.0029, mask_mismatch 0.003;
+# the program's int8 path reads logit_gap 0.0125 and kv0_rel_err 0.0080; the
+# float8 reference reads kv_rel_err 0.09 and kv0_rel_err 0.041-0.044
+LIMITS = {"toy.batch": {"logit_gap": 0.006, "kv_rel_err": 0.03, "kv0_rel_err": 0.005,
+                        "mask_mismatch": 0.02},
+          "toy.chat": {"logit_gap": 0.006, "kv_rel_err": 0.03, "kv0_rel_err": 0.005}}
+SECONDS = 1.5
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    root = tmp_path_factory.mktemp("bench_check")
+    bench = root / "bench"
+    for sub in ("reference", "metrics"):
+        shutil.copytree(spec.BENCH_DIR / sub, bench / sub)
+    (bench / "traffic").mkdir()
+    for kind in ("closed", "poisson"):
+        shutil.copy(spec.BENCH_DIR / "traffic" / f"{kind}.py", bench / "traffic")
+    (bench / "configs").mkdir()
+    (bench / "limits").mkdir()
+    (bench / "configs" / "toy.json").write_text(json.dumps(TOY))
+    (bench / "traffic" / "batch.json").write_text(json.dumps(BATCH))
+    (bench / "traffic" / "chat.json").write_text(json.dumps(CHAT))
+    for cell, lim in LIMITS.items():
+        (bench / "limits" / f"{cell}.json").write_text(json.dumps(lim))
+    (root / "BENCHMARK.json").write_text(json.dumps({
+        "configs": [{"name": "toy", "file": "bench/configs/toy.json"}],
+        "workloads": [{"name": "toy.batch", "config": "toy", "traffic": "batch", "chips": 1},
+                      {"name": "toy.chat", "config": "toy", "traffic": "chat", "chips": 1}],
+        "end_to_end": [{"name": "tokens_per_s", "unit": "tokens/s"},
+                       {"name": "setup_s", "unit": "s"},
+                       {"name": "ttft_p95_ms", "unit": "ms", "workloads": ["toy.chat"]}],
+        "per_layer": []}))
+    return root
+
+
+def run(root, cell, seed=2**33 + 1, **kw):
+    c = spec.load_cell(cell, repo_root=root, bench_dir=root / "bench")
+    return harness.run_cell(c, seed, SECONDS, False, time.perf_counter(), **kw)
+
+
+def test_sound_sparse_run_is_correct(root):
+    r = run(root, "toy.batch")
+    assert r["correct"] and r["failed"] == 0 and r["attempted"] >= 4
+    assert set(r["checks"]) == {"logit_gap", "kv_rel_err", "kv0_rel_err", "mask_mismatch"}
+    assert set(r["metrics"]) == {"tokens_per_s", "setup_s"}
+    assert list(r)[-1] == "checks"
+
+
+def test_sound_dense_run_is_correct_and_its_control_is_not(root):
+    r = run(root, "toy.chat")
+    assert r["correct"], r["checks"]
+    assert set(r["metrics"]) == {"tokens_per_s", "setup_s", "ttft_p95_ms"}
+    c = run(root, "toy.chat", control="fp8")
+    assert not c["correct"]
+    assert c["checks"]["kv0_rel_err"]["value"] > c["checks"]["kv0_rel_err"]["limit"]
+
+
+def test_the_programs_int8_path_as_control_is_not_correct(root):
+    c = run(root, "toy.batch", control="program")
+    assert not c["correct"]
+    assert c["checks"]["kv0_rel_err"]["value"] > c["checks"]["kv0_rel_err"]["limit"]
+
+
+def test_the_float8_control_of_a_sparse_cell_is_not_correct(root):
+    c = run(root, "toy.batch", control="fp8")
+    assert not c["correct"]
+    assert c["checks"]["kv0_rel_err"]["value"] > c["checks"]["kv0_rel_err"]["limit"]
+
+
+def test_a_step_that_returns_its_state_unchanged_is_caught(root):
+    r = run(root, "toy.batch", hooks=faults.stale_state())
+    assert not r["correct"]
+    assert r["checks"]["kv_rel_err"]["value"] > r["checks"]["kv_rel_err"]["limit"]
+
+
+def test_a_token_altered_where_it_is_produced_is_caught(root):
+    r = run(root, "toy.batch", hooks=faults.altered_token(TOY["vocab_size"]))
+    assert not r["correct"]
+    assert r["checks"]["logit_gap"]["value"] > r["checks"]["logit_gap"]["limit"]
+
+
+def test_half_the_batch_left_out_of_the_importance_is_caught(root):
+    with faults.half_batch():
+        r = run(root, "toy.batch")
+    assert not r["correct"]
+    assert r["checks"]["mask_mismatch"]["value"] > r["checks"]["mask_mismatch"]["limit"]
+
+
+def test_dense_faults_are_caught(root):
+    r = run(root, "toy.chat", hooks=faults.stale_state())
+    assert not r["correct"] and r["checks"]["kv_rel_err"]["value"] > 0.03
+    r = run(root, "toy.chat", hooks=faults.altered_token(TOY["vocab_size"]))
+    assert not r["correct"] and r["checks"]["logit_gap"]["value"] > 0.006
+
+
+def test_weights_of_one_layer_equal_that_slice_of_the_served_tree():
+    import jax.numpy as jnp
+
+    from bench.weights import dims_of, layer_params, served_params, top_params
+
+    dm = dims_of(TOY)
+    tree = served_params(dm, 2**40 + 3)
+    one = layer_params(dm, 2**40 + 3, 1, jnp.bfloat16)
+    for name, leaf in one.items():
+        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(tree["layers"][name][1]))
+    top = top_params(dm, 2**40 + 3, jnp.bfloat16)
+    np.testing.assert_array_equal(np.asarray(top["embed"]), np.asarray(tree["embed"]))
+    other = served_params(dm, 2**40 + 4)
+    assert not np.array_equal(np.asarray(other["embed"]), np.asarray(tree["embed"]))
+
+
+def test_the_reference_agrees_with_the_programs_dense_prefill():
+    """The reference and the served model share only the weights' seed:
+    the program's bfloat16 prefill logits lie within bfloat16 rounding of
+    the reference's float32 ones, and float8 weights do not."""
+    import jax.numpy as jnp
+
+    from bench.reference import decoder
+    from bench.serve import program_config
+    from bench.weights import dims_of, served_params
+    from repro.models import build_model
+
+    dm = dims_of(TOY)
+    model = build_model(program_config(TOY))
+    tokens = np.random.default_rng(0).integers(0, dm.vocab, (2, 12)).astype(np.int32)
+    last, _ = model.prefill(served_params(dm, 5), {"tokens": jnp.asarray(tokens)}, 16)
+    ref, _ = decoder.forward(dm, 5, tokens, [11])
+    lo, _ = decoder.forward(dm, 5, tokens, [11], fp8=True)
+    def err(a):
+        d = np.asarray(a, np.float32) - np.asarray(ref)[:, 0]
+        return float(np.linalg.norm(d) / np.linalg.norm(np.asarray(ref)))
+
+    assert err(last) < 3e-2
+    assert err(np.asarray(lo)[:, 0]) > 3 * err(last)

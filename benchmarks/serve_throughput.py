@@ -595,7 +595,7 @@ def bench_fault_robustness(rows: Rows, cfg, model, params,
 
       * serve/fault_identity — fault machinery attached but disabled must
         be FREE: greedy tokens AND io_summary() byte-identical to an
-        engine without it (select_overhead_s excluded: wall-clock timed);
+        engine without it;
       * serve/fault_off|on — sustained thermal throttle + per-request
         deadlines, DegradationController off vs on: asserts attainment_on
         strictly above attainment_off, p99_on strictly below p99_off, the
@@ -616,7 +616,6 @@ def bench_fault_robustness(rows: Rows, cfg, model, params,
         "when disabled"
     )
     s_base, s_none = base.io_summary(), eng_none.io_summary()
-    s_base.pop("select_overhead_s"), s_none.pop("select_overhead_s")
     assert s_base == s_none, (
         f"fault-off engine perturbed io_summary: "
         f"{ {k: (s_base[k], s_none[k]) for k in s_base if s_base[k] != s_none[k]} }"

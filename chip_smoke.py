@@ -39,8 +39,8 @@ VLM_ARGS = ["--arch", "internvl2-76b", "--method", "chunk", "--batch", "2",
             "--prompt-len", "512", "--frames", "2", "--decode-tokens", "8",
             "--max-seq", "1024"]
 VLM_LAYERS = 2
-# top-k selection: on a v5e the chunk selection costs ~2 s per layer
-# refresh (phase A's select_overhead), and over 22 layers it put the smoke
+# top-k selection: on a v5e one chunk-selection refresh costs ~2 s per
+# layer, and over 22 layers it put the smoke
 # past its 1,200 s; the decode still runs through the gather kernels
 LLM_ARGS = ["--arch", "tinyllama-1.1b", "--method", "topk", "--batch", "2",
             "--streams", "4", "--prompt-len", "32", "--decode-tokens", "8",

@@ -229,6 +229,7 @@ class ExecutionBackend:
                              check_vma=False)(*operands)
 
     # -- single-site projection (attn_out wo; gelu MLP fc/proj) -------------
+    @jax.named_scope("decode.gather")
     def project(
         self,
         w: jnp.ndarray,  # (N, D); int8 payload when scales is given
@@ -267,6 +268,7 @@ class ExecutionBackend:
         return jax.lax.optimization_barrier(y)
 
     # -- fused multi-site SwiGLU MLP -----------------------------------------
+    @jax.named_scope("decode.gather")
     def swiglu_mlp(
         self,
         w_gate: jnp.ndarray,  # (N, F)

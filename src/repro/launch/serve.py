@@ -382,8 +382,7 @@ def run_single_stream(args, cfg, eng: ServeEngine) -> SingleStreamRun:
           f"serial {s['decode_serial_s']*1e3:.2f} ms  "
           f"overlapped {s['decode_overlap_s']*1e3:.2f} ms  "
           f"stall {s['decode_stall_s']*1e3:.2f} ms  "
-          f"overlap_efficiency {s['overlap_efficiency']:.3f}  "
-          f"select_overhead {s['select_overhead_s']*1e3:.2f} ms")
+          f"overlap_efficiency {s['overlap_efficiency']:.3f}")
     if eng.mesh.is_sharded:
         ss = eng.shard_summary()
         per = ", ".join(f"{b/1e6:.1f}" for b in ss["io_bytes_per_shard"])

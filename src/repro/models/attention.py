@@ -370,6 +370,7 @@ def cache_layer_update(
     return k, v
 
 
+@jax.named_scope("decode.attention")
 def decode_attention(
     x: jnp.ndarray,  # (b, 1, d)
     params: Dict[str, jnp.ndarray],

@@ -50,7 +50,6 @@ decode_step only (their state is the cache).
 from __future__ import annotations
 
 import dataclasses
-import math
 import time
 from typing import Any, Dict, List, Optional
 
@@ -67,6 +66,7 @@ from ..core.faults import (
 from ..core.latency_model import MB
 from ..core.offload import ComputeModel, FlashOffloadSimulator, pack_checksums
 from ..core.pipeline import PipelineModel, PipelineTimeline, overlap_efficiency
+from .. import trace
 from ..models.model import Model
 from ..models.transformer import SPARSE_WEIGHT_NAMES
 from ..kernels.backend import validate_backend
@@ -94,7 +94,6 @@ class StepStats:
     tokens: int
     io_est_s: float
     io_sim_s: float
-    select_overhead_s: float
     wall_s: float
     # residency-tier accounting: selected rows served from the DRAM cache
     # (free) vs streamed from flash this step; 0/0 when the tier is off
@@ -125,7 +124,6 @@ IO_SUMMARY_KEYS = (
     "miss_rows",
     "cache_hit_rate",
     "io_bytes",
-    "select_overhead_s",
     "decode_compute_s",
     "decode_serial_s",
     "decode_overlap_s",
@@ -458,7 +456,6 @@ class ServeEngine:
             )
         self.stats: List[StepStats] = []
         self._plan = None  # chunk-plan carry, persists across decode calls
-        self._select_s_per_refresh: Optional[float] = None  # lazy, wall-timed
         # per-decode-call (n_steps, n_layers) simulated-I/O matrices, kept so
         # the host-side timeline can be repriced at other prefetch depths;
         # bounded to the most recent _LAYER_IO_LOG_MAX_STEPS decode steps so
@@ -540,7 +537,8 @@ class ServeEngine:
             dsb = self._plan_shard_bytes(new_plan) - self._plan_shard_bytes(plan)
             dci = (plan_integrity_counters(new_plan)
                    - plan_integrity_counters(plan))
-            nxt = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+            with jax.named_scope("decode.sample"):
+                nxt = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
             return (nxt, cache, new_plan), (
                 nxt[:, 0], io, h1 - h0, m1 - m0, db, dsb, dci
             )
@@ -551,19 +549,6 @@ class ServeEngine:
         )
         # toks: (n, b) -> (b, n)
         return toks.T, cache, plan, ios, hits, misses, byts, sbyts, civ
-
-    def _selection_seconds_per_refresh(self) -> float:
-        """Wall seconds one refresh step spends on chunk selection: the
-        batched per-layer dispatch (SparseExecution.time_selection — the
-        same quantity benchmarks/fig13_overhead.py reports per matrix)
-        × n_layers. Measured lazily once per engine, on the compiled path."""
-        if self.sparse_ctx is None or self.method == "dense":
-            return 0.0
-        if self._select_s_per_refresh is None:
-            self._select_s_per_refresh = (
-                self.sparse_ctx.time_selection() * self.model.cfg.n_layers
-            )
-        return self._select_s_per_refresh
 
     def _dense_step_bytes(self) -> float:
         """Per-decode-step transfer volume of the dense streaming policy
@@ -576,7 +561,9 @@ class ServeEngine:
         """Shared fused-loop body: run the scan, sync the estimate arrays
         once, convert them to simulated measurements, run the overlap
         pipeline, log per-step stats. Returns (new_tokens (b, n), per-step
-        charged latency (n,) — overlapped or serial per ``self.overlap``)."""
+        charged latency (n,) — overlapped or serial per ``self.overlap``).
+        Spans: ``engine.decode.wait`` while the host waits on the device,
+        ``engine.decode.account`` for the host-side accounting after it."""
         if self._plan is None:
             self._plan = self._init_plan()
         self._plan = reset_plan_counters(self._plan)
@@ -597,55 +584,51 @@ class ServeEngine:
         # ONE blocking host transfer for the whole scan (per-layer estimates
         # + residency/integrity counters), after the tokens and cache are
         # ready so the wall below covers the whole decode
-        jax.block_until_ready((toks, self.cache))
-        ios, hits, misses, byts, sbyts, civ = jax.device_get(
-            (ios, hits, misses, byts, sbyts, civ)
-        )
-        ios = np.asarray(ios, np.float64)  # (n, n_layers)
-        hits, misses = np.asarray(hits, np.float64), np.asarray(misses, np.float64)
-        byts = np.asarray(byts, np.float64)
-        sbyts = np.asarray(sbyts, np.float64)  # (n, n_shards)
-        civ = np.asarray(civ, np.float64)  # (n, len(INTEGRITY_COUNTER_KEYS))
-        self._integrity_totals += civ.sum(axis=0)
-        if self.method == "dense":
-            byts = np.full_like(byts, self._dense_step_bytes())
-            sbyts = np.full_like(sbyts, self._dense_step_bytes() / self.n_shards)
-        wall = time.perf_counter() - t0
-        io_steps = ios.sum(axis=1)
-        rows = hits + misses
-        hit_rates = np.where(rows > 0, hits / np.maximum(rows, 1.0), 0.0)
-        sims = self.simulator.measure_from_estimate_batch(
-            io_steps, name="decode", hit_rates=hit_rates, nbytes=byts,
-            shard_bytes=sbyts if self.n_shards > 1 else None,
-            integrity_s=civ[:, 5],
-        )
-        # the simulator's lift+jitter applies per step; spread it over the
-        # step's layers proportionally so the pipeline sees simulated time
-        scale = np.where(io_steps > 0, sims / np.maximum(io_steps, 1e-30), 1.0)
-        layer_io = ios * scale[:, None]
-        self._log_layer_io(layer_io)
-        tl = self.simulator.pipeline.timeline(layer_io, self.compute_layer_s)
-        n_refresh = math.ceil(n_tokens / self.plan_refresh_interval)
-        select_amortized = (
-            self._selection_seconds_per_refresh() * n_refresh / max(n_tokens, 1)
-        )
-        per_step_wall = wall / max(n_tokens, 1)
-        compute_step = float(np.asarray(self.compute_layer_s).sum())
-        for i, (est, sim, h, m) in enumerate(zip(io_steps, sims, hits, misses)):
-            self.stats.append(
-                StepStats("decode", 1, float(est), float(sim),
-                          select_amortized, per_step_wall,
-                          hit_rows=float(h), miss_rows=float(m),
-                          nbytes=float(byts[i]), compute_s=compute_step,
-                          serial_s=float(tl.serial_s[i]),
-                          overlap_s=float(tl.overlap_s[i]),
-                          stall_s=float(tl.stall_s[i]),
-                          bubble_s=float(tl.bubble_s[i]))
+        with trace.span("engine.decode.wait"):
+            jax.block_until_ready((toks, self.cache))
+            ios, hits, misses, byts, sbyts, civ = jax.device_get(
+                (ios, hits, misses, byts, sbyts, civ)
             )
-        self._observe_degradation(io_steps, sims)
-        self._observe_corruption(float(civ[:, 0].sum()), float(misses.sum()))
-        charged = tl.overlap_s if self.overlap else tl.serial_s
-        return toks, charged
+        with trace.span("engine.decode.account"):
+            ios = np.asarray(ios, np.float64)  # (n, n_layers)
+            hits, misses = np.asarray(hits, np.float64), np.asarray(misses, np.float64)
+            byts = np.asarray(byts, np.float64)
+            sbyts = np.asarray(sbyts, np.float64)  # (n, n_shards)
+            civ = np.asarray(civ, np.float64)  # (n, len(INTEGRITY_COUNTER_KEYS))
+            self._integrity_totals += civ.sum(axis=0)
+            if self.method == "dense":
+                byts = np.full_like(byts, self._dense_step_bytes())
+                sbyts = np.full_like(sbyts, self._dense_step_bytes() / self.n_shards)
+            wall = time.perf_counter() - t0
+            io_steps = ios.sum(axis=1)
+            rows = hits + misses
+            hit_rates = np.where(rows > 0, hits / np.maximum(rows, 1.0), 0.0)
+            sims = self.simulator.measure_from_estimate_batch(
+                io_steps, name="decode", hit_rates=hit_rates, nbytes=byts,
+                shard_bytes=sbyts if self.n_shards > 1 else None,
+                integrity_s=civ[:, 5],
+            )
+            # the simulator's lift+jitter applies per step; spread it over the
+            # step's layers proportionally so the pipeline sees simulated time
+            scale = np.where(io_steps > 0, sims / np.maximum(io_steps, 1e-30), 1.0)
+            layer_io = ios * scale[:, None]
+            self._log_layer_io(layer_io)
+            tl = self.simulator.pipeline.timeline(layer_io, self.compute_layer_s)
+            per_step_wall = wall / max(n_tokens, 1)
+            compute_step = float(np.asarray(self.compute_layer_s).sum())
+            for i, (est, sim, h, m) in enumerate(zip(io_steps, sims, hits, misses)):
+                self.stats.append(
+                    StepStats("decode", 1, float(est), float(sim), per_step_wall,
+                              hit_rows=float(h), miss_rows=float(m),
+                              nbytes=float(byts[i]), compute_s=compute_step,
+                              serial_s=float(tl.serial_s[i]),
+                              overlap_s=float(tl.overlap_s[i]),
+                              stall_s=float(tl.stall_s[i]),
+                              bubble_s=float(tl.bubble_s[i]))
+                )
+            self._observe_degradation(io_steps, sims)
+            self._observe_corruption(float(civ[:, 0].sum()), float(misses.sum()))
+        return toks, (tl.overlap_s if self.overlap else tl.serial_s)
 
     def _decode_lift(self) -> float:
         """The deterministic lift decode measurements carry
@@ -694,7 +677,9 @@ class ServeEngine:
         equal settings, byte-identical output) as the legacy
         ``decode_per_token`` loop."""
         self._validate_greedy(greedy)
-        toks, _ = self._run_decode_scan(first_token, n_tokens)
+        trace.count("engine.decode_calls")
+        with trace.span("engine.decode", n_tokens=n_tokens):
+            toks, _ = self._run_decode_scan(first_token, n_tokens)
         return jnp.concatenate([first_token, toks], axis=1)
 
     def decode_per_token(self, first_token: jnp.ndarray, n_tokens: int,
@@ -707,6 +692,11 @@ class ServeEngine:
         eliminates. Pipeline accounting is backfilled once the loop ends
         (the overlap timeline needs every step's per-layer I/O)."""
         self._validate_greedy(greedy)
+        trace.count("engine.decode_calls")
+        with trace.span("engine.decode", n_tokens=n_tokens):
+            return self._decode_per_token(first_token, n_tokens)
+
+    def _decode_per_token(self, first_token: jnp.ndarray, n_tokens: int):
         if self._plan is None:
             self._plan = self._init_plan()
         self._plan = reset_plan_counters(self._plan)
@@ -722,7 +712,6 @@ class ServeEngine:
         start_idx = len(self.stats)
         io_rows = []
         det_call = 0.0
-        select_per_refresh = self._selection_seconds_per_refresh()
         for i in range(n_tokens):
             t0 = time.perf_counter()
             (logits, self.cache, io_vec, self._plan, dh, dm, db,
@@ -752,8 +741,7 @@ class ServeEngine:
                 integrity_s=float(dci[5]),
             )
             io_rows.append(io_vec * (sim / io if io > 0 else 1.0))
-            sel = select_per_refresh if (i % self.plan_refresh_interval) == 0 else 0.0
-            self.stats.append(StepStats("decode", 1, io, sim, sel, wall,
+            self.stats.append(StepStats("decode", 1, io, sim, wall,
                                         hit_rows=hit, miss_rows=miss,
                                         nbytes=nbytes))
         if not io_rows:  # n_tokens == 0: nothing to time
@@ -786,11 +774,12 @@ class ServeEngine:
                 "paged KV is slot-mode only: admit requests with admit_slot "
                 "(single-stream prefill would bypass the page allocator)"
             )
-        t0 = time.perf_counter()
-        last, self.cache = self.model.prefill(self.params, batch, self.max_seq)
-        jax.block_until_ready((last, self.cache))
-        wall = time.perf_counter() - t0
         n = int(batch["tokens"].shape[1])
+        t0 = time.perf_counter()
+        with trace.span("engine.prefill", tokens=n):
+            last, self.cache = self.model.prefill(self.params, batch, self.max_seq)
+            jax.block_until_ready((last, self.cache))
+        wall = time.perf_counter() - t0
         # prefill loads every matrix once, contiguously (weights streamed)
         est = self._dense_io() if self.sparse_ctx else 0.0
         nbytes = (
@@ -801,7 +790,7 @@ class ServeEngine:
             est, name="prefill", nbytes=nbytes,
             shard_bytes=self._even_shard_bytes(nbytes),
         )
-        self.stats.append(StepStats("prefill", n, est, sim, 0.0, wall,
+        self.stats.append(StepStats("prefill", n, est, sim, wall,
                                     nbytes=float(nbytes)))
         self._plan = None  # new sequence → stale plan
         return last
@@ -813,15 +802,15 @@ class ServeEngine:
                 "paged KV is slot-mode only: append_frame extends the "
                 "single-stream linear cache, which paged engines don't keep"
             )
+        n = int(frame_embeds.shape[1])
         t0 = time.perf_counter()
-        hidden, self.cache, io = self._append(self.params, frame_embeds, self.cache)
-        jax.block_until_ready((hidden, self.cache))
+        with trace.span("engine.prefill", tokens=n):
+            hidden, self.cache, io = self._append(self.params, frame_embeds, self.cache)
+            jax.block_until_ready((hidden, self.cache))
         io = float(io)
         wall = time.perf_counter() - t0
         sim = self.simulator.measure_from_estimate(io, name="frame")
-        self.stats.append(
-            StepStats("frame", int(frame_embeds.shape[1]), io, sim, 0.0, wall)
-        )
+        self.stats.append(StepStats("frame", n, io, sim, wall))
         return hidden
 
     # -- slot mode (continuous batching; used by serving.scheduler) ----------
@@ -887,7 +876,29 @@ class ServeEngine:
         cannot cover the unshared pages (``kv_can_admit`` pre-checks)."""
         if not (0 <= slot < self.batch_size):
             raise ValueError(f"slot {slot} out of range [0, {self.batch_size})")
-        last, cache1 = self._prefill_one(self.params, batch)
+        n = int(batch["tokens"].shape[1])
+        with trace.span("engine.prefill", tokens=n):
+            last, cache1 = self._prefill_one(self.params, batch)
+        with trace.span("engine.kv_write", slot=slot):
+            self._write_slot(slot, batch, cache1)
+        est = self._dense_io() if self.sparse_ctx else 0.0
+        nbytes = (
+            self.sparse_ctx.sparsifiable_bytes(self.model.cfg.n_layers)
+            if self.sparse_ctx else 0.0
+        )
+        sim = self.simulator.measure_from_estimate(
+            est, name=f"admit[{slot}]", nbytes=nbytes,
+            shard_bytes=self._even_shard_bytes(nbytes),
+        )
+        self.stats.append(
+            StepStats("prefill", n, est, sim, 0.0, nbytes=float(nbytes))
+        )
+        return last, sim
+
+    def _write_slot(self, slot: int, batch: Dict[str, jnp.ndarray], cache1):
+        """Write a batch-1 prefill's cache into ``slot``: its fresh pages
+        and the pushed page table (paged), or its row (dense), and its
+        length."""
         if self.kv_pool is not None:
             seq_len, hashes = prompt_prefix_hashes(batch, self.kv_page_tokens)
             entries = self.kv_pool.admit(slot, seq_len, hashes)
@@ -915,20 +926,6 @@ class ServeEngine:
             self.cache["length"] = (
                 self.cache["length"].at[slot].set(cache1["length"].astype(jnp.int32))
             )
-        est = self._dense_io() if self.sparse_ctx else 0.0
-        nbytes = (
-            self.sparse_ctx.sparsifiable_bytes(self.model.cfg.n_layers)
-            if self.sparse_ctx else 0.0
-        )
-        sim = self.simulator.measure_from_estimate(
-            est, name=f"admit[{slot}]", nbytes=nbytes,
-            shard_bytes=self._even_shard_bytes(nbytes),
-        )
-        self.stats.append(
-            StepStats("prefill", int(batch["tokens"].shape[1]), est, sim, 0.0, 0.0,
-                      nbytes=float(nbytes))
-        )
-        return last, sim
 
     def decode_slots(self, tokens: jnp.ndarray, n_tokens: int):
         """Fused decode round over all slots. ``tokens``: (batch, 1) current
@@ -936,6 +933,14 @@ class ServeEngine:
         Returns (new_tokens (batch, n), per-step charged latency (n,) —
         the overlapped-pipeline critical path by default, the serial
         Σ io + Σ compute charge with ``overlap=False``)."""
+        trace.count("engine.decode_calls")
+        with trace.span("engine.decode", n_tokens=n_tokens):
+            self._grow_pages(n_tokens)
+            return self._run_decode_scan(tokens, n_tokens)
+
+    def _grow_pages(self, n_tokens: int) -> None:
+        """Paged KV: cover each occupied slot's writes of the coming round
+        before the table rides the scan carry."""
         if self.kv_pool is not None and n_tokens > 0:
             # grow each occupied slot's page table to cover this round's
             # write positions [length, length + n_tokens) before the table
@@ -966,7 +971,6 @@ class ServeEngine:
                     grew = True
             if grew:
                 self.cache["page_table"] = self._push_table()
-        return self._run_decode_scan(tokens, n_tokens)
 
     def slot_lengths(self) -> np.ndarray:
         return np.asarray(self.cache["length"]).reshape(-1)
@@ -1134,7 +1138,6 @@ class ServeEngine:
         | ``miss_rows``          | selected rows streamed from flash                | PR 2  |
         | ``cache_hit_rate``     | hit_rows / (hit_rows + miss_rows), 0 when idle   | PR 2  |
         | ``io_bytes``           | Σ estimated flash→DRAM transfer volume (nbytes)  | PR 3  |
-        | ``select_overhead_s``  | Σ chunk-selection wall seconds (fig13 quantity)  | PR 3  |
         | ``decode_compute_s``   | Σ compute-lane seconds over decode steps         | PR 3  |
         | ``decode_serial_s``    | Σ serial Σio+Σcompute charge (decode steps)      | PR 3  |
         | ``decode_overlap_s``   | Σ prefetch-pipeline critical-path charge         | PR 3  |
@@ -1187,7 +1190,6 @@ class ServeEngine:
             "miss_rows": miss,
             "cache_hit_rate": hit / (hit + miss) if (hit + miss) > 0 else 0.0,
             "io_bytes": sum(s.nbytes for s in self.stats),
-            "select_overhead_s": sum(s.select_overhead_s for s in self.stats),
             # overlapped-pipeline rollup (decode steps)
             "decode_compute_s": sum(s.compute_s for s in dec),
             "decode_serial_s": serial,

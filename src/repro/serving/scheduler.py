@@ -47,6 +47,7 @@ from typing import Deque, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from .. import trace
 from .engine import ServeEngine
 from .kv_pool import KVPoolExhausted
 from .request import Request, RequestState
@@ -218,42 +219,51 @@ class Scheduler:
                         "prompt needs more KV pages than the pool can free"
                     )
                 break
-            last, prefill_sim = self.engine.admit_slot(slot, req.prompt)
-            prefill_sim = float(prefill_sim)
-            if self.admit_in_bubbles and self.stall_credit_s > 0.0:
-                hidden = min(self.stall_credit_s, prefill_sim)
-                self.stall_credit_s -= hidden
-                prefill_sim -= hidden
-                self.admitted_during_stall += 1
-                self.stall_hidden_s += hidden
-                self.engine.note_stall_admission(hidden)
-            self.now_s += prefill_sim
-            req.state = RequestState.RUNNING
-            req.slot = slot
-            req.admitted_s = self.now_s
-            self.running[slot] = req
-            tok0 = jnp.argmax(last, axis=-1)[:, None].astype(jnp.int32)
-            self._slot_tokens = self._slot_tokens.at[slot].set(tok0[0])
+            with trace.span("sched.admit", rid=req.rid, slot=slot):
+                self._admit(req, slot)
             admitted += 1
+        trace.count("sched.admitted", admitted)
         return admitted
 
+    def _admit(self, req: Request, slot: int) -> None:
+        last, prefill_sim = self.engine.admit_slot(slot, req.prompt)
+        prefill_sim = float(prefill_sim)
+        if self.admit_in_bubbles and self.stall_credit_s > 0.0:
+            hidden = min(self.stall_credit_s, prefill_sim)
+            self.stall_credit_s -= hidden
+            prefill_sim -= hidden
+            self.admitted_during_stall += 1
+            self.stall_hidden_s += hidden
+            self.engine.note_stall_admission(hidden)
+        self.now_s += prefill_sim
+        req.state = RequestState.RUNNING
+        req.slot = slot
+        req.admitted_s = self.now_s
+        self.running[slot] = req
+        tok0 = jnp.argmax(last, axis=-1)[:, None].astype(jnp.int32)
+        self._slot_tokens = self._slot_tokens.at[slot].set(tok0[0])
+
     def _evict(self, req: Request) -> None:
-        req.state = RequestState.FINISHED
-        if req.finished_s is None:
-            req.finished_s = self.now_s
-        # release the slot's KV storage through the engine's single release
-        # funnel (paged: page refs drop; dense: the slot length zeroes) so
-        # freed-byte accounting can't drift from what the pool actually holds
-        self.engine.release_slot(req.slot)
-        # reset the freed slot's decode input: a free slot keeps riding the
-        # fused scan, and its garbage activations feed the BATCHED chunk
-        # selection — leaving the dead occupant's last token here would make
-        # selection (and so every active slot's tokens) depend on KV-layout
-        # garbage that differs between the dense and paged caches
-        self._slot_tokens = self._slot_tokens.at[req.slot].set(0)
-        self.running[req.slot] = None
-        req.slot = None
-        self.finished.append(req)
+        trace.count("sched.evicted")
+        with trace.span("sched.evict", rid=req.rid, slot=req.slot):
+            req.state = RequestState.FINISHED
+            if req.finished_s is None:
+                req.finished_s = self.now_s
+            # release the slot's KV storage through the engine's single
+            # release funnel (paged: page refs drop; dense: the slot length
+            # zeroes) so freed-byte accounting can't drift from what the
+            # pool actually holds
+            self.engine.release_slot(req.slot)
+            # reset the freed slot's decode input: a free slot keeps riding
+            # the fused scan, and its garbage activations feed the BATCHED
+            # chunk selection — leaving the dead occupant's last token here
+            # would make selection (and so every active slot's tokens)
+            # depend on KV-layout garbage that differs between the dense
+            # and paged caches
+            self._slot_tokens = self._slot_tokens.at[req.slot].set(0)
+            self.running[req.slot] = None
+            req.slot = None
+            self.finished.append(req)
 
     def _requeue(self, req: Request) -> None:
         """Evict-and-requeue one RUNNING request (shared by deadline and
@@ -322,6 +332,11 @@ class Scheduler:
     def step(self) -> bool:
         """One scheduler iteration: admit, decode a round, reconcile.
         Returns False when there is nothing left to do."""
+        with trace.span("sched.step", running=self.num_running(),
+                        waiting=len(self.waiting)):
+            return self._step()
+
+    def _step(self) -> bool:
         # fast-forward an idle engine to the next arrival (requeued
         # preemptees can put the deque out of arrival order — scan it)
         if self.num_running() == 0:
@@ -360,6 +375,7 @@ class Scheduler:
                 for s in self.engine.stats[n_stats0:] if s.kind == "decode"
             )
         toks_np = np.asarray(toks)  # (slots, round_tokens)
+        delivered0 = self.decode_tokens
         active = [r for r in self.running if r is not None]
         for i, sim in enumerate(step_lat):
             # the batch shares each model step; clock advances once per step
@@ -375,6 +391,7 @@ class Scheduler:
                     # latency marks the token's mid-round time; the slot is
                     # only recycled at the round boundary below
                     req.finished_s = self.now_s
+        trace.count("sched.tokens_delivered", self.decode_tokens - delivered0)
         self._slot_tokens = toks[:, -1:]
         for req in list(active):
             if req.done:

@@ -33,7 +33,6 @@ and hit/miss accounting — see docs/serving.md for the lifecycle.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -552,6 +551,7 @@ class SparseExecution:
             return None, jnp.float32(site.dense_latency)
         return self._compute_mask(kind, site, acts)
 
+    @jax.named_scope("decode.importance")
     def record_importance(self, kind: str, acts: jnp.ndarray, plan):
         """Stash this site's current-step importance (selection row order)
         into the plan carry as the ``pending`` vector the NEXT refresh
@@ -577,6 +577,7 @@ class SparseExecution:
         new_plan[kind] = entry
         return new_plan
 
+    @jax.named_scope("decode.select")
     def refresh_layer(self, plan, refresh: jnp.ndarray, weights=None):
         """One batched refresh for ALL of a layer's sites — the planned
         decode path's replacement for per-site selection calls.
@@ -938,29 +939,6 @@ class SparseExecution:
             new_plan[kind] = entry
         return new_plan, lat
 
-    def time_selection(self, repeats: int = 5) -> float:
-        """Median wall-seconds of ONE layer's refresh-step selection
-        dispatch (compiled & warmed) — the same quantity
-        ``benchmarks/fig13_overhead.py`` measures per matrix, measured here
-        for the batched per-layer dispatch the serve engine actually runs.
-        The engine amortizes it into ``StepStats.select_overhead_s``."""
-        if self.method == "dense":
-            return 0.0
-        n_max = self.batched.n_max
-        v = jnp.abs(jnp.sin(jnp.arange(self.batched.n_sites * n_max, dtype=jnp.float32)))
-        vs = v.reshape(self.batched.n_sites, n_max)
-        if self.method == "topk":
-            fn = jax.jit(lambda x: jax.vmap(topk_mask)(x, self._budgets))
-        else:
-            fn = jax.jit(lambda x: self.batched.select(x, self._budgets)[0])
-        fn(vs).block_until_ready()  # compile + warm
-        walls = []
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            fn(vs).block_until_ready()
-            walls.append(time.perf_counter() - t0)
-        return float(np.median(walls))
-
     def _compute_mask(self, kind: str, site: _Site, acts: jnp.ndarray):
         from ..core.importance import importance
 
@@ -979,10 +957,11 @@ class SparseExecution:
                 ).astype(bool)
             v = jnp.where(cv, 0.0, v)  # resident weights cost no I/O
 
-        if self.method == "topk":
-            m = topk_mask(v, site.budget())
-        else:
-            m, _, _ = site.selector.select(v, site.budget())
+        with jax.named_scope("decode.select"):
+            if self.method == "topk":
+                m = topk_mask(v, site.budget())
+            else:
+                m, _, _ = site.selector.select(v, site.budget())
         lat = jnp.float32(0.0)
         for t in site.tables:
             lat += t.mask_latency(m)

@@ -4,8 +4,7 @@ The headline invariants (ISSUE 8):
 
   * faults disabled (default) ⇒ greedy tokens AND ``io_summary()`` are
     byte/bit-identical to an engine without the fault machinery, across
-    backends and wbits (``select_overhead_s`` is excluded everywhere — it
-    is wall-clock measured and differs even between two identical runs);
+    backends and wbits;
   * faults enabled ⇒ tokens are UNCHANGED (time-only perturbation), and a
     given (profile, fault_seed) replays bit-identically;
   * under a sustained thermal throttle with per-request deadlines the
@@ -64,15 +63,6 @@ def _engine(model, params, **kw):
     kw.setdefault("method", "chunk")
     return ServeEngine(model, params, max_seq=64, batch_size=2, device="nano",
                        sparsity=0.4, seed=1, **kw)
-
-
-def _sim_summary(eng):
-    """io_summary minus the wall-clock-measured selection-overhead lane
-    (run-to-run noisy by construction; everything else is simulated and
-    must be bit-identical where the tests say so)."""
-    s = eng.io_summary()
-    s.pop("select_overhead_s")
-    return s
 
 
 # -- ThermalTrajectory -------------------------------------------------------
@@ -449,7 +439,7 @@ def test_engine_fault_off_byte_identity(lm, backend, wbits):
                   fault_profile="none", fault_seed=123)
     t_off = off.decode(tok0, 5)
     np.testing.assert_array_equal(np.asarray(t_base), np.asarray(t_off))
-    assert _sim_summary(base) == _sim_summary(off)
+    assert base.io_summary() == off.io_summary()
     fs = off.fault_summary()
     assert not fs["fault_enabled"] and fs["fault_events"] == 0
 
@@ -460,11 +450,11 @@ def test_engine_faults_perturb_time_never_tokens(lm, backend, wbits):
     tok0 = jnp.ones((2, 1), jnp.int32)
     base = _engine(model, params, backend=backend, wbits=wbits)
     t_base = base.decode(tok0, 5)
-    s_base = _sim_summary(base)
+    s_base = base.io_summary()
     faulty = _engine(model, params, backend=backend, wbits=wbits,
                      fault_profile=HAMMER, fault_seed=3)
     t_faulty = faulty.decode(tok0, 5)
-    s_faulty = _sim_summary(faulty)
+    s_faulty = faulty.io_summary()
     np.testing.assert_array_equal(np.asarray(t_base), np.asarray(t_faulty))
     assert s_faulty["io_est_s"] == s_base["io_est_s"]  # planning unchanged
     assert s_faulty["io_bytes"] == s_base["io_bytes"]
@@ -479,11 +469,11 @@ def test_engine_fault_seed_deterministic(lm):
     for _ in range(2):
         e = _engine(model, params, fault_profile=HAMMER, fault_seed=3)
         e.decode(tok0, 5)
-        runs.append((_sim_summary(e), e.fault_summary()))
+        runs.append((e.io_summary(), e.fault_summary()))
     assert runs[0] == runs[1]
     other = _engine(model, params, fault_profile=HAMMER, fault_seed=4)
     other.decode(tok0, 5)
-    assert _sim_summary(other)["io_sim_s"] != runs[0][0]["io_sim_s"]
+    assert other.io_summary()["io_sim_s"] != runs[0][0]["io_sim_s"]
 
 
 def test_engine_degrade_clean_device_identity(lm):
@@ -497,7 +487,7 @@ def test_engine_degrade_clean_device_identity(lm):
     on = _engine(model, params, degrade=True)
     t_on = on.decode(tok0, 6)
     np.testing.assert_array_equal(np.asarray(t_base), np.asarray(t_on))
-    assert _sim_summary(base) == _sim_summary(on)
+    assert base.io_summary() == on.io_summary()
     assert on.fault_summary()["degrade_scale"] == 1.0
 
 
@@ -628,17 +618,17 @@ def test_fault_trajectory_sweep(lm, profile):
         # values by design) — baseline each (backend, wbits) combo
         base = _engine(model, params, backend=backend, wbits=wbits)
         t_base = np.asarray(base.decode(tok0, 6))
-        base_sim = _sim_summary(base)["io_sim_s"]
+        base_sim = base.io_summary()["io_sim_s"]
         for seed in (0, 1, 2):
             e = _engine(model, params, backend=backend, wbits=wbits,
                         fault_profile=profile, fault_seed=seed)
             t = np.asarray(e.decode(tok0, 6))
             np.testing.assert_array_equal(t_base, t)
             # faults can only add charged time, never remove it
-            assert _sim_summary(e)["io_sim_s"] >= base_sim - 1e-12
+            assert e.io_summary()["io_sim_s"] >= base_sim - 1e-12
             # exact replay
             e2 = _engine(model, params, backend=backend, wbits=wbits,
                          fault_profile=profile, fault_seed=seed)
             e2.decode(tok0, 6)
-            assert _sim_summary(e2) == _sim_summary(e)
+            assert e2.io_summary() == e.io_summary()
             assert e2.fault_summary() == e.fault_summary()

@@ -302,25 +302,6 @@ def test_engine_bytes_threaded_to_simulator(lm):
     assert sum(s.nbytes for s in eng_p.stats if s.kind == "decode") == decode_bytes
 
 
-def test_engine_select_overhead_populated(lm):
-    """Both decode paths report the fig13 quantity (selection seconds per
-    step): the per-token loop on refresh steps, the scan path amortized."""
-    cfg, model, params, batch = lm
-    eng = ServeEngine(model, params, max_seq=64, batch_size=2, device="nano",
-                      sparsity=0.4, method="chunk", seed=3,
-                      plan_refresh_interval=2)
-    tok0 = jnp.argmax(eng.prefill(batch), -1)[:, None].astype(jnp.int32)
-    eng.decode_per_token(tok0, 4)
-    per_tok = [s.select_overhead_s for s in eng.stats if s.kind == "decode"]
-    assert per_tok[0] > 0 and per_tok[2] > 0  # refresh steps timed
-    assert per_tok[1] == 0.0 and per_tok[3] == 0.0  # reuse steps free
-    eng.prefill(batch)
-    eng.decode(tok0, 4)
-    scan = [s.select_overhead_s for s in eng.stats if s.kind == "decode"][4:]
-    assert all(v > 0 for v in scan)  # amortized uniformly
-    np.testing.assert_allclose(sum(scan), sum(per_tok), rtol=1e-6)
-
-
 def test_engine_scan_and_per_token_pipeline_agree(lm):
     """With a deterministic simulator the two decode loops must agree on
     the pipeline charges, not just tokens and estimates."""

@@ -20,9 +20,6 @@ that matter for the perf trajectory are structural and deterministic:
     the ``chunk_gather_mlp_ref`` oracle on the plan actually produced by
     ``SparseExecution``'s batched refresh (tables routed straight from the
     plan carry, no host re-splitting).
-  * ``kernel/tile_d*`` — the single-site DMA matmul swept over the output
-    tile width (grid-step count vs VMEM slot budget; the ROADMAP's first
-    real-TPU perf knob), parity asserted at every width.
   * ``kernel/quant_*`` — the quantized chunk format (PR 6): int8 payloads
     + per-block scale lanes fetched through the same DMA slot rotation and
     dequantized in VMEM, parity-checked against the dequantized-weights
@@ -181,49 +178,9 @@ def run(rows: Rows, smoke: bool = False) -> None:
             assert err < 1e-5
             rows.add(f"kernel/matmul_dma_depth{depth}", 0.0, f"rel_err={err:.2e}")
 
-    bench_tile_sweep(rows, sparse, kstarts, ksizes, rng, batch, smoke=smoke)
     bench_quantized_gather(rows, sparse, kstarts, ksizes, rng, batch,
                            smoke=smoke)
     bench_decode_backends(rows, smoke=smoke)
-
-
-def bench_tile_sweep(rows: Rows, sparse, kstarts, ksizes, rng, batch: int,
-                     smoke: bool = False) -> None:
-    """``tile_d`` sweep of the single-site DMA matmul on the attn_out lane.
-
-    tile_d is the kernel's output-column block: each grid step DMA-gathers
-    one (block_rows × tile_d) weight tile, so a wider tile means fewer
-    grid steps and larger contiguous transfers but a bigger VMEM slot
-    budget ((prefetch_depth + 1) × block_rows × tile_d × dtype bytes per
-    streamed operand). On real TPU this is the first knob of the ROADMAP's
-    hardware perf pass; recorded here (interpret-mode wall, compiled &
-    warmed) so the trajectory has a baseline shape, with parity asserted at
-    every tile width (the schedule only re-tiles the same arithmetic)."""
-    order = list(sparse.site_order)
-    io_ = order.index("attn_out")
-    n_o = sparse.sites["attn_out"].n
-    d = sparse.cfg.d_model
-    w_o = jnp.asarray(rng.normal(0, 0.05, (n_o, d)), jnp.float32)
-    x_o = jnp.asarray(rng.normal(0, 1, (batch, n_o)), jnp.float32)
-    y0 = chunk_gather_matmul_ref(w_o, x_o, kstarts[io_], ksizes[io_])
-    scale = float(jnp.max(jnp.abs(y0))) + 1.0
-    tiles = [t for t in (32, 64, 128) if d % t == 0]
-    reps = 1 if smoke else 5
-    for tile in tiles:
-        y = sparse_matmul_dma(w_o, x_o, kstarts[io_], ksizes[io_],
-                              tile_d=tile)
-        y.block_until_ready()  # compile + warm
-        err = float(jnp.max(jnp.abs(y - y0))) / scale
-        assert err < 1e-5, f"tile_d={tile} diverged from oracle: {err}"
-        walls = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            sparse_matmul_dma(w_o, x_o, kstarts[io_], ksizes[io_],
-                              tile_d=tile,
-                              ).block_until_ready()
-            walls.append(time.perf_counter() - t0)
-        rows.add(f"kernel/tile_d{tile}", float(np.median(walls)) * 1e6,
-                 f"rel_err={err:.2e} grid_steps={d // tile} interpret=cpu")
 
 
 def bench_quantized_gather(rows: Rows, sparse, kstarts, ksizes, rng,

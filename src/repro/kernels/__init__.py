@@ -4,13 +4,13 @@ from .backend import (
     BACKENDS,
     ExecutionBackend,
     blocked_masked_matmul,
-    pick_tile,
     validate_backend,
 )
 from .chunk_gather_dma import (
     chunk_gather_matmul_dma,
     chunk_gather_mlp_dma,
     masks_to_block_tables,
+    pick_tile,
 )
 from .chunk_gather_matmul import align_chunk_table, chunk_gather_matmul
 from .chunk_gather_swiglu import chunk_gather_swiglu

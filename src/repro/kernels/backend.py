@@ -9,14 +9,16 @@ sparse projections through one of two implementations selected by
 ``ServeEngine(backend=...)`` / ``launch.serve --backend``:
 
   * ``reference`` (default) — pure-jnp masked matmuls, restructured as the
-    kernel's **schedule twin**: f32 accumulation over ``block_rows``-sized
-    row blocks in ascending order, the exact arithmetic the DMA kernel's
+    kernel's **schedule twin**: f32 accumulation over super-blocks (128
+    input rows at every real width, ``superblock_rows``) in ascending
+    order, each super-block's partial one ``Precision.HIGHEST`` contraction
+    over its rows — the exact arithmetic one step of the DMA kernel's
     slot-rotation loop performs (interpret mode executes the same jnp ops
-    per block). Blocks outside the chunk tables contribute exact zeros
-    (the input is pre-masked), so skipping them — as the kernel does — or
-    adding them changes nothing. Result: the two backends are **bitwise
-    identical**, and byte-identical decode tokens become the system's
-    strongest correctness invariant (tests/test_backend.py pins it).
+    per step). Super-blocks and blocks outside the chunk tables contribute
+    exact zeros (the input is pre-masked), so skipping them — as the kernel
+    does — or adding them changes nothing. Result: the two backends are
+    **bitwise identical**, and byte-identical decode tokens become the
+    system's strongest correctness invariant (tests/test_backend.py pins it).
   * ``kernel`` — the PR-4 Pallas kernels consume the decode plan's
     ``kstarts``/``ksizes``/``mlp_kernel_plan`` lanes directly:
     ``chunk_gather_mlp_dma`` replaces the masked dense SwiGLU (ONE dispatch
@@ -41,7 +43,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from .chunk_gather_dma import chunk_gather_matmul_dma, chunk_gather_mlp_dma
+from .chunk_gather_dma import (
+    chunk_gather_matmul_dma,
+    chunk_gather_mlp_dma,
+    chunk_lanes,
+    superblock_rows,
+)
 
 BACKENDS = ("reference", "kernel")
 
@@ -54,68 +61,53 @@ def validate_backend(name: str) -> str:
     return name
 
 
-def pick_tile(dim: int, cap: int = 128) -> int:
-    """Largest power-of-two tile ≤ ``cap`` dividing ``dim`` — the kernels
-    require output dims to split evenly into tiles (reduced-config d_ff
-    values like 704 need 64-wide tiles; full-size dims take the 128 MXU
-    lane width)."""
-    t = cap
-    while t >= 8:
-        if dim % t == 0:
-            return t
-        t //= 2
-    raise ValueError(
-        f"dim {dim} has no power-of-two tile divisor >= 8 — the kernel "
-        "backend needs dims divisible by 8"
-    )
-
-
 def blocked_masked_matmul(
     xm: jnp.ndarray,  # (B, N) pre-masked input, any float dtype
     w: jnp.ndarray,  # (N, D); int8 payload when scales is given
     block_rows: int = 8,
     scales: jnp.ndarray | None = None,  # (N // block_rows,) f32 per-block
 ) -> jnp.ndarray:
-    """The DMA gather kernel's schedule twin: y = Σ_blocks xm_blk @ w_blk in
-    ascending ``block_rows`` blocks, f32 accumulation — per output element
-    the identical multiply/add sequence the kernel's fori_loop performs, so
-    the result is bitwise equal to interpret-mode ``chunk_gather_matmul_dma``
-    on any chunk table covering the mask (uncovered blocks see zeroed xm
-    rows and contribute exact +0.0).
+    """The DMA gather kernel's schedule twin: y = Σ_sb xm_sb @ w_sb over
+    ascending super-blocks of ``superblock_rows(N)`` rows, f32
+    accumulation — per output element the identical multiply/add sequence
+    the kernel's step loop performs, so the result is bitwise equal to
+    interpret-mode ``chunk_gather_matmul_dma`` on any chunk table covering
+    the mask (uncovered rows see zeroed xm and contribute exact +0.0).
 
-    The per-block partial products are independent, so they run as ONE
-    batched einsum (each (B, block_rows) · (block_rows, D) contraction is
-    elementwise identical to the kernel's per-step dot); only the f32
-    additions — the order-sensitive part — stay sequential. That keeps the
-    decode hot path one fused matmul + nb cheap adds instead of nb
-    serialized dots (bitwise equality across both forms and the kernel is
-    pinned by tests/test_backend.py).
+    The partial products are independent, so they run as ONE batched
+    contraction over (super-block, column piece) pairs, each
+    (B, rows) · (rows, ``chunk_lanes(D)``) the shape of one of the
+    kernel's per-step dots (XLA:CPU rounds a dot differently at other
+    widths); only the f32 additions — the order-sensitive part — stay
+    sequential.
 
     With ``scales`` (the quantized chunk format): ``w`` is the int8 payload
-    and each block's f32 partial product ``xm_blk @ q_blk.astype(f32)`` is
-    multiplied by the block's scale inside the accumulation step,
-    ``acc + part * scale`` — the kernel's exact expression, so whatever the
-    compiler makes of it (XLA:CPU fuses it into one FMA) it makes of both,
-    keeping the twins bitwise equal at 8 bits.
+    and each input lane is multiplied by its block's scale before the
+    contraction, ``xm · scale_lanes`` — the kernel's exact expression, so
+    the twins stay bitwise equal at 8 bits.
 
     The contraction runs at ``Precision.HIGHEST``, as the kernel's does: on
     TPU the default f32 matmul precision is a single bf16 pass."""
     b, n = xm.shape
+    d = w.shape[1]
     if n % block_rows:
         raise ValueError(f"N={n} must be a multiple of block_rows={block_rows}")
-    nb = n // block_rows
-    xb = xm.astype(jnp.float32).reshape(b, nb, block_rows)
-    wb = w.astype(jnp.float32).reshape(nb, block_rows, w.shape[1])
-    parts = jnp.einsum("bkr,krd->kbd", xb, wb,
-                       preferred_element_type=jnp.float32,
-                       precision=jax.lax.Precision.HIGHEST)
-    sc = None if scales is None else scales.astype(jnp.float32)
-
-    def body(k, acc):
-        return acc + (parts[k] if sc is None else parts[k] * sc[k])
-
+    sb, c = superblock_rows(n, block_rows), chunk_lanes(d)
+    nsb, nc = n // sb, d // c
+    xm = xm.astype(jnp.float32)
+    if scales is not None:
+        xm = xm * jnp.repeat(scales.astype(jnp.float32), block_rows)[None, :]
+    # (super-block, piece) pairs as one batch dimension
+    xb = jnp.repeat(xm.reshape(b, nsb, sb).transpose(1, 0, 2), nc, axis=0)
+    wb = (w.astype(jnp.float32).reshape(nsb, sb, nc, c)
+          .transpose(0, 2, 1, 3).reshape(nsb * nc, sb, c))
+    parts = jax.lax.dot_general(
+        xb, wb, (((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    ).reshape(nsb, nc, b, c).transpose(0, 2, 1, 3).reshape(nsb, b, d)
     return jax.lax.fori_loop(
-        0, nb, body, jnp.zeros((b, w.shape[1]), jnp.float32)
+        0, nsb, lambda k, acc: acc + parts[k], jnp.zeros((b, d), jnp.float32)
     )
 
 
@@ -178,7 +170,6 @@ class ExecutionBackend:
     prefetch_depth: int = 1
     interpret: bool = True
     block_rows: int = 8
-    tile_cap: int = 128
     mesh: Optional[Mesh] = None
 
     @staticmethod
@@ -187,7 +178,6 @@ class ExecutionBackend:
         prefetch_depth: int = 1,
         interpret: Optional[bool] = None,
         block_rows: int = 8,
-        tile_cap: int = 128,
         mesh: Optional[Mesh] = None,
     ) -> "ExecutionBackend":
         validate_backend(name)
@@ -198,7 +188,6 @@ class ExecutionBackend:
             prefetch_depth=prefetch_depth,
             interpret=resolve_interpret(interpret),
             block_rows=block_rows,
-            tile_cap=tile_cap,
             mesh=mesh,
         )
 
@@ -244,8 +233,8 @@ class ExecutionBackend:
         EXACT mask for both backends, so the kernel's outward block rounding
         gathers only zeroed extra rows — masked-matmul semantics hold and
         the two implementations agree bitwise. With ``scales`` (8-bit chunk
-        storage) both backends scale each block's f32 partial product as
-        it accumulates, preserving the bitwise twin property. With
+        storage) both backends multiply each input lane by its block's
+        scale before the contraction, preserving the bitwise twin property. With
         ``checksums`` the kernel path carries each block's integrity word
         into SMEM with the call (verification happens at the selection
         boundary); the reference path — whose operands never leave device
@@ -257,7 +246,6 @@ class ExecutionBackend:
             kernel = functools.partial(
                 chunk_gather_matmul_dma,
                 block_rows=self.block_rows,
-                tile_d=pick_tile(w.shape[1], self.tile_cap),
                 prefetch_depth=self.prefetch_depth,
                 interpret=self.interpret,
             )
@@ -306,8 +294,6 @@ class ExecutionBackend:
             kernel = functools.partial(
                 chunk_gather_mlp_dma,
                 block_rows=self.block_rows,
-                tile_f=pick_tile(w_gate.shape[1], self.tile_cap),
-                tile_d=pick_tile(w_down.shape[1], self.tile_cap),
                 prefetch_depth=self.prefetch_depth,
                 interpret=self.interpret,
                 return_h=True,

@@ -1,4 +1,4 @@
-"""Explicitly double-buffered DMA chunk-gather kernels.
+"""Explicitly pipelined DMA chunk-gather kernels.
 
 The BlockSpec-driven kernels (chunk_gather_matmul.py / chunk_gather_swiglu.py)
 let the Pallas pipeline emitter fetch one HBM block per grid step — which the
@@ -10,14 +10,38 @@ classic in-kernel pipeline —
     warm-up:  start copies for steps 0 .. depth-1
     step k:   start copy k+depth  →  wait copy k  →  MXU on slot k % (depth+1)
 
-so chunk-block k+1's HBM→VMEM transfer is in flight while the MXU contracts
-block k (depth 1 = double buffering; depth 0 degenerates to fetch-then-compute
-serial, the baseline the overlap is benchmarked against). The steps are the
-blocks the chunk table covers, in ascending row order (``block_list``), so
-padded table entries cost nothing. This is the kernel
+so step k+1's HBM→VMEM transfer is in flight while the MXU contracts step k
+(depth 1 = double buffering; depth 0 degenerates to fetch-then-compute
+serial, the baseline the overlap is benchmarked against). This is the kernel
 realization of the host-side prefetch timeline in core/pipeline.py: the same
-``prefetch_depth`` knob, the same hidden-fetch discipline, so the model and
-the kernel agree on what is hidden.
+``prefetch_depth`` knob, the same hidden-fetch discipline.
+
+**The step unit is a super-block**: an aligned group of ``superblock_rows``
+input rows (128 at every real width — the MXU's contraction depth and the
+lane width; the largest power of two ≤ 128 dividing N for the reduced CPU
+configs) across the whole column tile. The steps are the super-blocks the
+chunk table covers, in ascending row order (``block_list``), so padded table
+entries and uncovered super-blocks cost nothing. One step:
+
+  * **DMAs** — a super-block whose ``block_rows``-row blocks are all covered
+    moves as ONE ``(superblock_rows, tile)`` copy per matrix (the common
+    case at moderate sparsity); otherwise each covered block moves as one
+    ``(block_rows, tile)`` copy into its rows of the slot. Uncovered blocks
+    are never fetched: the bytes the plan selects are the bytes moved.
+  * **Contraction** — one ``(B, superblock_rows) · (superblock_rows, tile)``
+    dot per matrix on the lane-aligned ``x[:, off:off + superblock_rows]``
+    slice (the down phase: h times the exact ffn mask), run in
+    ``chunk_lanes``-column pieces (512 at real widths), f32 at
+    ``Precision.HIGHEST``. The slice's lanes of uncovered blocks are zeroed
+    (``_covered_lanes``), and the slots are zeroed once at kernel start, so
+    the rows a partial super-block leaves stale (earlier super-blocks'
+    finite weights) meet exact zeros and never uninitialised VMEM.
+  * **Column tile** — the full output width unless VMEM forces a split:
+    the widest multiple of the contraction piece dividing the width whose
+    slots (every operand the step streams, all ``prefetch_depth + 1``
+    slots) fit ``VMEM_SLOT_BUDGET`` (``column_tile``). Derived from the
+    shapes, not an option; of the served models only InternVL2-76B's
+    28,672-wide bf16 gate/up split (in two).
 
 Two entry points:
 
@@ -26,8 +50,8 @@ Two entry points:
     (starts/sizes multiples of ``block_rows``, size 0 = padded entry).
   * ``chunk_gather_mlp_dma`` — the **fused multi-site** path: ONE
     ``pallas_call`` gathers gate, up AND down off the two MLP lanes of a
-    ``BatchedChunkSelector`` ``(n_sites, K)`` plan. A hidden-lane chunk
-    block is fetched once and contracted against both W_gate and W_up
+    ``BatchedChunkSelector`` ``(n_sites, K)`` plan. A hidden-lane
+    super-block is fetched once and contracted against both W_gate and W_up
     while resident, and the SwiGLU intermediate h stays in VMEM for the
     down-lane gather — no per-site re-dispatch, no h HBM round-trip.
 
@@ -39,10 +63,8 @@ on the chip.
 
 Layout rules Mosaic enforces, and how the kernels meet them:
 
-  * a vector load's lane offset must be a multiple of the 128-lane tile, so
-    the ``block_rows``-wide column block of ``x`` / ``h`` / the ffn mask is
-    read through its aligned lane window and rotated into place
-    (``_column_block``);
+  * a vector load's lane offset must be a multiple of the 128-lane tile:
+    super-block offsets are, and carry a ``pl.multiple_of`` hint;
   * a DMA's row offset must provably sit on the HBM tiling, so block
     offsets carry a ``pl.multiple_of(…, block_rows)`` hint;
   * a one-word DMA is below a 1-D HBM array's tiling, so the per-block
@@ -123,68 +145,224 @@ def masks_to_block_tables(
     return starts, sizes
 
 
+SUPERBLOCK_ROWS = 128  # the MXU's contraction depth and the lane width
+# VMEM the weight slots of one pipeline may hold: every operand a step
+# streams, all prefetch_depth + 1 slots. Wider matrices split their columns.
+VMEM_SLOT_BUDGET = 16 * 2**20
+_CHUNK_LANES = 512  # output columns per dot inside a step
+_VMEM_HEADROOM = 8 * 2**20  # Mosaic's own temporaries: f32 weight pieces, dots
+_VMEM_CAP = 100 * 2**20  # of the v5e's 128 MiB per core
+
+
+def pick_tile(dim: int, cap: int = SUPERBLOCK_ROWS) -> int:
+    """Largest power-of-two tile ≤ ``cap`` dividing ``dim`` — the
+    super-block height of a matrix with ``dim`` input rows: 128, the MXU's
+    contraction depth, at every real model width; reduced-config widths
+    like d_ff 704 take 64."""
+    t = cap
+    while t >= 8:
+        if dim % t == 0:
+            return t
+        t //= 2
+    raise ValueError(
+        f"dim {dim} has no power-of-two tile divisor >= 8 — the kernel "
+        "backend needs dims divisible by 8"
+    )
+
+
+def superblock_rows(n_rows: int, block_rows: int = 8) -> int:
+    """Rows per pipeline step of a matrix with ``n_rows`` input rows."""
+    sb = pick_tile(n_rows)
+    if sb % block_rows or block_rows & (block_rows - 1):
+        raise ValueError(
+            f"super-block of {sb} rows is not a whole number of "
+            f"power-of-two blocks of {block_rows} rows"
+        )
+    return sb
+
+
+def chunk_lanes(width: int) -> int:
+    """Columns per dot inside a step, for a matrix ``width`` wide: the
+    widest multiple of 128 up to ``_CHUNK_LANES`` dividing it, or the whole
+    width off the 128-lane grid. A property of the matrix, not of its
+    column tile, so the reference twin contracts the same pieces."""
+    for c in range(_CHUNK_LANES, 0, -128):
+        if width % c == 0:
+            return c
+    return width
+
+
+def column_tile(width: int, bytes_per_column: int) -> int:
+    """Columns per pipeline of a matrix ``width`` wide whose slots hold
+    ``bytes_per_column`` bytes per column: the full width when they fit
+    ``VMEM_SLOT_BUDGET``, else the widest multiple of its contraction piece
+    (``chunk_lanes``, a multiple of 128) dividing it that fits, one piece
+    at least. A width off the 128-lane grid — only the reduced CPU
+    configs, interpreted — is never split."""
+    chunk = chunk_lanes(width)
+    for t in range(width, chunk, -chunk):
+        if width % t == 0 and t * bytes_per_column <= VMEM_SLOT_BUDGET:
+            return t
+    return chunk
+
+
+def _rows8(b: int) -> int:
+    return -(-b // 8) * 8
+
+
+def _vmem_limit(nbytes: int) -> int:
+    return min(nbytes + _VMEM_HEADROOM, _VMEM_CAP)
+
+
+# ---------------------------------------------------------------------------
+# the step list and what it moves
+# ---------------------------------------------------------------------------
+
+
+def _block_coverage(starts, sizes, n_rows: int, block_rows: int):
+    """(n_rows // block_rows,) bool: the blocks the chunk table covers, by a
+    difference array (+1 where a chunk starts, -1 past its end; a size-0
+    entry cancels itself)."""
+    nb = n_rows // block_rows
+    b0 = jnp.clip(starts // block_rows, 0, nb)
+    b1 = jnp.clip((starts + sizes) // block_rows, 0, nb)
+    diff = jnp.zeros((nb + 1,), jnp.int32).at[b0].add(1).at[b1].add(-1)
+    return jnp.cumsum(diff[:nb]) > 0
+
+
+@functools.partial(jax.jit, static_argnames=("n_rows", "block_rows", "sb_rows"))
+def block_list(starts, sizes, n_rows: int, sb_rows: int, block_rows: int = 8):
+    """Chunk table → (offsets, bits, count): the row offsets of the
+    ``sb_rows``-row super-blocks the table covers, ascending and each once,
+    padded to ``n_rows // sb_rows`` entries; beside each, its covered
+    ``block_rows`` blocks as a bitmask (bit j: rows ``off + j·block_rows``
+    on); and how many entries are real (a (1,) int32). The kernels'
+    pipelines walk this list, so a padded table entry costs no loop step,
+    and super-blocks accumulate in the ascending order of the reference
+    twin whatever the table's order."""
+    per, n_sb = sb_rows // block_rows, n_rows // sb_rows
+    cov = _block_coverage(starts, sizes, n_rows, block_rows).reshape(n_sb, per)
+    bits = jnp.sum(cov.astype(jnp.int32) << jnp.arange(per, dtype=jnp.int32),
+                   axis=1, dtype=jnp.int32)
+    real = bits != 0
+    rank = jnp.where(real, jnp.cumsum(real) - 1, n_sb)
+
+    def compact(v):
+        return jnp.zeros((n_sb + 1,), jnp.int32).at[rank].set(v)[:n_sb]
+
+    offs = compact(jnp.arange(n_sb, dtype=jnp.int32) * sb_rows)
+    return offs, compact(bits), jnp.sum(real, dtype=jnp.int32)[None]
+
+
+@functools.partial(jax.jit, static_argnames=("n_rows", "block_rows"))
+def superblock_counts(starts, sizes, n_rows: int, block_rows: int = 8):
+    """What one walk of the table by a kernel moves per matrix, (3,) int32:
+    super-blocks moved whole (one DMA each), super-blocks moved by blocks,
+    and the ``block_rows``-row DMAs those take. ``per × whole + block
+    DMAs`` is the number of covered blocks (``per`` = blocks per
+    super-block, 16 at real widths): no unselected block is read."""
+    per = superblock_rows(n_rows, block_rows) // block_rows
+    n_cov = jnp.sum(
+        _block_coverage(starts, sizes, n_rows, block_rows).reshape(-1, per),
+        axis=1, dtype=jnp.int32)
+    whole = n_cov == per
+    partial = (n_cov > 0) & ~whole
+    return jnp.stack([jnp.sum(whole, dtype=jnp.int32),
+                      jnp.sum(partial, dtype=jnp.int32),
+                      jnp.sum(jnp.where(partial, n_cov, 0), dtype=jnp.int32)])
+
+
 # ---------------------------------------------------------------------------
 # the slot-rotation pipeline (shared by both kernels)
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("n_rows", "block_rows"))
-def block_list(starts, sizes, n_rows: int, block_rows: int = 8):
-    """Chunk table → (offsets, count): the row offsets of the ``block_rows``
-    blocks the table covers, ascending and each once, padded to
-    ``n_rows // block_rows`` entries, and how many of them are real (a
-    (1,) int32). The kernels' pipelines walk this list, so a padded table
-    entry (size 0) costs no loop step, and blocks accumulate in the
-    ascending order of the reference twin whatever the table's order."""
-    nb = n_rows // block_rows
-    b0 = jnp.clip(starts // block_rows, 0, nb)
-    b1 = jnp.clip((starts + sizes) // block_rows, 0, nb)
-    # coverage by a difference array: +1 where a chunk starts, -1 past its
-    # end (a size-0 entry cancels itself)
-    diff = jnp.zeros((nb + 1,), jnp.int32).at[b0].add(1).at[b1].add(-1)
-    covered = jnp.cumsum(diff[:nb]) > 0
-    rank = jnp.where(covered, jnp.cumsum(covered) - 1, nb)
-    offs = (jnp.zeros((nb + 1,), jnp.int32)
-            .at[rank].set(jnp.arange(nb, dtype=jnp.int32) * block_rows))
-    return offs[:nb], jnp.sum(covered, dtype=jnp.int32)[None]
+def _superblock_copies(streams, off, bits, slot, col, width: int,
+                       sb_rows: int, block_rows: int, wait: bool):
+    """Start — or, with ``wait``, wait on — one step's copies into slot
+    ``slot`` of each stream ``(hbm ref, slots, semaphores)``: the
+    super-block at row ``off`` as ONE ``(sb_rows, width)`` copy when every
+    block is covered (``bits`` all ones), else one ``(block_rows, width)``
+    copy per covered block into the same rows of the slot. A slot's copies
+    all signal its semaphore; the wait side rebuilds the same descriptors,
+    so each wait matches one copy."""
+    per = sb_rows // block_rows
+
+    def copy(r0: int, rows: int):
+        src = pl.ds(pl.multiple_of(off + r0, block_rows), rows)
+        for hbm, slots, sems in streams:
+            c = pltpu.make_async_copy(hbm.at[src, pl.ds(col, width)],
+                                      slots.at[slot, pl.ds(r0, rows)],
+                                      sems.at[slot])
+            c.wait() if wait else c.start()
+
+    whole = bits == (1 << per) - 1
+    pl.when(whole)(functools.partial(copy, 0, sb_rows))
+
+    @pl.when(jnp.logical_not(whole))
+    def _():
+        for j in range(per):
+            pl.when(((bits >> j) & 1) == 1)(
+                functools.partial(copy, j * block_rows, block_rows))
 
 
-def _lane_window(n: int, block_rows: int, cap: int = 128) -> int:
-    """Width of the aligned lane window a ``block_rows``-wide column block
-    is read through: the largest power of two ≤ ``cap`` dividing ``n``
-    (128, the lane width, at every real model width; smaller only for the
-    reduced CPU configs, which run in interpret mode)."""
-    w = cap
-    while n % w:
-        w //= 2
-    if w < block_rows:
-        raise ValueError(f"dim {n} has no lane window >= block_rows={block_rows}")
-    return w
+def _lane_blocks(sb_rows: int, block_rows: int):
+    """(1, sb_rows) int32: each lane's block within the super-block."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, sb_rows), 1)
+    return lanes >> (block_rows.bit_length() - 1)
 
 
-def _column_block(ref, off, block_rows: int, window: int):
-    """``ref[:, off:off + block_rows]`` for an ``off`` that is only
-    ``block_rows``-aligned. A TPU vector load needs a lane offset aligned to
-    the 128-lane tile, so load the aligned window holding the block, rotate
-    the block down to lane 0 and slice it statically — pure data movement,
-    bit-exact."""
-    base = pl.multiple_of((off // window) * window, window)
-    v = ref[:, pl.ds(base, window)]
-    v = pltpu.roll(v, (window - (off - base)) % window, axis=1)
-    return v[:, :block_rows]
+def _covered_lanes(bits, lane_blk):
+    return ((bits >> lane_blk) & 1) == 1
 
 
-def _block_dot(xb, wb):
-    """One block's contribution: (B, block_rows) · (block_rows, T) in f32 at
-    full f32 precision — the reference twin's einsum asks for the same (on
-    the v5e both compile to the same bits, checked on the chip)."""
+def _lane_scales(s_ref, blk0, lane_blk, per: int):
+    """(1, sb_rows) f32: each lane's block scale, read from SMEM."""
+    v = jnp.zeros(lane_blk.shape, jnp.float32)
+    for j in range(per):
+        v = jnp.where(lane_blk == j, s_ref[blk0 + j], v)
+    return v
+
+
+def _dot(xb, wb):
+    """(B, rows) · (rows, T) in f32 at full f32 precision — the reference
+    twin's contraction asks for the same."""
     return jnp.dot(xb, wb, preferred_element_type=jnp.float32,
                    precision=jax.lax.Precision.HIGHEST)
 
 
+def _contract(terms, slot, width: int, chunk: int, acc_col=0):
+    """``acc[:, acc_col + c] += xb · slots[slot][:, c]`` for each
+    ``(xb, slots, acc)`` of ``terms``, over the slot's ``width`` columns in
+    ``chunk``-column pieces."""
+
+    def body(c, carry):
+        lo = pl.multiple_of(c * chunk, chunk)
+        dst = pl.ds(pl.multiple_of(acc_col + lo, chunk), chunk)
+        for xb, slots, acc in terms:
+            acc[:, dst] += _dot(xb, slots[slot, :, pl.ds(lo, chunk)].astype(jnp.float32))
+        return carry
+
+    jax.lax.fori_loop(0, width // chunk, body, None)
+
+
+def _zero_slots(slots, chunk: int):
+    """Zero every slot, ``chunk`` columns at a time: a partially covered
+    super-block leaves rows of its slot unfetched, and zeroed ``x`` lanes
+    meet them; ``0 · garbage`` could be NaN, ``0 · weight`` is 0."""
+    n_slots, rows, width = slots.shape
+
+    def body(c, carry):
+        lo = pl.multiple_of(c * chunk, chunk)
+        slots[:, :, pl.ds(lo, chunk)] = jnp.zeros((n_slots, rows, chunk), slots.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, width // chunk, body, None)
+
+
 def _pipelined_steps(total, n_slots, start_copy, wait_and_compute):
-    """Run the slot-rotation schedule over ``total`` (traced) block steps:
-    start copies ``n_slots - 1`` steps ahead (prefetch_depth = n_slots - 1),
+    """Run the slot-rotation schedule over ``total`` (traced) steps: start
+    copies ``n_slots - 1`` steps ahead (prefetch_depth = n_slots - 1),
     wait + compute in order. With n_slots == 1 (depth 0) each step starts
     its own copy then immediately waits on it — the serial baseline
     schedule."""
@@ -195,7 +373,7 @@ def _pipelined_steps(total, n_slots, start_copy, wait_and_compute):
         def _():
             start_copy(jnp.int32(s), s % n_slots)
 
-    def body(step, _):
+    def body(step, carry):
         nxt = step + depth
 
         @pl.when(nxt < total)
@@ -203,7 +381,7 @@ def _pipelined_steps(total, n_slots, start_copy, wait_and_compute):
             start_copy(nxt, nxt % n_slots)
 
         wait_and_compute(step, step % n_slots)
-        return _
+        return carry
 
     jax.lax.fori_loop(0, total, body, None)
 
@@ -214,58 +392,56 @@ def _pipelined_steps(total, n_slots, start_copy, wait_and_compute):
 
 
 def _matmul_dma_kernel(
-    offs_ref,  # scalar prefetch (N // block_rows,): covered block row offsets
+    offs_ref,  # scalar prefetch (N // sb_rows,): covered super-block offsets
+    bits_ref,  # scalar prefetch (N // sb_rows,): their covered blocks
     count_ref,  # scalar prefetch (1,): how many of them are real
     x_ref,  # (B, N) f32 VMEM
     w_hbm,  # (N, D) ANY/HBM — fetched by explicit DMA only
     *rest,  # [s_smem,] [c_smem,] out_ref, wslots, sems
     block_rows: int,
+    sb_rows: int,
     tile_d: int,
+    chunk: int,
     n_slots: int,
-    window: int,
     quantized: bool,
     checksummed: bool,
 ):
     s_smem = rest[0] if quantized else None
     out_ref, wslots, sems = rest[quantized + checksummed:]
     col = pl.multiple_of(pl.program_id(0) * tile_d, tile_d)
+    lane_blk = _lane_blocks(sb_rows, block_rows)
+    streams = ((w_hbm, wslots, sems),)
 
-    def offset(step):
-        # table entries are block multiples by contract; Mosaic must see
-        # that to tile the block_rows-row DMA
-        return pl.multiple_of(offs_ref[step], block_rows)
+    def step_args(step):
+        return pl.multiple_of(offs_ref[step], sb_rows), bits_ref[step]
 
-    def copy(off, slot):
-        return pltpu.make_async_copy(
-            w_hbm.at[pl.ds(off, block_rows), pl.ds(col, tile_d)],
-            wslots.at[slot],
-            sems.at[slot],
-        )
-
-    def start_copy(step, slot):
-        copy(offset(step), slot).start()
+    def start_copy(step, slot, wait=False):
+        off, bits = step_args(step)
+        _superblock_copies(streams, off, bits, slot, col, tile_d, sb_rows,
+                           block_rows, wait)
 
     def wait_and_compute(step, slot):
-        off = offset(step)
-        copy(off, slot).wait()
-        xb = _column_block(x_ref, off, block_rows, window)
-        part = _block_dot(xb, wslots[slot].astype(jnp.float32))
+        start_copy(step, slot, wait=True)
+        off, bits = step_args(step)
+        xb = jnp.where(_covered_lanes(bits, lane_blk),
+                       x_ref[:, pl.ds(off, sb_rows)], 0.0)
         if quantized:
-            # dequantize after the contraction: one f32 multiply per output
-            # element by the block's scale, the same order the reference
-            # twin uses, so the two stay bitwise equal
-            part = part * s_smem[off // block_rows]
-        out_ref[...] += part
+            # dequantize on the input side: each lane carries its block's
+            # scale, the reference twin's exact expression
+            xb = xb * _lane_scales(s_smem, off // block_rows, lane_blk,
+                                   sb_rows // block_rows)
+        _contract(((xb, wslots, out_ref),), slot, tile_d, chunk)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        _zero_slots(wslots, chunk)
 
     out_ref[...] = jnp.zeros_like(out_ref)
     _pipelined_steps(count_ref[0], n_slots, start_copy, wait_and_compute)
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=(
-        "block_rows", "tile_d", "prefetch_depth", "interpret"
-    ),
+    jax.jit, static_argnames=("block_rows", "prefetch_depth", "interpret")
 )
 def chunk_gather_matmul_dma(
     w: jnp.ndarray,  # (N, D); int8 payload when scales is given
@@ -276,36 +452,32 @@ def chunk_gather_matmul_dma(
     checksums: jnp.ndarray | None = None,  # (N // block_rows,) u32 per-block
     *,
     block_rows: int = 8,
-    tile_d: int = 128,
     prefetch_depth: int = 1,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """y (B, D) f32 = Σ_chunks x_chunk @ W_chunk, fetched by an explicitly
-    ``prefetch_depth``-deep double-buffered DMA pipeline over the table's
-    covered blocks (``block_list``), in ascending row order. Numerically
+    ``prefetch_depth``-deep DMA pipeline over the table's covered
+    super-blocks (``block_list``), in ascending row order. Numerically
     identical at every depth (the schedule only re-times the same fetches) —
-    matches ``chunk_gather_matmul_ref`` exactly like the BlockSpec kernel.
+    matches ``chunk_gather_matmul_ref`` like the BlockSpec kernel, and
+    ``blocked_masked_matmul`` on an input masked to the table bitwise.
 
     With ``scales`` (the quantized chunk format, ``kernels/quantize.py``):
     ``w`` is the int8 payload, the per-block f32 scales sit in SMEM for the
-    whole call, and each block's f32 partial product is multiplied by its
-    scale before accumulation — matching
+    whole call, and each input lane is multiplied by its block's scale
+    before the contraction — matching
     ``blocked_masked_matmul(..., scales=...)`` bitwise.
 
     With ``checksums`` (``kernels/quantize.block_checksums`` /
     ``core/offload.pack_checksums``): the per-block uint32 integrity words
     travel into SMEM with the call, next to the payload they cover. They
-    are not verified here — a checksum covers the full (block_rows, D)
-    storage block while the kernel fetches (block_rows, tile_d) tiles; the
-    verification happens at the selection boundary
-    (``serving/sparse_exec.refresh_layer``), identically on both backends.
-    Output is bit-identical with and without the lane."""
+    are not verified here; the verification happens at the selection
+    boundary (``serving/sparse_exec.refresh_layer``), identically on both
+    backends. Output is bit-identical with and without the lane."""
     n, d = w.shape
     b = x.shape[0]
     if prefetch_depth < 0:
         raise ValueError(f"prefetch_depth must be >= 0, got {prefetch_depth}")
-    if d % tile_d:
-        raise ValueError(f"D={d} must be a multiple of tile_d={tile_d}")
     if n % block_rows:
         raise ValueError(f"N={n} must be a multiple of block_rows={block_rows}")
     quantized = scales is not None
@@ -319,13 +491,16 @@ def chunk_gather_matmul_dma(
             f"checksums must be ({n // block_rows},), got {checksums.shape}"
         )
     n_slots = prefetch_depth + 1
+    sb = superblock_rows(n, block_rows)
+    slot_col = n_slots * sb * jnp.dtype(w.dtype).itemsize
+    tile_d = column_tile(d, slot_col)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [
         pl.BlockSpec((b, n), lambda dj, *_: (0, 0)),  # x resident in VMEM
         pl.BlockSpec(memory_space=pl.ANY),  # w stays in HBM; DMA'd manually
     ]
-    offs, count = block_list(starts, sizes, n, block_rows)
-    operands = [offs, count, x.astype(jnp.float32), w]
+    offs, bits, count = block_list(starts, sizes, n, sb, block_rows)
+    operands = [offs, bits, count, x.astype(jnp.float32), w]
     if quantized:
         in_specs.append(smem)
         operands.append(scales.astype(jnp.float32))
@@ -333,28 +508,33 @@ def chunk_gather_matmul_dma(
         in_specs.append(smem)
         operands.append(checksums.astype(jnp.uint32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(d // tile_d,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((b, tile_d), lambda dj, *_: (0, dj)),
         scratch_shapes=[
-            pltpu.VMEM((n_slots, block_rows, tile_d), w.dtype),
+            pltpu.VMEM((n_slots, sb, tile_d), w.dtype),
             pltpu.SemaphoreType.DMA((n_slots,)),
         ],
     )
+    # x and the output block are double-buffered by the grid pipeline
+    vmem = slot_col * tile_d + 2 * 4 * _rows8(b) * (n + tile_d)
     return pl.pallas_call(
         functools.partial(
             _matmul_dma_kernel,
             block_rows=block_rows,
+            sb_rows=sb,
             tile_d=tile_d,
+            chunk=chunk_lanes(d),
             n_slots=n_slots,
-            window=_lane_window(n, block_rows),
             quantized=quantized,
             checksummed=checksummed,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit(vmem)),
         interpret=interpret,
     )(*operands)
 
@@ -365,7 +545,8 @@ def chunk_gather_matmul_dma(
 
 
 def _mlp_dma_kernel(
-    offs_ref,  # scalar prefetch (2, nb): block lists, 0 = hidden_mlp, 1 = ffn
+    offs_ref,  # scalar prefetch (2, L): super-block lists, 0 = hidden_mlp, 1 = ffn
+    bits_ref,  # scalar prefetch (2, L): their covered blocks
     count_ref,  # scalar prefetch (2,): real entries per lane
     x_ref,  # (B, N) f32 VMEM
     wg_hbm,  # (N, F) ANY
@@ -374,112 +555,106 @@ def _mlp_dma_kernel(
     fmask_ref,  # (1, F) VMEM f32 — exact ffn row mask (all-ones = table only)
     *rest,  # [sg, su, sd SMEM,] [cg, cu, cd SMEM,] out_ref, h, slots, accs, sems
     block_rows: int,
+    sb_x: int,
+    sb_h: int,
     tile_f: int,
     tile_d: int,
+    chunk_f: int,
+    chunk_d: int,
     n_slots: int,
     n_f_tiles: int,
     n_d_tiles: int,
-    x_window: int,
-    h_window: int,
     quantized: bool,
     checksummed: bool,
 ):
     sg, su, sd = rest[:3] if quantized else (None, None, None)
     (out_ref, h_ref, gslots, uslots, dslots, acc_g, acc_u,
      sems_g, sems_u, sems_d) = rest[3 * (quantized + checksummed):]
+    lane_x = _lane_blocks(sb_x, block_rows)
+    lane_h = _lane_blocks(sb_h, block_rows)
 
-    def offset(lane, step):
-        return pl.multiple_of(offs_ref[lane, step], block_rows)
+    def step_args(lane, step, sb):
+        return pl.multiple_of(offs_ref[lane, step], sb), bits_ref[lane, step]
+
+    _zero_slots(gslots, chunk_f)
+    _zero_slots(uslots, chunk_f)
+    _zero_slots(dslots, chunk_d)
 
     # -- phase 1: gate/up over the hidden lane, one f-tile at a time --------
-    def gate_up_tile(fj):
+    def gate_up_tile(fj, carry):
         col = pl.multiple_of(fj * tile_f, tile_f)
+        # one super-block, fetched once, feeds BOTH gate and up
+        streams = ((wg_hbm, gslots, sems_g), (wu_hbm, uslots, sems_u))
 
-        def copies(off, slot):
-            # one chunk block, fetched once, feeds BOTH gate and up
-            rows, cols = pl.ds(off, block_rows), pl.ds(col, tile_f)
-            return (
-                pltpu.make_async_copy(
-                    wg_hbm.at[rows, cols], gslots.at[slot], sems_g.at[slot]),
-                pltpu.make_async_copy(
-                    wu_hbm.at[rows, cols], uslots.at[slot], sems_u.at[slot]),
-            )
-
-        def start_copy(step, slot):
-            for c in copies(offset(0, step), slot):
-                c.start()
+        def start_copy(step, slot, wait=False):
+            off, bits = step_args(0, step, sb_x)
+            _superblock_copies(streams, off, bits, slot, col, tile_f, sb_x,
+                               block_rows, wait)
 
         def wait_and_compute(step, slot):
-            off = offset(0, step)
-            for c in copies(off, slot):
-                c.wait()
-            xb = _column_block(x_ref, off, block_rows, x_window)
-            g = _block_dot(xb, gslots[slot].astype(jnp.float32))
-            u = _block_dot(xb, uslots[slot].astype(jnp.float32))
+            start_copy(step, slot, wait=True)
+            off, bits = step_args(0, step, sb_x)
+            xg = xu = jnp.where(_covered_lanes(bits, lane_x),
+                                x_ref[:, pl.ds(off, sb_x)], 0.0)
             if quantized:
-                bk = off // block_rows
-                g = g * sg[bk]
-                u = u * su[bk]
-            acc_g[...] += g
-            acc_u[...] += u
+                blk0, per = off // block_rows, sb_x // block_rows
+                xg = xg * _lane_scales(sg, blk0, lane_x, per)
+                xu = xu * _lane_scales(su, blk0, lane_x, per)
+            _contract(((xg, gslots, acc_g), (xu, uslots, acc_u)), slot,
+                      tile_f, chunk_f)
 
         acc_g[...] = jnp.zeros_like(acc_g)
         acc_u[...] = jnp.zeros_like(acc_u)
         _pipelined_steps(count_ref[0], n_slots, start_copy, wait_and_compute)
-        g = acc_g[...]
-        h_ref[:, pl.ds(col, tile_f)] = g * (1.0 / (1.0 + jnp.exp(-g))) * acc_u[...]
 
-    def f_body(fj, _):
-        gate_up_tile(fj)
-        return _
+        def swiglu(c, carry):
+            lo = pl.multiple_of(c * chunk_f, chunk_f)
+            g = acc_g[:, pl.ds(lo, chunk_f)]
+            h_ref[:, pl.ds(pl.multiple_of(col + lo, chunk_f), chunk_f)] = (
+                g * (1.0 / (1.0 + jnp.exp(-g))) * acc_u[:, pl.ds(lo, chunk_f)])
+            return carry
 
-    jax.lax.fori_loop(0, n_f_tiles, f_body, None)
+        jax.lax.fori_loop(0, tile_f // chunk_f, swiglu, None)
+        return carry
+
+    jax.lax.fori_loop(0, n_f_tiles, gate_up_tile, None)
 
     # -- phase 2: down over the ffn lane, consuming h straight from VMEM ----
-    def down_tile(dj):
+    def down_tile(dj, carry):
         col = pl.multiple_of(dj * tile_d, tile_d)
+        streams = ((wd_hbm, dslots, sems_d),)
 
-        def copy(off, slot):
-            return pltpu.make_async_copy(
-                wd_hbm.at[pl.ds(off, block_rows), pl.ds(col, tile_d)],
-                dslots.at[slot],
-                sems_d.at[slot],
-            )
-
-        def start_copy(step, slot):
-            copy(offset(1, step), slot).start()
+        def start_copy(step, slot, wait=False):
+            off, bits = step_args(1, step, sb_h)
+            _superblock_copies(streams, off, bits, slot, col, tile_d, sb_h,
+                               block_rows, wait)
 
         def wait_and_compute(step, slot):
-            off = offset(1, step)
-            copy(off, slot).wait()
+            start_copy(step, slot, wait=True)
+            off, bits = step_args(1, step, sb_h)
+            rows = pl.ds(off, sb_h)
             # the exact ffn mask applies at the gather, NOT to the h output:
-            # block-rounding may pull in rows outside the selected mask, and
+            # block-rounding pulls in rows outside the selected mask, and
             # those must contribute zero for the kernel to equal the
             # masked-matmul reference exactly
-            hb = _column_block(h_ref, off, block_rows, h_window)
-            hb = hb * _column_block(fmask_ref, off, block_rows, h_window)
-            part = _block_dot(hb, dslots[slot].astype(jnp.float32))
+            hb = jnp.where(_covered_lanes(bits, lane_h),
+                           h_ref[:, rows] * fmask_ref[:, rows], 0.0)
             if quantized:
-                part = part * sd[off // block_rows]
-            out_ref[:, pl.ds(col, tile_d)] += part
+                hb = hb * _lane_scales(sd, off // block_rows, lane_h,
+                                       sb_h // block_rows)
+            _contract(((hb, dslots, out_ref),), slot, tile_d, chunk_d,
+                      acc_col=col)
 
         _pipelined_steps(count_ref[1], n_slots, start_copy, wait_and_compute)
+        return carry
 
     out_ref[...] = jnp.zeros_like(out_ref)
-
-    def d_body(dj, _):
-        down_tile(dj)
-        return _
-
-    jax.lax.fori_loop(0, n_d_tiles, d_body, None)
+    jax.lax.fori_loop(0, n_d_tiles, down_tile, None)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "block_rows", "tile_f", "tile_d", "prefetch_depth", "interpret",
-        "return_h",
-    ),
+    static_argnames=("block_rows", "prefetch_depth", "interpret", "return_h"),
 )
 def chunk_gather_mlp_dma(
     w_gate: jnp.ndarray,  # (N, F); int8 payloads when scales is given
@@ -493,8 +668,6 @@ def chunk_gather_mlp_dma(
     checksums: tuple | None = None,  # (cg (N//br,), cu (N//br,), cd (F//br,)) u32
     *,
     block_rows: int = 8,
-    tile_f: int = 128,
-    tile_d: int = 128,
     prefetch_depth: int = 1,
     interpret: bool = False,
     return_h: bool = False,
@@ -523,8 +696,8 @@ def chunk_gather_mlp_dma(
 
     With ``scales = (sg, su, sd)`` the three weights are int8 payloads of
     the quantized chunk format; the scale lanes sit in SMEM for the call
-    and each block's f32 partial product is multiplied by its scale before
-    accumulation (bitwise equal to the reference backend's quantized
+    and each input lane is multiplied by its block's scale before the
+    contraction (bitwise equal to the reference backend's quantized
     schedule twin).
 
     With ``checksums = (cg, cu, cd)`` the three lanes' per-block uint32
@@ -544,7 +717,7 @@ def chunk_gather_mlp_dma(
         )
     if prefetch_depth < 0:
         raise ValueError(f"prefetch_depth must be >= 0, got {prefetch_depth}")
-    if f % tile_f or d % tile_d or n % block_rows or f % block_rows:
+    if n % block_rows or f % block_rows:
         raise ValueError("alignment violation")
     if ffn_mask is None:
         fmask = jnp.ones((1, f), jnp.float32)
@@ -577,6 +750,10 @@ def chunk_gather_mlp_dma(
                 f"down checksums must be ({f // block_rows},), got {cd.shape}"
             )
     n_slots = prefetch_depth + 1
+    sb_x, sb_h = superblock_rows(n, block_rows), superblock_rows(f, block_rows)
+    gu_col = 2 * n_slots * sb_x * jnp.dtype(w_gate.dtype).itemsize
+    d_col = n_slots * sb_h * jnp.dtype(w_down.dtype).itemsize
+    tile_f, tile_d = column_tile(f, gu_col), column_tile(d, d_col)
     # h (B, F) occupies the same positional kernel-ref slot either way:
     # second OUTPUT when the caller wants it, first SCRATCH when not (so a
     # return_h=False dispatch never writes the intermediate back to HBM)
@@ -594,12 +771,14 @@ def chunk_gather_mlp_dma(
         pl.BlockSpec(memory_space=pl.ANY),  # w_down
         vmem,  # ffn mask
     ]
-    nb = max(n, f) // block_rows
-    lanes = [block_list(starts[i], sizes[i], m, block_rows)
-             for i, m in enumerate((n, f))]
-    offs = jnp.stack([jnp.pad(o, (0, nb - o.shape[0])) for o, _ in lanes])
-    count = jnp.concatenate([c for _, c in lanes])
-    operands = [offs, count, x.astype(jnp.float32), w_gate, w_up, w_down, fmask]
+    lanes = [block_list(starts[i], sizes[i], m, sb, block_rows)
+             for i, (m, sb) in enumerate(((n, sb_x), (f, sb_h)))]
+    n_sb = max(n // sb_x, f // sb_h)
+    offs, bits = (jnp.stack([jnp.pad(lane[k], (0, n_sb - lane[k].shape[0]))
+                             for lane in lanes]) for k in (0, 1))
+    count = jnp.concatenate([lane[2] for lane in lanes])
+    operands = [offs, bits, count, x.astype(jnp.float32), w_gate, w_up, w_down,
+                fmask]
     if quantized:
         in_specs += [smem] * 3  # scales lanes
         operands += [s.astype(jnp.float32) for s in (sg, su, sd)]
@@ -607,14 +786,14 @@ def chunk_gather_mlp_dma(
         in_specs += [smem] * 3  # checksum lanes
         operands += [c.astype(jnp.uint32) for c in (cg, cu, cd)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=h_scratch + [
-            pltpu.VMEM((n_slots, block_rows, tile_f), w_gate.dtype),
-            pltpu.VMEM((n_slots, block_rows, tile_f), w_up.dtype),
-            pltpu.VMEM((n_slots, block_rows, tile_d), w_down.dtype),
+            pltpu.VMEM((n_slots, sb_x, tile_f), w_gate.dtype),
+            pltpu.VMEM((n_slots, sb_x, tile_f), w_up.dtype),
+            pltpu.VMEM((n_slots, sb_h, tile_d), w_down.dtype),
             pltpu.VMEM((b, tile_f), jnp.float32),
             pltpu.VMEM((b, tile_f), jnp.float32),
             pltpu.SemaphoreType.DMA((n_slots,)),
@@ -622,21 +801,28 @@ def chunk_gather_mlp_dma(
             pltpu.SemaphoreType.DMA((n_slots,)),
         ],
     )
+    # slots; x, h, the two accumulators and y; the (1, F) mask pads to 8 rows
+    vmem_bytes = (gu_col * tile_f + d_col * tile_d
+                  + 4 * _rows8(b) * (n + f + 2 * tile_f + d) + 4 * 8 * f)
     return pl.pallas_call(
         functools.partial(
             _mlp_dma_kernel,
             block_rows=block_rows,
+            sb_x=sb_x,
+            sb_h=sb_h,
             tile_f=tile_f,
             tile_d=tile_d,
+            chunk_f=chunk_lanes(f),
+            chunk_d=chunk_lanes(d),
             n_slots=n_slots,
             n_f_tiles=f // tile_f,
             n_d_tiles=d // tile_d,
-            x_window=_lane_window(n, block_rows),
-            h_window=_lane_window(f, block_rows),
             quantized=quantized,
             checksummed=checksummed,
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(vmem_bytes)),
         interpret=interpret,
     )(*operands)

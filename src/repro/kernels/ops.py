@@ -73,12 +73,11 @@ def sparse_matmul_dma(
     sizes: jnp.ndarray,
     *,
     block_rows: int = 8,
-    tile_d: int = 128,
     prefetch_depth: int = 1,
 ) -> jnp.ndarray:
     """``sparse_matmul`` through the explicitly double-buffered DMA kernel:
-    ``prefetch_depth + 1`` VMEM slots rotate so chunk-block k+1 streams from
-    HBM while the MXU contracts block k. Interpret mode off-TPU validates
+    ``prefetch_depth + 1`` VMEM slots rotate so super-block k+1 streams from
+    HBM while the MXU contracts super-block k. Interpret mode off-TPU validates
     the identical slot-rotation schedule synchronously."""
     return chunk_gather_matmul_dma(
         w,
@@ -86,7 +85,6 @@ def sparse_matmul_dma(
         starts,
         sizes,
         block_rows=block_rows,
-        tile_d=tile_d,
         prefetch_depth=prefetch_depth,
         interpret=resolve_interpret(),
     )
@@ -102,8 +100,6 @@ def sparse_mlp_fused(
     ffn_mask: Optional[jnp.ndarray] = None,
     *,
     block_rows: int = 8,
-    tile_f: int = 128,
-    tile_d: int = 128,
     prefetch_depth: int = 1,
     return_h: bool = False,
 ) -> jnp.ndarray:
@@ -121,8 +117,6 @@ def sparse_mlp_fused(
         sizes,
         ffn_mask,
         block_rows=block_rows,
-        tile_f=tile_f,
-        tile_d=tile_d,
         prefetch_depth=prefetch_depth,
         interpret=resolve_interpret(),
         return_h=return_h,

@@ -81,6 +81,7 @@ from .sparse_exec import (
     SparseExecution,
     plan_hit_miss,
     plan_integrity_counters,
+    plan_kernel_counters,
     plan_transfer_bytes,
     reset_plan_counters,
     set_plan_budget_scale,
@@ -111,6 +112,13 @@ class StepStats:
     overlap_s: float = 0.0
     stall_s: float = 0.0
     bubble_s: float = 0.0
+
+
+# the trace counters (repro.trace) of the gather kernels' DMA plans, in
+# KERNEL_COUNTER_KEYS order: per matrix walk of a site's block table,
+# super-blocks moved whole, super-blocks moved by blocks, block DMAs
+KERNEL_TRACE_COUNTERS = ("kernel.superblocks_whole",
+                         "kernel.superblocks_partial", "kernel.block_dmas")
 
 
 # the exact key set io_summary() returns — the docstring table and
@@ -478,7 +486,8 @@ class ServeEngine:
             # corruption off — see INTEGRITY_COUNTER_KEYS)
             dci = (plan_integrity_counters(new_plan)
                    - plan_integrity_counters(plan))
-            return logits, cache, io, new_plan, h1 - h0, m1 - m0, db, dsb, dci
+            dk = plan_kernel_counters(new_plan) - plan_kernel_counters(plan)
+            return logits, cache, io, new_plan, h1 - h0, m1 - m0, db, dsb, dci, dk
 
         self._decode_one = jax.jit(_decode_one_impl)
         self._append = jax.jit(
@@ -517,13 +526,17 @@ class ServeEngine:
 
         Returns (tokens (b, n), final cache, final plan, io (n, n_layers),
         hits (n,), misses (n,), bytes (n,), shard_bytes (n, n_shards),
-        integrity (n, 6)) — per-step per-layer I/O estimates plus
-        residency-cache row/byte counters, per-model-shard byte splits and
-        integrity-counter deltas (INTEGRITY_COUNTER_KEYS order; zeros with
-        corruption off) ride along. Everything stays on device until the
-        caller syncs once.
+        integrity (n, 6), kernel DMAs (n, 3)) — per-step per-layer I/O
+        estimates plus residency-cache row/byte counters, per-model-shard
+        byte splits, integrity-counter deltas (INTEGRITY_COUNTER_KEYS order;
+        zeros with corruption off) and the gather kernels' DMA-plan deltas
+        (KERNEL_COUNTER_KEYS order; zeros on the reference backend) ride
+        along. Everything stays on device until the
+        caller syncs once. The plan's counters are zeroed here, inside the
+        program: an eager reset costs one host dispatch per counter lane.
         """
         k = self.plan_refresh_interval
+        plan = reset_plan_counters(plan)
 
         def step(carry, i):
             tok, cache, plan = carry
@@ -537,18 +550,19 @@ class ServeEngine:
             dsb = self._plan_shard_bytes(new_plan) - self._plan_shard_bytes(plan)
             dci = (plan_integrity_counters(new_plan)
                    - plan_integrity_counters(plan))
+            dk = plan_kernel_counters(new_plan) - plan_kernel_counters(plan)
             with jax.named_scope("decode.sample"):
                 nxt = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
             return (nxt, cache, new_plan), (
-                nxt[:, 0], io, h1 - h0, m1 - m0, db, dsb, dci
+                nxt[:, 0], io, h1 - h0, m1 - m0, db, dsb, dci, dk
             )
 
         (_, cache, plan), (toks, ios, hits, misses, byts, sbyts,
-                           civ) = jax.lax.scan(
+                           civ, kdma) = jax.lax.scan(
             step, (token, cache, plan), jnp.arange(n_tokens)
         )
         # toks: (n, b) -> (b, n)
-        return toks.T, cache, plan, ios, hits, misses, byts, sbyts, civ
+        return toks.T, cache, plan, ios, hits, misses, byts, sbyts, civ, kdma
 
     def _dense_step_bytes(self) -> float:
         """Per-decode-step transfer volume of the dense streaming policy
@@ -566,7 +580,6 @@ class ServeEngine:
         ``engine.decode.account`` for the host-side accounting after it."""
         if self._plan is None:
             self._plan = self._init_plan()
-        self._plan = reset_plan_counters(self._plan)
         if self.degrade_controller is not None:
             # the controller acts only at decode-call boundaries: write its
             # current budget scale into the plan's traced "bscale" leaf so
@@ -578,18 +591,19 @@ class ServeEngine:
         tokens = self.mesh.put_batch(tokens)
         t0 = time.perf_counter()
         (toks, self.cache, self._plan, ios, hits, misses, byts,
-         sbyts, civ) = self._decode_scan(
+         sbyts, civ, kdma) = self._decode_scan(
             self.params, tokens, self.cache, n_tokens, self._plan
         )
         # ONE blocking host transfer for the whole scan (per-layer estimates
-        # + residency/integrity counters), after the tokens and cache are
-        # ready so the wall below covers the whole decode
+        # + residency/integrity/kernel counters), after the tokens and cache
+        # are ready so the wall below covers the whole decode
         with trace.span("engine.decode.wait"):
             jax.block_until_ready((toks, self.cache))
-            ios, hits, misses, byts, sbyts, civ = jax.device_get(
-                (ios, hits, misses, byts, sbyts, civ)
+            ios, hits, misses, byts, sbyts, civ, kdma = jax.device_get(
+                (ios, hits, misses, byts, sbyts, civ, kdma)
             )
         with trace.span("engine.decode.account"):
+            self._count_kernel_dmas(kdma)
             ios = np.asarray(ios, np.float64)  # (n, n_layers)
             hits, misses = np.asarray(hits, np.float64), np.asarray(misses, np.float64)
             byts = np.asarray(byts, np.float64)
@@ -629,6 +643,16 @@ class ServeEngine:
             self._observe_degradation(io_steps, sims)
             self._observe_corruption(float(civ[:, 0].sum()), float(misses.sum()))
         return toks, (tl.overlap_s if self.overlap else tl.serial_s)
+
+    @staticmethod
+    def _count_kernel_dmas(kdma) -> None:
+        """Add a decode call's kernel DMA-plan deltas ((..., 3), ordered
+        like KERNEL_COUNTER_KEYS, already on the host) to the trace's
+        counters; nothing on the reference backend, whose plans carry none."""
+        total = np.asarray(kdma, np.float64).reshape(-1, 3).sum(axis=0)
+        if total.any():
+            for name, v in zip(KERNEL_TRACE_COUNTERS, total):
+                trace.count(name, int(round(v)))
 
     def _decode_lift(self) -> float:
         """The deterministic lift decode measurements carry
@@ -715,10 +739,11 @@ class ServeEngine:
         for i in range(n_tokens):
             t0 = time.perf_counter()
             (logits, self.cache, io_vec, self._plan, dh, dm, db,
-             dsb, dci) = self._decode_one(
+             dsb, dci, dk) = self._decode_one(
                 self.params, token, self.cache, self._plan, jnp.int32(i)
             )
             io_vec = np.asarray(io_vec, np.float64)  # the per-token host sync
+            self._count_kernel_dmas(dk)
             dci = np.asarray(dci, np.float64)
             self._integrity_totals += dci
             det_call += float(dci[0])

@@ -43,8 +43,8 @@ from ..configs.base import ModelConfig
 from ..core.baselines import topk_mask
 from ..core.chunking import BatchedChunkSelector, ChunkConfig, ChunkSelector
 from ..core.faults import CorruptionModel, CorruptionProfile, corruption_key
-from ..kernels.backend import ExecutionBackend, pick_tile
-from ..kernels.chunk_gather_dma import masks_to_block_tables
+from ..kernels.backend import ExecutionBackend
+from ..kernels.chunk_gather_dma import masks_to_block_tables, pick_tile, superblock_counts
 from ..kernels.quantize import block_checksums
 from ..core.latency_model import (
     DeviceProfile,
@@ -198,19 +198,39 @@ def plan_integrity_counters(plan) -> jnp.ndarray:
     return out
 
 
+# per-(layer, site) lanes of what the gather kernels' DMA plans move,
+# carried by kernel-backend plans and added every decode step from the
+# site's block table (``superblock_counts``, per matrix the table feeds):
+# super-blocks moved whole (one DMA), super-blocks moved by blocks, and the
+# block DMAs those take
+KERNEL_COUNTER_KEYS = ("sb_whole", "sb_partial", "block_dmas")
+
+
+def plan_kernel_counters(plan) -> jnp.ndarray:
+    """Totals of the kernel DMA-plan lanes in a decode plan pytree, (3,)
+    float32 ordered like ``KERNEL_COUNTER_KEYS``; zeros when the plan
+    carries none (reference backend). jit-safe."""
+    out = jnp.zeros((len(KERNEL_COUNTER_KEYS),), jnp.float32)
+    for state in (plan or {}).values():
+        if isinstance(state, dict) and KERNEL_COUNTER_KEYS[0] in state:
+            out = out + jnp.stack([jnp.sum(state[k]) for k in KERNEL_COUNTER_KEYS])
+    return out
+
+
 def reset_plan_counters(plan):
-    """Zero the hit/miss/bytes (and integrity-counter) accumulators of a
-    decode plan state. Called by the engine at the start of each decode
-    invocation so the float32 counters only ever accumulate one call's
-    rows — exact far beyond any realistic n_tokens."""
+    """Zero the hit/miss/bytes (and integrity and kernel-counter)
+    accumulators of a decode plan state. Run at the start of each engine
+    decode invocation (inside the fused scan's program) so the float32
+    counters only ever accumulate one call's rows — exact far beyond any
+    realistic n_tokens. jit-safe."""
     if not plan:
         return plan
     out = {}
     for kind, state in plan.items():
         if isinstance(state, dict):
             state = dict(state)
-            for key in ("hit", "miss", "bytes", "hit_shard",
-                        "miss_shard") + INTEGRITY_COUNTER_KEYS:
+            for key in (("hit", "miss", "bytes", "hit_shard", "miss_shard")
+                        + INTEGRITY_COUNTER_KEYS + KERNEL_COUNTER_KEYS):
                 if key in state:
                     state[key] = jnp.zeros_like(state[key])
         out[kind] = state
@@ -928,6 +948,13 @@ class SparseExecution:
                 )
             entry["kstarts"] = results[kind]["kstarts"]
             entry["ksizes"] = results[kind]["ksizes"]
+            if KERNEL_COUNTER_KEYS[0] in entry:
+                # the kernels walk the tables every step, refreshed or reused
+                counts = superblock_counts(
+                    entry["kstarts"], entry["ksizes"], self.sites[kind].n,
+                    KERNEL_BLOCK_ROWS).astype(jnp.float32)
+                for j, key in enumerate(KERNEL_COUNTER_KEYS):
+                    entry[key] = plan[kind][key] + counts[j]
             if cache:
                 entry["score"] = results[kind]["score"]
             if integ:
@@ -1057,6 +1084,10 @@ class SparseExecution:
         set is its top cap_rows); pre-warmed ``cached`` rows start at
         PIN_SCORE.
 
+        With the kernel backend every site also carries the three
+        ``KERNEL_COUNTER_KEYS`` (L,) accumulators of what the gather
+        kernels' DMA plans move (zeroed per call like hit/miss).
+
         With corruption injection on, every site also carries the
         integrity lanes: "lid" (L,) layer ids + "epoch" (L,) refresh
         counters (the corruption key schedule's traced inputs), the six
@@ -1096,6 +1127,9 @@ class SparseExecution:
                 if pinned is not None:
                     score0 = jnp.where(pinned[None, :], PIN_SCORE, score0)
                 entry["score"] = score0
+            if self.backend.is_kernel:
+                for key in KERNEL_COUNTER_KEYS:
+                    entry[key] = jnp.zeros((n_layers,), jnp.float32)
             if self.degradable:
                 # the DegradationController's traced budget multiplier —
                 # rewritten between decode calls by set_plan_budget_scale,

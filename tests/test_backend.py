@@ -24,9 +24,12 @@ from repro.kernels import (
     quantize_rows,
     validate_backend,
 )
+from repro import trace
 from repro.models import build_model
 from repro.models.inputs import make_dummy_batch
 from repro.serving import ServeEngine, SparseExecution
+from repro.serving.engine import KERNEL_TRACE_COUNTERS
+from repro.serving.sparse_exec import plan_kernel_counters, reset_plan_counters
 
 
 def _backends():
@@ -80,6 +83,47 @@ def test_swiglu_mlp_bitwise_parity_and_h():
     # (importance recording must see them), while y charges only masked rows
     off = ~np.asarray(ffn)
     assert float(jnp.max(jnp.abs(np.asarray(h_ref)[:, off]))) > 0.0
+
+
+def _superblock_mask(n, whole, partial):
+    """Whole 128-row super-blocks, and one row of each listed 8-row block
+    of the partial ones."""
+    mask = np.zeros(n, bool)
+    for k in whole:
+        mask[k * 128:(k + 1) * 128] = True
+    for k, blocks in partial.items():
+        for j in blocks:
+            mask[k * 128 + j * 8 + 5] = True
+    return mask
+
+
+@pytest.mark.parametrize("depth,wbits", [(0, 16), (1, 16), (2, 16), (1, 8)])
+def test_swiglu_mlp_superblock_steps_bitwise(depth, wbits):
+    """The fused MLP kernel's super-block steps — whole super-blocks as one
+    DMA, then a partial one fetched block by block into a slot holding an
+    earlier super-block's rows — against the twin's super-block
+    accumulations, through both backends: y and h bitwise."""
+    rng = np.random.default_rng(7)
+    n, f, d, b = 512, 384, 256, 3
+    hidden = _superblock_mask(n, [0, 1, 2], {3: [1, 6, 15]})
+    ffn = _superblock_mask(f, [1], {0: [4], 2: list(range(1, 16))})
+    ws = [jnp.asarray(rng.normal(0, 0.1, shape), jnp.bfloat16)
+          for shape in ((n, f), (n, f), (f, d))]
+    scales = None
+    if wbits == 8:
+        ws, scales = zip(*(quantize_rows(w) for w in ws))
+    x = jnp.asarray(rng.normal(0, 1.0, (b, n)), jnp.bfloat16)
+    masks = np.zeros((2, n), bool)
+    masks[0], masks[1, :f] = hidden, ffn
+    starts, sizes = masks_to_block_tables(jnp.asarray(masks))
+    ref = ExecutionBackend.create("reference")
+    ker = ExecutionBackend.create("kernel", prefetch_depth=depth, interpret=True)
+    args = (*ws, x, jnp.asarray(hidden), jnp.asarray(ffn), starts, sizes, scales)
+    y_ref, h_ref = ref.swiglu_mlp(*args)
+    y_ker, h_ker = ker.swiglu_mlp(*args)
+    assert bool(jnp.all(h_ref == h_ker))
+    assert bool(jnp.all(y_ref == y_ker))
+    assert float(jnp.max(jnp.abs(y_ref))) > 0.0
 
 
 @pytest.mark.parametrize("name", ["reference", "kernel"])
@@ -257,10 +301,24 @@ def _decode(model, params, batch, backend, n=6, **kw):
     return eng, out
 
 
-def test_decode_tokens_byte_identical_across_backends(vlm):
+@pytest.fixture(scope="module")
+def decoded(vlm):
+    """Each backend's engine and tokens after one decode, and what its
+    decode added to the trace's kernel DMA counters."""
     cfg, model, params, batch = vlm
-    eng_r, out_r = _decode(model, params, batch, "reference")
-    eng_k, out_k = _decode(model, params, batch, "kernel")
+    runs = {}
+    for backend in ("reference", "kernel"):
+        before = trace.counters()
+        eng, out = _decode(model, params, batch, backend)
+        after = trace.counters()
+        runs[backend] = (eng, out, [after.get(k, 0) - before.get(k, 0)
+                                    for k in KERNEL_TRACE_COUNTERS])
+    return runs
+
+
+def test_decode_tokens_byte_identical_across_backends(decoded):
+    eng_r, out_r, _ = decoded["reference"]
+    eng_k, out_k, _ = decoded["kernel"]
     assert bool(jnp.all(out_r == out_k)), (
         "kernel-backend decode diverged from the reference backend"
     )
@@ -269,6 +327,24 @@ def test_decode_tokens_byte_identical_across_backends(vlm):
     sr, sk = eng_r.io_summary(), eng_k.io_summary()
     assert sr["io_est_s"] == pytest.approx(sk["io_est_s"], rel=0, abs=0)
     assert sr["miss_rows"] == sk["miss_rows"]
+
+
+def test_kernel_dma_counters_reach_the_trace(decoded):
+    """The kernel backend's plan counts what the gather kernels' DMA plans
+    move — super-blocks whole, super-blocks by blocks, block DMAs — and the
+    decode call's one read-back adds exactly the plan's totals to the
+    trace; the reference backend's plan carries no such lanes."""
+    eng_k, _, counted = decoded["kernel"]
+    eng_r, _, counted_ref = decoded["reference"]
+    assert counted_ref == [0, 0, 0]
+    assert float(jnp.sum(plan_kernel_counters(eng_r._plan))) == 0.0
+    whole, partial, dmas = counted
+    assert whole + partial > 0
+    assert partial <= dmas  # each partial super-block takes 1+ block DMAs
+    np.testing.assert_array_equal(np.asarray(plan_kernel_counters(eng_k._plan)),
+                                  np.asarray(counted, np.float32))
+    zeroed = reset_plan_counters(eng_k._plan)
+    assert float(jnp.sum(plan_kernel_counters(zeroed))) == 0.0
 
 
 def test_decode_tokens_byte_identical_at_wbits8(vlm):

@@ -2,6 +2,8 @@
 (kernels/chunk_gather_dma.py) against the kernels/ref.py oracles, the
 jit-safe batched-plan → kernel-table bridge, and the serve-stack wiring
 (prefetch depth byte-identity, plan-routed fused MLP)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -353,21 +355,26 @@ def test_masks_to_block_tables_empty_and_full():
     assert int((kz[1] > 0).sum()) == n // 32
 
 
+@pytest.mark.parametrize("sb", [8, 128])
 @pytest.mark.parametrize("density", [0.0, 0.3, 1.0])
-def test_block_list_is_the_ascending_covered_blocks(density, rng):
-    """The kernels' step list: every block the table covers, ascending and
-    once, padded; the count says how many are real."""
+def test_block_list_is_the_ascending_covered_blocks(density, sb, rng):
+    """The kernels' step list: every super-block the table covers,
+    ascending and once, padded, with its covered 8-row blocks as bits; the
+    count says how many are real. 8-row super-blocks list single blocks."""
     from repro.kernels.chunk_gather_dma import block_list
 
     n = 256
     mask = rng.random(n) < density
     ks, kz = masks_to_block_tables(jnp.asarray(mask)[None], 8, 32)
-    offs, count = block_list(ks[0], kz[0], n, 8)
-    cov = np.asarray(chunk_table_to_mask(ks[0], kz[0], n)).reshape(-1, 8)
-    want = np.flatnonzero(cov.any(axis=1)) * 8
-    assert offs.shape == (n // 8,) and count.shape == (1,)
+    offs, bits, count = block_list(ks[0], kz[0], n, sb, 8)
+    cov = np.asarray(chunk_table_to_mask(ks[0], kz[0], n)).reshape(-1, sb // 8, 8)
+    blocks = cov.any(axis=2)  # (n // sb, blocks per super-block)
+    want = np.flatnonzero(blocks.any(axis=1))
+    want_bits = (blocks[want] * (1 << np.arange(sb // 8))).sum(axis=1)
+    assert offs.shape == bits.shape == (n // sb,) and count.shape == (1,)
     assert int(count[0]) == len(want)
-    np.testing.assert_array_equal(np.asarray(offs)[: len(want)], want)
+    np.testing.assert_array_equal(np.asarray(offs)[: len(want)], want * sb)
+    np.testing.assert_array_equal(np.asarray(bits)[: len(want)], want_bits)
 
 
 @pytest.mark.parametrize("depth", DEPTHS)
@@ -385,6 +392,184 @@ def test_matmul_dma_unsorted_table_matches_twin_bitwise(depth, rng):
                                 interpret=True)
     y_twin = blocked_masked_matmul(x * cov, w, 8)
     assert bool(jnp.all(y == y_twin))
+
+
+# ---------------------------------------------------------------------------
+# super-block steps: the kernels against the reference twin, bitwise
+# ---------------------------------------------------------------------------
+
+
+def _sb_mask(n, sb, whole, partial):
+    """Row mask over ``n`` rows: the super-blocks in ``whole`` selected
+    entirely; in super-block k of ``partial``, one row of each listed
+    8-row block (so the table covers the block, the exact mask one row)."""
+    mask = np.zeros(n, bool)
+    for k in whole:
+        mask[k * sb:(k + 1) * sb] = True
+    for k, blocks in partial.items():
+        for j in blocks:
+            mask[k * sb + j * 8 + 3] = True
+    return mask
+
+
+# (n, d, whole super-blocks, {super-block: covered blocks}); super-blocks
+# 0-2 whole then 3 partial leaves the partial step's slot holding an
+# earlier super-block's rows at depth 0 (slot 0), 1 and 2
+SB_CASES = {
+    "partial_after_whole": (512, 256, [0, 1, 2], {3: [1, 6, 15]}),
+    "whole": (256, 128, [0, 1], {}),
+    "partial_only": (384, 128, [], {0: [0], 2: list(range(15))}),
+    "reduced_704": (704, 192, [0, 4, 9], {10: [2, 7]}),  # 64-row super-blocks
+}
+
+
+def _tables(mask):
+    ks, kz = masks_to_block_tables(jnp.asarray(mask)[None], 8, 64)
+    return ks[0], kz[0], chunk_table_to_mask(ks[0], kz[0], len(mask)).astype(jnp.float32)
+
+
+def _mlp_lanes(mask_h, mask_f):
+    """The (2, K) hidden/ffn plan lanes of two masks, and each coverage."""
+    width = max(len(mask_h), len(mask_f))
+    masks = np.zeros((2, width), bool)
+    masks[0, :len(mask_h)], masks[1, :len(mask_f)] = mask_h, mask_f
+    s2, z2 = masks_to_block_tables(jnp.asarray(masks), 8, 64)
+    covs = [chunk_table_to_mask(s2[i], z2[i], len(m)).astype(jnp.float32)
+            for i, m in enumerate((mask_h, mask_f))]
+    return s2, z2, covs
+
+
+@pytest.mark.parametrize(
+    "depth,wbits", [(d, 16) for d in DEPTHS] + [(1, 8)])
+@pytest.mark.parametrize("case", sorted(SB_CASES))
+def test_superblock_matmul_matches_twin_bitwise(case, depth, wbits, rng):
+    """One step per covered super-block — whole ones as one DMA, partial
+    ones block by block into a slot that still holds an earlier
+    super-block's rows — equals the twin's super-block accumulation
+    bitwise. The input is NOT pre-masked: the kernel must zero the lanes of
+    the rows it did not fetch itself."""
+    from repro.kernels.chunk_gather_dma import superblock_rows
+
+    n, d, whole, partial = SB_CASES[case]
+    sb = superblock_rows(n)
+    assert sb == (64 if n == 704 else 128)
+    ks, kz, cov = _tables(_sb_mask(n, sb, whole, partial))
+    w = jnp.asarray(rng.normal(0, 0.5, (n, d)), jnp.bfloat16)
+    x = jnp.asarray(rng.normal(0, 1, (3, n)), jnp.float32)
+    s = None
+    if wbits == 8:
+        w, s = quantize_rows(w.astype(jnp.float32), 8)
+    y = chunk_gather_matmul_dma(w, x, ks, kz, s, prefetch_depth=depth,
+                                interpret=True)
+    y_twin = blocked_masked_matmul(x * cov, w, 8, s)
+    assert bool(jnp.all(y == y_twin))
+    assert float(jnp.max(jnp.abs(y))) > 0.0
+
+
+def _mlp_twin(wg, wu, wd, x, cov_h, fm, scales=(None, None, None)):
+    sg, su, sd = scales
+    xm = x * cov_h
+    g = blocked_masked_matmul(xm, wg, 8, sg)
+    u = blocked_masked_matmul(xm, wu, 8, su)
+    h = g * (1.0 / (1.0 + jnp.exp(-g))) * u
+    return blocked_masked_matmul(h * fm[None, :], wd, 8, sd), h
+
+
+def _pallas_grids(jaxpr):
+    """The grids of every pallas_call in ``jaxpr`` and its sub-jaxprs."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(eqn.params["grid_mapping"].grid)
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                grids += _pallas_grids(sub)
+    return grids
+
+
+@pytest.mark.parametrize("depth", DEPTHS)
+def test_column_tiled_kernels_match_twin_bitwise(depth, rng, monkeypatch):
+    """Where the slots of a full-width step would overrun the VMEM budget
+    (patched small here, as InternVL2's 28,672-wide gate/up does on the
+    chip) the column tile narrows to a multiple of 128 dividing the width;
+    the arithmetic per output column is unchanged."""
+    from repro.kernels import chunk_gather_dma as cgd
+
+    n, f, d = 256, 2048, 3072
+    n_slots = depth + 1
+    # bf16 slots hold 2 * 128 * n_slots bytes per column per matrix: room
+    # for 1024 columns of gate + up, 2048 of down (or of one projection);
+    # tiles are whole 512-column contraction pieces
+    monkeypatch.setattr(cgd, "VMEM_SLOT_BUDGET", 2 * 256 * n_slots * 1024)
+    assert cgd.column_tile(f, 2 * 256 * n_slots) == 1024
+    assert cgd.column_tile(d, 256 * n_slots) == 1536
+    mask_h = _sb_mask(n, 128, [0], {1: [0, 9]})
+    mask_f = _sb_mask(f, 128, [0, 3, 4, 5, 6], {9: [2, 3], 15: [0]})
+    s2, z2, (cov_h, cov_f) = _mlp_lanes(mask_h, mask_f)
+    ks_h, kz_h = s2[0], z2[0]
+    w = jnp.asarray(rng.normal(0, 0.2, (n, d)), jnp.bfloat16)
+    x = jnp.asarray(rng.normal(0, 1, (2, n)), jnp.float32)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        chunk_gather_matmul_dma, prefetch_depth=depth, interpret=True))(
+        w, x, ks_h, kz_h)
+    assert _pallas_grids(jaxpr.jaxpr) == [(2,)]  # two 1536-column tiles
+    y = chunk_gather_matmul_dma(w, x, ks_h, kz_h, prefetch_depth=depth,
+                                interpret=True)
+    assert bool(jnp.all(y == blocked_masked_matmul(x * cov_h, w, 8)))
+    ws = [jnp.asarray(rng.normal(0, 0.2, shape), jnp.bfloat16)
+          for shape in ((n, f), (n, f), (f, d))]
+    y, h = chunk_gather_mlp_dma(*ws, x, s2, z2, prefetch_depth=depth,
+                                interpret=True, return_h=True)
+    y_twin, h_twin = _mlp_twin(*ws, x, cov_h, cov_f)
+    assert bool(jnp.all(h == h_twin))
+    assert bool(jnp.all(y == y_twin))
+
+
+def test_column_tile_engages_only_where_vmem_forces_it():
+    """The cells' widths run at full width; InternVL2-76B's gate/up split
+    in two; a width off the 128-lane grid is never split."""
+    from repro.kernels.chunk_gather_dma import column_tile
+
+    def tile(width, streams, depth=1, itemsize=2):
+        return column_tile(width, streams * (depth + 1) * 128 * itemsize)
+
+    assert tile(8192, 2) == 8192 and tile(2048, 1) == 2048  # granite
+    assert tile(18432, 1) == 18432 and tile(18432, 1, depth=2) == 18432
+    assert tile(4608, 1) == 4608  # starcoder2
+    assert tile(28672, 2) == 14336 and tile(8192, 1) == 8192  # internvl2
+    assert tile(28672, 2, itemsize=1) == 28672  # int8 slots fit whole
+    assert tile(704, 2) == 704
+    assert tile(1536, 1, itemsize=2**15) == 512  # one contraction piece
+
+
+def test_superblock_counts_on_a_hand_built_mask():
+    """Four super-blocks: one whole, one with blocks 0, 5 and 15, one
+    empty, one with every block but 7 — one whole DMA, two partial
+    super-blocks taking 3 + 15 block DMAs; 16 × whole + block DMAs is
+    every covered block."""
+    from repro.kernels.chunk_gather_dma import superblock_counts
+
+    mask = _sb_mask(512, 128, [0], {1: [0, 5, 15],
+                                    3: [j for j in range(16) if j != 7]})
+    ks, kz, cov = _tables(mask)
+    counts = superblock_counts(ks, kz, 512, 8)
+    assert counts.tolist() == [1, 2, 18]
+    assert 16 * 1 + 18 == int(cov.sum()) // 8
+
+
+@pytest.mark.parametrize("density", [0.05, 0.6, 0.95])
+def test_superblock_counts_read_only_covered_blocks(density, rng):
+    from repro.kernels.chunk_gather_dma import superblock_counts
+
+    n = 1024
+    mask = rng.random(n) < density
+    ks, kz, cov = _tables(mask)
+    whole, partial, dmas = superblock_counts(ks, kz, n, 8).tolist()
+    blocks = np.asarray(cov).reshape(-1, 16, 8).any(axis=2).sum(axis=1)
+    assert whole == int((blocks == 16).sum())
+    assert partial == int(((blocks > 0) & (blocks < 16)).sum())
+    assert 16 * whole + dmas == int(blocks.sum())
 
 
 # ---------------------------------------------------------------------------

@@ -269,18 +269,16 @@ def test_matmul_kernel_checksum_lane_bit_identical(rng):
     x = jnp.asarray(rng.normal(size=(2, 64)), jnp.float32)
     starts = jnp.asarray([0, 24, 0, 0], jnp.int32)
     sizes = jnp.asarray([16, 32, 0, 0], jnp.int32)
-    y0 = chunk_gather_matmul_dma(w, x, starts, sizes, tile_d=8,
-                                 interpret=True)
+    y0 = chunk_gather_matmul_dma(w, x, starts, sizes, interpret=True)
     y1 = chunk_gather_matmul_dma(w, x, starts, sizes,
-                                 checksums=block_checksums(w), tile_d=8,
+                                 checksums=block_checksums(w),
                                  interpret=True)
     np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
     # quantized + checksummed: both extra lanes ride the slot rotation
     q, s = quantize_rows(w)
-    yq0 = chunk_gather_matmul_dma(q, x, starts, sizes, s, tile_d=8,
-                                  interpret=True)
+    yq0 = chunk_gather_matmul_dma(q, x, starts, sizes, s, interpret=True)
     yq1 = chunk_gather_matmul_dma(q, x, starts, sizes, s, block_checksums(q),
-                                  tile_d=8, interpret=True)
+                                  interpret=True)
     np.testing.assert_array_equal(np.asarray(yq0), np.asarray(yq1))
 
 
@@ -291,13 +289,12 @@ def test_mlp_kernel_checksum_lane_bit_identical(rng):
     x = jnp.asarray(rng.normal(size=(2, 64)), jnp.float32)
     st = jnp.asarray([[0, 24, 0, 0], [8, 0, 0, 0]], jnp.int32)
     sz = jnp.asarray([[16, 32, 0, 0], [24, 0, 0, 0]], jnp.int32)
-    z0 = chunk_gather_mlp_dma(wg, wu, wd, x, st, sz, tile_f=8, tile_d=8,
-                              interpret=True)
+    z0 = chunk_gather_mlp_dma(wg, wu, wd, x, st, sz, interpret=True)
     z1 = chunk_gather_mlp_dma(
         wg, wu, wd, x, st, sz,
         checksums=(block_checksums(wg), block_checksums(wu),
                    block_checksums(wd)),
-        tile_f=8, tile_d=8, interpret=True)
+        interpret=True)
     np.testing.assert_array_equal(np.asarray(z0), np.asarray(z1))
 
 
@@ -309,7 +306,7 @@ def test_matmul_kernel_rejects_bad_checksum_shape(rng):
     with pytest.raises(ValueError, match="checksums"):
         chunk_gather_matmul_dma(w, x, starts, sizes,
                                 checksums=jnp.zeros(7, jnp.uint32),
-                                tile_d=8, interpret=True)
+                                interpret=True)
 
 
 # ---------------------------------------------------------------------------

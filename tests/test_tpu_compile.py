@@ -4,8 +4,10 @@ Mosaic refuses at compile time what interpret mode never checks: lane
 offsets off the 128-lane tile, DMA row offsets it cannot prove aligned,
 one-word DMAs below an HBM array's tiling, more VMEM than a kernel may use.
 These tests compile both decode-path kernels at the served models' real
-widths (TinyLlama-1.1B, InternVL2-76B), bf16 and int8 with scales, plus
-the checksum lane — a chip is described, not attached, so nothing runs.
+widths (TinyLlama-1.1B, InternVL2-76B, whose gate/up split into column
+tiles, and the benchmark cells' Granite-3.0-2B and StarCoder2-7B at their
+batch of 16), bf16 and int8 with scales, plus the checksum lane — a chip is
+described, not attached, so nothing runs.
 
 The topology is described inside module fixtures, never at import: only
 one process may hold the TPU library, and pytest-xdist workers all import
@@ -20,9 +22,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import chunk_gather_matmul_dma, chunk_gather_mlp_dma
 
-# (name, d_model, d_ff) at published widths
-WIDTHS = (("tinyllama", 2048, 5632), ("internvl2", 8192, 28672))
-BATCH = 2  # the serve CLI's default slot count
+# (name, d_model, d_ff, batch) at published widths: the serve CLI's
+# default 2 slots, the benchmark cells' 16
+WIDTHS = (("tinyllama", 2048, 5632, 2), ("internvl2", 8192, 28672, 2),
+          ("granite", 2048, 8192, 16), ("starcoder2", 4608, 18432, 16))
 
 
 @pytest.fixture(scope="module")
@@ -59,42 +62,54 @@ def _assert_kernel(compiled):
     assert compiled.as_text().count("tpu_custom_call") >= 1
 
 
-CASES = [(w, wbits, False) for w in WIDTHS for wbits in (16, 8)]
+CASES = [(w, wbits, False) for w in WIDTHS[:2] for wbits in (16, 8)]
 CASES.append((WIDTHS[1], 8, True))  # InternVL2 int8 with the checksum lane
+CASES += [(w, 16, False) for w in WIDTHS[2:]]  # the cells serve bf16
 
 
 def _case_id(case):
-    (name, _, _), wbits, ck = case
+    (name, *_), wbits, ck = case
     return f"{name}-w{wbits}" + ("-checksums" if ck else "")
+
+
+def _compile_matmul(sharding, case, rows, cols):
+    (_, n, f, batch), wbits, checksummed = case
+    r, c = ({"d": n, "f": f}[a] for a in (rows, cols))
+    wdt = jnp.bfloat16 if wbits == 16 else jnp.int8
+    k = max(n, f) // 8  # the serve plan's table width (n_max / block_rows)
+    args = (
+        _spec(sharding, (r, c), wdt),
+        _spec(sharding, (batch, r), jnp.float32),
+        _spec(sharding, (k,), jnp.int32),
+        _spec(sharding, (k,), jnp.int32),
+        _lanes(sharding, (r,), jnp.float32)[0] if wbits == 8 else None,
+        _lanes(sharding, (r,), jnp.uint32)[0] if checksummed else None,
+    )
+    _assert_kernel(jax.jit(chunk_gather_matmul_dma).lower(*args).compile())
 
 
 @pytest.mark.parametrize("case", CASES, ids=_case_id)
 def test_matmul_dma_compiles_for_v5e(one_chip, case):
-    (_, n, f), wbits, checksummed = case
-    wdt = jnp.bfloat16 if wbits == 16 else jnp.int8
-    k = max(n, f) // 8  # the serve plan's table width (n_max / block_rows)
-    args = (
-        _spec(one_chip, (n, n), wdt),  # attn_out wo at d_model width
-        _spec(one_chip, (BATCH, n), jnp.float32),
-        _spec(one_chip, (k,), jnp.int32),
-        _spec(one_chip, (k,), jnp.int32),
-        _lanes(one_chip, (n,), jnp.float32)[0] if wbits == 8 else None,
-        _lanes(one_chip, (n,), jnp.uint32)[0] if checksummed else None,
-    )
-    compiled = jax.jit(chunk_gather_matmul_dma).lower(*args).compile()
-    _assert_kernel(compiled)
+    _compile_matmul(one_chip, case, "d", "d")  # attn_out wo at d_model width
+
+
+@pytest.mark.parametrize("rows,cols", [("d", "f"), ("f", "d")], ids=["fc", "proj"])
+@pytest.mark.parametrize("case", CASES, ids=_case_id)
+def test_gelu_mlp_matmul_dma_compiles_for_v5e(one_chip, case, rows, cols):
+    """The non-gated MLP's fc and proj, each one projection kernel."""
+    _compile_matmul(one_chip, case, rows, cols)
 
 
 @pytest.mark.parametrize("case", CASES, ids=_case_id)
 def test_mlp_dma_compiles_for_v5e(one_chip, case):
-    (_, n, f), wbits, checksummed = case
+    (_, n, f, batch), wbits, checksummed = case
     wdt = jnp.bfloat16 if wbits == 16 else jnp.int8
     k = max(n, f) // 8
     args = (
         _spec(one_chip, (n, f), wdt),
         _spec(one_chip, (n, f), wdt),
         _spec(one_chip, (f, n), wdt),
-        _spec(one_chip, (BATCH, n), jnp.float32),
+        _spec(one_chip, (batch, n), jnp.float32),
         _spec(one_chip, (2, k), jnp.int32),
         _spec(one_chip, (2, k), jnp.int32),
         _spec(one_chip, (f,), jnp.float32),  # exact ffn mask

@@ -33,7 +33,7 @@ def main():
     from bench import spec
     from bench.serve import program_config
     from bench.traffic.common import prompt_length
-    from bench.weights import dims_of, served_params
+    from bench.weights import served_params
     from repro.models import build_model
     from repro.serving import ServeEngine
 
@@ -49,9 +49,10 @@ def main():
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
                             tree)
 
-    dm = dims_of(cell.cfg)
-    model = build_model(program_config(cell.cfg))
-    params = shaped(jax.eval_shape(lambda: served_params(dm, 0)))
+    ref = spec.reference_module(cell)
+    dm = ref.dims(cell.cfg)
+    model = build_model(program_config(cell.cfg, ref))
+    params = shaped(jax.eval_shape(lambda: served_params(ref.leaves, dm, 0)))
     slots, max_seq = int(e["slots"]), int(e["max_seq"])
     # the engine keeps only references to what it is given at wbits 16
     eng = ServeEngine(model, params, max_seq=8, batch_size=1,

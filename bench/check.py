@@ -12,7 +12,8 @@ Numbers compared (each against a limit in ``limits/<cell>.json``):
   the cache and attention, the gather kernels and the tokens.
 - ``kv_rel_err``: for the requests whose KV cache the program left intact
   (one full cohort of a sparse cell, one request of a dense cell), the
-  largest relative error, over layers, of the keys and values in the cache
+  largest relative error, over layers and the cache entries the reference
+  names (``cache_entries``: keys and values, or one latent), of the cache
   against the reference's, over every position, prompt and decoded.
 - ``kv0_rel_err``: the same at the first layer and the decoded positions
   only. Forty layers of bfloat16 rounding drift the deep layers by a few
@@ -25,13 +26,18 @@ Numbers compared (each against a limit in ``limits/<cell>.json``):
   each step and takes the top budget rows; this is the share of selected
   rows on which the program's mask differs from that choice, over every
   step but the cohort's first (whose importance comes from the cohort
-  before) and every layer and site.
+  before) and every site and layer it spans (the reference's ``sites``).
+  A reference whose selection is not a plain top-k (experts chosen before
+  their rows) says what it expects with ``expected_mask(dims, site,
+  importance, budget)``.
 
 The control in the program's place comes in two forms: the program's own
 int8 weight path (``Program(wbits=8)``, harness ``control=True`` on a
 selecting method), and the reference itself with float8 weights
 (``control_fp8``), whose keys and values stand for the cache and whose
 top token at each position is read against the float32 reference.
+
+``ref`` is the cell's reference module and ``dm`` its ``dims``.
 """
 from __future__ import annotations
 
@@ -40,7 +46,6 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from bench.traffic.common import seed_words
-from bench.weights import Dims
 
 SAMPLE_TOKENS = 400  # dense cells: served tokens compared per run
 
@@ -83,56 +88,63 @@ def cohorts(done, slots: int) -> Dict[tuple, list]:
             if len(g) == slots and None not in key}
 
 
-def compare_rows(ref, dm: Dims, seed: int, tokens: np.ndarray, served: np.ndarray,
-                 p: int, s_len: int, cache_kv: Optional[Tuple[np.ndarray, np.ndarray]],
+def compare_rows(ref, dm, seed: int, tokens: np.ndarray, served: np.ndarray,
+                 p: int, s_len: int, cache: Optional[Dict[str, np.ndarray]],
                  control_fp8: bool, masks_at: Optional[Callable] = None,
                  imp_pos: Optional[List[int]] = None,
                  n_served: Optional[int] = None) -> Tuple[Dict[str, float], dict]:
     """Rows of one prompt length ``p`` and true length ``s_len`` (``tokens``
     may be padded past it). ``served`` (rows, n) are compared at positions
-    p-1 .. p+n-2; ``cache_kv`` (layers, rows, >= s_len, kv, hd) each, or
-    None to compare no cache; only the first ``n_served`` columns count
-    (the rest pad a fixed shape). With ``control_fp8`` the float8 reference
-    takes the program's place: its top tokens and its keys and values."""
+    p-1 .. p+n-2; ``cache``: each entry the reference names, (layers, rows,
+    >= s_len, ...), or None to compare no cache; only the first
+    ``n_served`` columns count (the rest pad a fixed shape). With
+    ``control_fp8`` the float8 reference takes the program's place: its top
+    tokens and its cache."""
     import jax.numpy as jnp
 
     n = served.shape[1]
     pos = [min(p - 1 + j, tokens.shape[1] - 1) for j in range(n)]
     if control_fp8:
-        kv_c: List[tuple] = []
+        low: Dict[str, list] = {name: [] for name in ref.cache_entries(dm)}
+
+        def keep(layer, entries):
+            for name, rows in low.items():
+                rows.append(np.asarray(entries[name]))
+
         lo, _ = ref.forward(dm, seed, tokens, pos, masks_at=masks_at, fp8=True,
-                            on_kv=lambda layer, k, v: kv_c.append(
-                                (np.asarray(k), np.asarray(v))))
+                            on_cache=keep)
         served = np.asarray(lo).argmax(-1).astype(np.int32)
-        if cache_kv is not None:
-            cache_kv = (np.stack([k for k, _ in kv_c]), np.stack([v for _, v in kv_c]))
+        if cache is not None:
+            cache = {name: np.stack(rows) for name, rows in low.items()}
     errs: List[float] = []
     errs0: List[float] = []
 
     def rel(a, b):
         return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
-    def on_kv(layer, k, v):
-        for ours, theirs in ((k, cache_kv[0]), (v, cache_kv[1])):
-            got = jnp.asarray(theirs[layer, :, :s_len], jnp.float32)
+    def on_cache(layer, entries):
+        for name in cache:
+            ours = entries[name]
+            got = jnp.asarray(cache[name][layer, :, :s_len], jnp.float32)
             errs.append(rel(got, ours[:, :s_len]))
             if layer == 0:
                 errs0.append(rel(got[:, p:s_len], ours[:, p:s_len]))
 
     logits, imps = ref.forward(dm, seed, tokens, pos, masks_at=masks_at, imp_pos=imp_pos,
-                               on_kv=on_kv if cache_kv is not None else None)
+                               on_cache=on_cache if cache is not None else None)
     out = {"logit_gap": float(gaps(np.asarray(logits), served)[:, :n_served].max())}
-    if cache_kv is not None:
+    if cache is not None:
         out["kv_rel_err"], out["kv0_rel_err"] = max(errs), max(errs0)
     return out, imps
 
 
-def check_selecting(ref, dm: Dims, seed: int, cohort: list, masks, sparsity: float,
-                    cache_kv: Tuple[np.ndarray, np.ndarray],
-                    control_fp8: bool = False) -> Dict[str, float]:
-    """``cohort``: the requests of one full batch, in row order;
-    ``cache_kv``: the program's KV cache rows of those requests as the
-    cohort's last step left it, (layers, rows, positions, kv, hd) each."""
+def check_selecting(ref, dm, seed: int, cohort: list, masks, sparsity: float,
+                    cache: Dict[str, np.ndarray], control_fp8: bool = False
+                    ) -> Dict[str, float]:
+    """``cohort``: the requests of one full batch, in row order; ``cache``:
+    the program's cache rows of those requests as the cohort's last step
+    left them, (layers, rows, positions, ...) each; ``masks[call][site]``:
+    (the site's layers, N)."""
     seqs = [served_sequence(sv) for sv in cohort]
     p = len(cohort[0].item.tokens)
     n = len(cohort[0].tokens)
@@ -140,35 +152,40 @@ def check_selecting(ref, dm: Dims, seed: int, cohort: list, masks, sparsity: flo
     served = np.stack([t for _, t in seqs])
     calls = cohort[0].decode_calls
     s_len = tokens.shape[1]
+    spans = {site: layers for site, (_, layers) in ref.sites(dm).items()}
 
     def masks_at(layer):
         out = {}
         for site in masks[calls[0]]:
+            if layer not in spans[site]:
+                continue
+            row = spans[site].index(layer)
             m = np.ones((s_len, masks[calls[0]][site].shape[1]), np.float32)
             for j, c in enumerate(calls[: s_len - p]):
-                m[p + j] = masks[c][site][layer]
+                m[p + j] = masks[c][site][row]
             out[site] = m
         return out
 
-    out, imps = compare_rows(ref, dm, seed, tokens, served, p, s_len, cache_kv, control_fp8,
+    out, imps = compare_rows(ref, dm, seed, tokens, served, p, s_len, cache, control_fp8,
                              masks_at=masks_at, imp_pos=list(range(p, p + n - 1)))
+    expected = getattr(ref, "expected_mask", lambda dm, site, imp, b: topk_mask(imp, b))
     differ = total = 0
     for j in range(1, n):
         for site, imp in imps.items():
             b = budget(imp.shape[-1], sparsity)
-            for layer in range(dm.layers):
-                want = topk_mask(imp[layer, j - 1], b)
-                differ += int(np.sum(want != masks[calls[j]][site][layer]))
+            for row in range(imp.shape[0]):
+                want = expected(dm, site, imp[row, j - 1], b)
+                differ += int(np.sum(want != masks[calls[j]][site][row]))
                 total += 2 * b
     out["mask_mismatch"] = differ / total
     return out
 
 
-def check_dense(ref, dm: Dims, seed: int, done, max_seq: int, n_pos: int,
+def check_dense(ref, dm, seed: int, done, max_seq: int, n_pos: int,
                 kept: Optional[tuple], control_fp8: bool) -> Dict[str, float]:
     """A sample drawn from the seed, the longest request first, of about
     ``SAMPLE_TOKENS`` served tokens; ``kept``: (request, its cache rows)
-    whose keys and values are compared too. ``n_pos``: served tokens read
+    whose cache entries are compared too. ``n_pos``: served tokens read
     per request at most (the longest output plus one), fixed per cell so
     the reference compiles once."""
     rng = np.random.default_rng(seed_words(seed) + [0xD0])

@@ -36,6 +36,7 @@ def main(argv) -> int:
     if args.control and args.fault:
         ap.error("a control or a fault, not both")
     cell = spec.load_cell(args.workload)
+    ref = spec.reference_module(cell)
     why = harness.require_chips(cell.chips)
     if why:
         print(f"control: {why}", file=sys.stderr)
@@ -46,7 +47,7 @@ def main(argv) -> int:
         if args.fault == "stale_state":
             hooks = faults.stale_state()
         elif args.fault == "altered_token":
-            hooks = faults.altered_token(int(cell.cfg["vocab_size"]))
+            hooks = faults.altered_token(ref.dims(cell.cfg).vocab)
         elif args.fault == "half_batch":
             ctx = faults.half_batch()
         try:

@@ -85,6 +85,7 @@ class Context:
     """What a per-layer metric reader may read."""
 
     cell: spec.Cell
+    ref: object  # the cell's reference module: its sites, kernel calls, FLOPs
     dims: object
     peaks: Dict[str, float]
     window: Window
@@ -242,39 +243,39 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, t0: float,
     done = [sv for sv in win.requests if sv.finished is not None]
     failed = len(win.requests) - len(done)
     rng = np.random.default_rng(seed_words(seed) + [0xC0])
-    cohort, cache_kv, masks, kept = None, None, {}, None
+    cohort, cache_rows, masks, kept = None, None, {}, None
     if selecting:
         full = check.cohorts(done, int(e["slots"]))
         snaps = [sn for sn in prog.cache_snaps if sn[0] in full]
         if not snaps:
             raise RuntimeError("no full cohort of the window left its KV cache to check")
-        calls_key, k, v = snaps[int(rng.integers(len(snaps)))]
+        calls_key, cache = snaps[int(rng.integers(len(snaps)))]
         cohort = full[calls_key]
         rows = np.asarray([sv.slot for sv in cohort])
-        cache_kv = (np.asarray(k[:, rows]), np.asarray(v[:, rows]))
+        cache_rows = {name: np.asarray(a[:, rows]) for name, a in cache.items()}
         masks = host_masks(prog.decode_masks, list(calls_key) + list(win.decode_calls))
     else:
         snaps = [sn for sn in prog.cache_snaps if any(sn[0] is d for d in done)]
         if snaps:
-            sv, k, v = snaps[int(rng.integers(len(snaps)))]
-            kept = (sv, (np.asarray(k), np.asarray(v)))
+            sv, cache = snaps[int(rng.integers(len(snaps)))]
+            kept = (sv, {name: np.asarray(a) for name, a in cache.items()})
     tokens_per_s = win.tokens / win.seconds
-    ctx = Context(cell, prog.dims, None, win, [prog.engine.stats[i].wall_s for i in win.stats
-                                                if prog.engine.stats[i].kind == "decode"],
+    ctx = Context(cell, prog.ref, prog.dims, None, win,
+                  [prog.engine.stats[i].wall_s for i in win.stats
+                   if prog.engine.stats[i].kind == "decode"],
                   summary, tokens_per_s,
                   mean_selected_rows({c: masks[c] for c in win.decode_calls}),
                   prog.wbits)
-    dims = prog.dims
+    ref, dims = prog.ref, prog.dims
     del prog, source
     gc.collect()
 
     t_check = clock()
-    ref = spec.reference_module(cell)
     if not done:
         raise RuntimeError("no request due in the window finished")
     if selecting:
         readings = check.check_selecting(ref, dims, seed, cohort, masks,
-                                         float(e["sparsity"]), cache_kv,
+                                         float(e["sparsity"]), cache_rows,
                                          control_fp8=control == "fp8")
     else:
         out = cell.traffic["output_tokens"]

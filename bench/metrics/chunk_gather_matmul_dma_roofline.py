@@ -1,6 +1,7 @@
 """Gather kernels: ``chunk_gather_matmul_dma``'s share of its roofline — the
 least time its calls could take (bench/roofline.py, from the rows the
-step's masks selected) over their device time in the trace."""
+step's masks selected and the calls the reference module lists) over
+their device time in the trace."""
 
 from bench.roofline import least_time_per_call
 
@@ -13,6 +14,6 @@ def read(ctx):
     t, n = ctx.trace.op_time_s(OP)
     if n == 0 or t <= 0:
         return None
-    per_call = least_time_per_call(KERNEL, ctx.dims, ctx.selected_rows,
+    per_call = least_time_per_call(ctx.ref, ctx.dims, KERNEL, ctx.selected_rows,
                                    int(ctx.cell.engine["slots"]), ctx.peaks, ctx.wbits)
     return 100.0 * n * per_call / t

@@ -19,31 +19,31 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from bench import spec
 from bench.traffic.common import Item
-from bench.weights import Dims, dims_of, served_params
+from bench.weights import served_params
 
 clock = time.perf_counter
 
-# configuration-file key -> the program's ModelConfig field
-KEY_MAP = {
-    "hidden_size": "d_model", "intermediate_size": "d_ff",
-    "num_hidden_layers": "n_layers", "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "tie_word_embeddings": "tie_embeddings",
-}
 SELECTING = ("chunk", "topk")
 CACHE_SNAPS = 2  # cohorts or requests whose KV cache the check may read
 
 
-def program_config(cfg: dict):
+def program_config(cfg: dict, ref):
     """The registry's ModelConfig for ``cfg['arch']``, with the keys the
-    file lists under ``reduced`` set to the file's values; any other key
-    that disagrees with the file is an error."""
+    file lists under ``reduced`` set to the file's values. Both ways an
+    error: a key of the reference's ``key_map`` that disagrees with the
+    registry and is not listed, and a listed key the map cannot set; then
+    the reference's ``program_facts`` have to hold."""
     from repro.configs import get_config
 
     base = get_config(cfg["arch"])
+    keys = {key: field for key, field in ref.key_map.items() if key in cfg}
+    stray = set(cfg["reduced"]) - set(keys)
+    if stray:
+        raise ValueError(f"{cfg['arch']}: reduced names {sorted(stray)}, which the "
+                         "reference's key_map cannot set in the program's registry")
     changes = {}
-    keys = dict(KEY_MAP, head_dim="head_dim") if "head_dim" in cfg else KEY_MAP
     for key, field in keys.items():
         want, have = cfg[key], getattr(base, field)
         if want != have:
@@ -52,11 +52,8 @@ def program_config(cfg: dict):
                                  f"file but {field}={have} in the program's registry")
             changes[field] = type(have)(want)
     out = dataclasses.replace(base, **changes)
-    dm = dims_of(cfg)
-    facts = {"head_dim": (out.resolved_head_dim, dm.head_dim),
-             "norm": (out.norm, {"rms": "rmsnorm", "layer": "layernorm"}[dm.norm]),
-             "mlp": (out.mlp, dm.mlp)}
-    for what, (have, want) in facts.items():
+    for what, want in ref.program_facts(ref.dims(cfg)).items():
+        have = getattr(out, what)
         if have != want:
             raise ValueError(f"{cfg['arch']}: the program has {what}={have}, "
                              f"the configuration file {want}")
@@ -105,17 +102,19 @@ class Program:
             raise ValueError("a selecting method is checked against the masks of "
                              "every decode step, which the plan holds only at the "
                              "end of a round: round_tokens must be 1")
-        self.dims: Dims = dims_of(cell.cfg)
-        self.pcfg = program_config(cell.cfg)
+        self.ref = spec.reference_module(cell)
+        self.dims = self.ref.dims(cell.cfg)
+        self.cache_entries = self.ref.cache_entries(self.dims)
+        self.pcfg = program_config(cell.cfg, self.ref)
         model = build_model(self.pcfg)
         want = jax.eval_shape(model.init, jax.random.key(0))
-        have = jax.eval_shape(lambda: served_params(self.dims, seed))
+        have = jax.eval_shape(lambda: served_params(self.ref.leaves, self.dims, seed))
         if jax.tree.structure(want) != jax.tree.structure(have) or any(
                 (a.shape, a.dtype) != (b.shape, b.dtype)
                 for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(have))):
             raise ValueError("bench/weights.py's tree differs from the program's "
                              f"parameters:\n{want}\n{have}")
-        params = served_params(self.dims, seed)
+        params = served_params(self.ref.leaves, self.dims, seed)
         self.wbits = int(e["wbits"]) if wbits is None else wbits
         self.engine = ServeEngine(
             model, params, max_seq=int(e["max_seq"]), batch_size=int(e["slots"]),
@@ -127,8 +126,9 @@ class Program:
         self.live: Dict[int, Served] = {}  # rid -> in flight
         self.done: List[Served] = []
         self.decode_masks: List[Dict[str, object]] = []  # per decode call
-        # the KV cache as the first finished cohorts (selecting methods,
-        # keyed by their decode calls) or requests (dense) left it
+        # the cache entries the check compares, as the first finished cohorts
+        # (selecting methods, keyed by their decode calls) or requests
+        # (dense) left them
         self.cache_snaps: List[tuple] = []
         self._by_prompt: Dict[int, Served] = {}
         self._decoded = False
@@ -201,15 +201,15 @@ class Program:
                 self.done.append(sv)
                 del self.live[rid]
         if finished and len(self.cache_snaps) < CACHE_SNAPS:
-            k, v = self.engine.cache["k"], self.engine.cache["v"]
+            cache = {name: self.engine.cache[name] for name in self.cache_entries}
             if self.method in SELECTING:
                 # a cohort fills every slot: hold the whole cache (references
                 # only; the next admission writes new arrays)
-                self.cache_snaps.append((tuple(finished[0].decode_calls), k, v))
+                self.cache_snaps.append((tuple(finished[0].decode_calls), cache))
             else:
                 sv = finished[0]
-                self.cache_snaps.append((sv, k[:, sv.slot:sv.slot + 1],
-                                         v[:, sv.slot:sv.slot + 1]))
+                self.cache_snaps.append((sv, {name: a[:, sv.slot:sv.slot + 1]
+                                              for name, a in cache.items()}))
         return Step(t0, t1, delivered), finished
 
     def decode_wall_s(self, first_stat: int) -> List[float]:

@@ -5,13 +5,43 @@ configuration and traffic mix. Then, under this directory:
 
 - ``configs/<config>.json``: the configuration as run (the file the
   benchmark names), with the registry ``arch`` and its ``reference``;
-- ``reference/<reference>.py``: its plain reference;
+- ``reference/<reference>.py``: its plain reference, which also holds
+  every fact of the model's layer layout (below);
 - ``traffic/<mix>.json``: the traffic mix and serving settings, whose
   ``kind`` names the generator ``traffic/<kind>.py``;
 - ``limits/<cell>.json``: the limits of the correctness check;
 - ``metrics/<metric>.py``: one reader per per-layer metric.
 
 A new cell, configuration, mix or metric is a new file, never an edit.
+
+A configuration file holds the source's ``config`` keys (Hugging Face
+names), ``arch`` (the program's registry entry), ``reference``,
+``reduced`` (the keys cut from the source), ``published`` (each cut key's
+source value) and, optionally, ``assumed``. Its reference module provides
+(``REFERENCE_API``):
+
+- ``dims(cfg)``: the shape facts, hashable (jit takes them as static),
+  with at least ``vocab`` and ``layers``; it reads the whole file, so a
+  router can be as wide as the ``published`` expert count while the held
+  count is the cut one;
+- ``leaves(dims)``: the weight tree, ``{"top": {name: (shape, kind)},
+  "stacks": {stack: (depth, {name: (shape, kind)})}}``, kinds from
+  ``bench/weights.py``; it has to match the program's parameter tree;
+- ``key_map``: configuration-file key -> the program's ``ModelConfig``
+  field, and ``program_facts(dims)``: ``ModelConfig`` attribute -> the
+  value the file implies (both checked by ``serve.program_config``);
+- ``cache_entries(dims)``: the names of the program's cache leaves the
+  check compares, each (layers, rows, positions, ...);
+- ``sites(dims)``: site -> (input width, the layers it spans); the plan's
+  mask of a site is (those layers, width); ``expected_mask(dims, site,
+  importance, budget)``, optionally, where selection is not a top-k;
+- ``kernel_calls(dims)``: kernel -> its calls in one decode step, in
+  proportion; a call is a list of (site, output width) per matrix;
+- ``flops_per_token(dims, context)``: one decode token with no selection;
+- ``forward(dims, seed, tokens, logit_pos, masks_at=None, imp_pos=None,
+  fp8=False, on_cache=None)``: float32 logits and each site's importance
+  (site -> (its layers, positions, width)); ``on_cache(layer, entries)``
+  gets each layer's cache entries by the names above.
 """
 from __future__ import annotations
 
@@ -89,8 +119,16 @@ def traffic_source(cell: Cell, seed: int, vocab: int, seconds: float):
     return gen.make_source(cell.traffic, seed, vocab, seconds)
 
 
+REFERENCE_API = ("dims", "leaves", "key_map", "program_facts", "cache_entries", "sites",
+                 "kernel_calls", "flops_per_token", "forward")
+
+
 def reference_module(cell: Cell) -> ModuleType:
-    return load_module(cell.bench_dir / "reference" / f"{cell.cfg['reference']}.py")
+    ref = load_module(cell.bench_dir / "reference" / f"{cell.cfg['reference']}.py")
+    missing = [name for name in REFERENCE_API if not hasattr(ref, name)]
+    if missing:
+        raise AttributeError(f"reference {cell.cfg['reference']!r} lacks {missing}")
+    return ref
 
 
 def metric_readers(cell: Cell) -> Dict[str, ModuleType]:

@@ -130,17 +130,62 @@ def test_dense_faults_are_caught(root):
 def test_weights_of_one_layer_equal_that_slice_of_the_served_tree():
     import jax.numpy as jnp
 
-    from bench.weights import dims_of, layer_params, served_params, top_params
+    from bench.reference import decoder
+    from bench.weights import layer_params, served_params, top_params
 
-    dm = dims_of(TOY)
-    tree = served_params(dm, 2**40 + 3)
-    one = layer_params(dm, 2**40 + 3, 1, jnp.bfloat16)
+    dm = decoder.dims(TOY)
+    tree = served_params(decoder.leaves, dm, 2**40 + 3)
+    one = layer_params(decoder.leaves, dm, 2**40 + 3, "layers", 1, jnp.bfloat16)
     for name, leaf in one.items():
         np.testing.assert_array_equal(np.asarray(leaf), np.asarray(tree["layers"][name][1]))
-    top = top_params(dm, 2**40 + 3, jnp.bfloat16)
+    top = top_params(decoder.leaves, dm, 2**40 + 3, jnp.bfloat16)
     np.testing.assert_array_equal(np.asarray(top["embed"]), np.asarray(tree["embed"]))
-    other = served_params(dm, 2**40 + 4)
+    other = served_params(decoder.leaves, dm, 2**40 + 4)
     assert not np.array_equal(np.asarray(other["embed"]), np.asarray(tree["embed"]))
+
+
+# TOY as a StarCoder2-like decoder: LayerNorm with biases, GELU, an untied head
+GELU = {**{k: v for k, v in TOY.items() if k not in ("rms_norm_eps", "served_norm_eps")},
+        "hidden_act": "gelu_pytorch_tanh", "norm_epsilon": 1e-05,
+        "tie_word_embeddings": False}
+
+
+def tree_checksum(tree) -> str:
+    import hashlib
+
+    import jax
+
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        a = np.asarray(leaf)
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(str(a.shape).encode())
+        h.update(a.view(np.uint16).tobytes())
+    return h.hexdigest()
+
+
+# sha256 of the served bfloat16 tree at seed 2**40 + 3, as the benchmark drew
+# it before the layer layout moved into the reference module
+@pytest.mark.parametrize("cfg,n_leaves,want", [
+    (TOY, 11, "94ca5c8a5524e33a72e39e2ae2ccb1d56059d027013a82e09f8bbac340c26079"),
+    (GELU, 16, "79c5703fb3170c95dd008ca712c79f24c4e6e628c6734a9c28c4f446cf67fad2"),
+], ids=["swiglu", "gelu"])
+def test_the_decoders_weights_are_pinned_bit_for_bit(cfg, n_leaves, want):
+    import jax
+    import jax.numpy as jnp
+
+    from bench.reference import decoder
+    from bench.weights import layer_params, served_params
+
+    dm = decoder.dims(cfg)
+    tree = served_params(decoder.leaves, dm, 2**40 + 3)
+    assert len(jax.tree.leaves(tree)) == n_leaves
+    assert tree_checksum(tree) == want
+    for i in range(dm.layers):
+        one = layer_params(decoder.leaves, dm, 2**40 + 3, "layers", i, jnp.bfloat16)
+        assert set(one) == set(tree["layers"])
+        for name, leaf in one.items():
+            np.testing.assert_array_equal(np.asarray(leaf), np.asarray(tree["layers"][name][i]))
 
 
 def test_the_reference_agrees_with_the_programs_dense_prefill():
@@ -151,13 +196,14 @@ def test_the_reference_agrees_with_the_programs_dense_prefill():
 
     from bench.reference import decoder
     from bench.serve import program_config
-    from bench.weights import dims_of, served_params
+    from bench.weights import served_params
     from repro.models import build_model
 
-    dm = dims_of(TOY)
-    model = build_model(program_config(TOY))
+    dm = decoder.dims(TOY)
+    model = build_model(program_config(TOY, decoder))
     tokens = np.random.default_rng(0).integers(0, dm.vocab, (2, 12)).astype(np.int32)
-    last, _ = model.prefill(served_params(dm, 5), {"tokens": jnp.asarray(tokens)}, 16)
+    last, _ = model.prefill(served_params(decoder.leaves, dm, 5),
+                            {"tokens": jnp.asarray(tokens)}, 16)
     ref, _ = decoder.forward(dm, 5, tokens, [11])
     lo, _ = decoder.forward(dm, 5, tokens, [11], fp8=True)
     def err(a):

@@ -6,7 +6,7 @@ from types import SimpleNamespace
 import pytest
 
 from bench import roofline, spec, trace_reduce
-from bench.weights import Dims
+from bench.reference import decoder
 
 
 @dataclasses.dataclass
@@ -86,7 +86,7 @@ def test_peaks_known_and_unknown_device():
         roofline.peaks_for("TPU v99")
 
 
-DM = Dims(d=16, f=64, layers=2, heads=2, kv_heads=1, head_dim=8, vocab=100,
+DM = decoder.Dims(d=16, f=64, layers=2, heads=2, kv_heads=1, head_dim=8, vocab=100,
           rope_theta=1e4, norm="rms", norm_eps=1e-5, mlp="swiglu", tied=True)
 PEAKS = {"bf16_flops": 1e6, "int8_ops": 2e6, "hbm_bytes_per_s": 1e3}
 
@@ -94,30 +94,32 @@ PEAKS = {"bf16_flops": 1e6, "int8_ops": 2e6, "hbm_bytes_per_s": 1e3}
 def test_call_work_counts_selected_rows_and_activations():
     rows = {"hidden_attn": 10, "attn_out": 16, "hidden_mlp": 8, "ffn": 32}
     # q projection: 10 rows x 16 columns of bf16, batch 2
-    flops, nbytes = roofline.call_work([("hidden_attn", 16)], rows, DM, 2, 2.0)
+    inputs = {site: width for site, (width, _) in decoder.sites(DM).items()}
+    flops, nbytes = roofline.call_work([("hidden_attn", 16)], rows, inputs, 2, 2.0)
     assert flops == 2 * 2 * 10 * 16
     assert nbytes == 10 * 16 * 2 + 2 * 16 * 4 + 2 * 16 * 4
     # fused MLP: gate+up over 8 rows of 64, down over 32 rows of 16
     flops, nbytes = roofline.call_work(
-        [("hidden_mlp", 64), ("hidden_mlp", 64), ("ffn", 16)], rows, DM, 2, 2.0)
+        [("hidden_mlp", 64), ("hidden_mlp", 64), ("ffn", 16)], rows, inputs, 2, 2.0)
     assert flops == 2 * 2 * (8 * 64 * 2 + 32 * 16)
     assert nbytes == (8 * 64 * 2 + 32 * 16) * 2 + 2 * 16 * 4 + 2 * (16 + 64) * 4
 
 
 def test_least_time_is_the_larger_bound_averaged_over_a_layers_calls():
     rows = {"hidden_attn": 10, "attn_out": 16, "hidden_mlp": 8, "ffn": 32}
-    t = roofline.least_time_per_call("matmul_dma", DM, rows, 2, PEAKS)
-    calls = roofline.kernel_calls(DM)["matmul_dma"]
+    t = roofline.least_time_per_call(decoder, DM, "matmul_dma", rows, 2, PEAKS)
+    calls = decoder.kernel_calls(DM)["matmul_dma"]
+    inputs = {"hidden_attn": 16, "attn_out": 16, "hidden_mlp": 16, "ffn": 64}
     want = sum(max(f / 1e6, b / 1e3) for f, b in
-               (roofline.call_work(c, rows, DM, 2, 2.0) for c in calls)) / len(calls)
+               (roofline.call_work(c, rows, inputs, 2, 2.0) for c in calls)) / len(calls)
     assert t == pytest.approx(want)
     # at 8 bits: half the weight bytes, the int8 peak
-    t8 = roofline.least_time_per_call("matmul_dma", DM, rows, 2, PEAKS, wbits=8)
+    t8 = roofline.least_time_per_call(decoder, DM, "matmul_dma", rows, 2, PEAKS, wbits=8)
     assert t8 < t
 
 
 def test_dense_flops_per_token():
-    f = roofline.dense_flops_per_token(DM, context=10)
+    f = decoder.flops_per_token(DM, context=10)
     per_layer = 16 * 16 + 2 * 16 * 8 + 16 * 16 + 3 * 16 * 64
     assert f == 2 * (2 * per_layer + 16 * 100) + 4 * 2 * 16 * 10
 
@@ -131,14 +133,14 @@ def test_roofline_and_mfu_readers():
     rows = {"hidden_attn": 10, "attn_out": 16, "hidden_mlp": 8, "ffn": 32}
     cell = SimpleNamespace(engine={"slots": 2})
     served = [SimpleNamespace(item=SimpleNamespace(tokens=[0] * 4), token_times=[0, 1])]
-    ctx = SimpleNamespace(trace=s, selected_rows=rows, peaks=PEAKS, dims=DM, cell=cell,
-                          wbits=16, tokens_per_s=5.0,
+    ctx = SimpleNamespace(trace=s, selected_rows=rows, peaks=PEAKS, ref=decoder, dims=DM,
+                          cell=cell, wbits=16, tokens_per_s=5.0,
                           window=SimpleNamespace(requests=served))
     got = _reader("chunk_gather_matmul_dma_roofline").read(ctx)
-    per_call = roofline.least_time_per_call("matmul_dma", DM, rows, 2, PEAKS)
+    per_call = roofline.least_time_per_call(decoder, DM, "matmul_dma", rows, 2, PEAKS)
     assert got == pytest.approx(100 * 2 * per_call / 200e-9)
     mfu = _reader("mfu.decode").read(ctx)
-    assert mfu == pytest.approx(100 * roofline.dense_flops_per_token(DM, 5.5) * 5 / 1e6)
+    assert mfu == pytest.approx(100 * decoder.flops_per_token(DM, 5.5) * 5 / 1e6)
     # nothing to read: the reader says nothing, never 0
     ctx.trace = trace_reduce.summarize_planes(
         [p if p.name != "/device:TPU:0" else Plane(p.name, [Line("XLA Ops", [])])

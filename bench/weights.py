@@ -2,21 +2,23 @@
 
 The served model and the reference both get their weights from here, from
 ``--seed`` alone: the reference never reads a weight the program holds.
-Leaf names and shapes follow the decoder layout the program serves
-(embedding, norms, q/k/v/o, SwiGLU gate/up/down or GELU fc/proj with
-biases); the harness checks them against the program's own parameter tree
-before serving, so a drift fails loudly.
+What the tree holds is the configuration's reference module's to say:
+``leaves(dims)`` gives ``{"top": {name: (shape, kind)}, "stacks": {stack:
+(depth, {name: (shape, kind)})}}``, and each stack's leaves get its depth
+as a leading axis. The harness checks the tree against the program's own
+parameter tree before serving, so a drift fails loudly.
 
-Every leaf has its own key, and every layer of a stacked leaf its own key
-under it, so one layer can be made alone (the reference walks the model
-layer by layer) and equals that layer of the whole tree bit for bit.
+Every leaf has its own key (from the crc32 of its name), and every layer
+of a stacked leaf its own key under it (its index in the whole model,
+stacks counted in the order declared), so one layer can be made alone (the
+reference walks the model layer by layer) and equals that layer of the
+whole tree bit for bit.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 import zlib
-from typing import Dict, Tuple
+from typing import Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,78 +26,10 @@ import numpy as np
 
 SERVED_DTYPE = jnp.bfloat16
 
-
-@dataclasses.dataclass(frozen=True)
-class Dims:
-    d: int
-    f: int
-    layers: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    vocab: int
-    rope_theta: float
-    norm: str  # rms | layer
-    norm_eps: float
-    mlp: str  # swiglu | gelu
-    tied: bool
-
-
-def dims_of(cfg: dict) -> Dims:
-    """Shape facts of a configuration file (Hugging Face key names)."""
-    act = cfg["hidden_act"]
-    if act == "silu":
-        mlp = "swiglu"
-    elif act.startswith("gelu"):
-        mlp = "gelu"
-    else:
-        raise ValueError(f"unsupported hidden_act {act!r}")
-    if "rms_norm_eps" in cfg:
-        norm, eps = "rms", float(cfg["rms_norm_eps"])
-    else:
-        norm, eps = "layer", float(cfg["norm_epsilon"])
-    # a served model that departs from the published epsilon says so
-    eps = float(cfg.get("served_norm_eps", eps))
-    heads = int(cfg["num_attention_heads"])
-    return Dims(
-        d=int(cfg["hidden_size"]), f=int(cfg["intermediate_size"]),
-        layers=int(cfg["num_hidden_layers"]), heads=heads,
-        kv_heads=int(cfg["num_key_value_heads"]),
-        head_dim=int(cfg.get("head_dim") or cfg["hidden_size"] // heads),
-        vocab=int(cfg["vocab_size"]), rope_theta=float(cfg["rope_theta"]),
-        norm=norm, norm_eps=eps, mlp=mlp, tied=bool(cfg["tie_word_embeddings"]),
-    )
-
-
 # leaf kinds: how a leaf is drawn
-_MATRIX, _EMBED, _NORM_W, _SMALL = "matrix", "embed", "norm_w", "small"
+MATRIX, EMBED, NORM_W, SMALL = "matrix", "embed", "norm_w", "small"
 
-
-def top_leaves(dm: Dims) -> Dict[str, Tuple[Tuple[int, ...], str]]:
-    leaves = {"embed": ((dm.vocab, dm.d), _EMBED),
-              "final_norm_w": ((dm.d,), _NORM_W)}
-    if dm.norm == "layer":
-        leaves["final_norm_b"] = ((dm.d,), _SMALL)
-    if not dm.tied:
-        leaves["head"] = ((dm.d, dm.vocab), _EMBED)
-    return leaves
-
-
-def layer_leaves(dm: Dims) -> Dict[str, Tuple[Tuple[int, ...], str]]:
-    """One layer's leaves (the stacked tree prepends the layer axis)."""
-    q, kv = dm.heads * dm.head_dim, dm.kv_heads * dm.head_dim
-    leaves = {"ln1_w": ((dm.d,), _NORM_W), "ln2_w": ((dm.d,), _NORM_W),
-              "wq": ((dm.d, q), _MATRIX), "wk": ((dm.d, kv), _MATRIX),
-              "wv": ((dm.d, kv), _MATRIX), "wo": ((q, dm.d), _MATRIX)}
-    if dm.norm == "layer":
-        leaves.update(ln1_b=((dm.d,), _SMALL), ln2_b=((dm.d,), _SMALL))
-    if dm.mlp == "swiglu":
-        leaves.update(w_gate=((dm.d, dm.f), _MATRIX), w_up=((dm.d, dm.f), _MATRIX),
-                      w_down=((dm.f, dm.d), _MATRIX))
-    else:
-        leaves.update(w_fc=((dm.d, dm.f), _MATRIX), b_fc=((dm.f,), _SMALL),
-                      w_proj=((dm.f, dm.d), _MATRIX), b_proj=((dm.d,), _SMALL))
-    return leaves
+Leaves = Callable[[object], dict]  # a reference module's ``leaves``
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -116,11 +50,11 @@ def _leaf_key(root, name: str):
 
 def _draw(key, shape, kind: str, dtype):
     z = jax.random.normal(key, shape, jnp.float32)
-    if kind == _MATRIX:
-        v = z / np.sqrt(shape[0])
-    elif kind == _EMBED:
+    if kind == MATRIX:
+        v = z / np.sqrt(shape[-2])  # the fan-in; a leading axis stacks experts
+    elif kind == EMBED:
         v = z * 0.02
-    elif kind == _NORM_W:
+    elif kind == NORM_W:
         v = 1.0 + 0.05 * z
     else:
         v = 0.02 * z
@@ -128,41 +62,62 @@ def _draw(key, shape, kind: str, dtype):
     return v.astype(SERVED_DTYPE).astype(dtype)
 
 
-def _layer(root, layer, dm: Dims, dtype):
+def _stacks(layout: dict) -> Dict[str, Tuple[int, int, dict]]:
+    """stack -> (index of its first layer in the model, depth, leaves)."""
+    out, first = {}, 0
+    for stack, (depth, leaves) in layout["stacks"].items():
+        out[stack] = (first, int(depth), leaves)
+        first += int(depth)
+    return out
+
+
+def _layer(root, layer, leaves: dict, dtype):
     return {name: _draw(jax.random.fold_in(_leaf_key(root, name), layer), shape,
                         kind, dtype)
-            for name, (shape, kind) in layer_leaves(dm).items()}
+            for name, (shape, kind) in leaves.items()}
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-def _all(root, dm: Dims):
-    params = {name: _draw(_leaf_key(root, name), shape, kind, SERVED_DTYPE)
-              for name, (shape, kind) in top_leaves(dm).items()}
-    layers = jnp.arange(dm.layers, dtype=jnp.uint32)
-    params["layers"] = jax.vmap(lambda l: _layer(root, l, dm, SERVED_DTYPE))(layers)
-    return params
-
-
-def served_params(dm: Dims, seed: int):
-    """The whole tree, in bfloat16 on the default device, in one call."""
-    return _all(seed_key(seed), dm)
-
-
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _one_layer(root, layer, dm: Dims, dtype):
-    return _layer(root, layer, dm, dtype)
-
-
-def layer_params(dm: Dims, seed: int, layer: int, dtype=jnp.float32):
-    """Layer ``layer``'s leaves (equal to that slice of the served tree)."""
-    return _one_layer(seed_key(seed), jnp.uint32(layer), dm, dtype)
+def _top(root, layout: dict, dtype):
+    return {name: _draw(_leaf_key(root, name), shape, kind, dtype)
+            for name, (shape, kind) in layout["top"].items()}
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
-def _top(root, dm: Dims, dtype):
-    return {name: _draw(_leaf_key(root, name), shape, kind, dtype)
-            for name, (shape, kind) in top_leaves(dm).items()}
+def _all(root, leaves_of: Leaves, dm):
+    layout = leaves_of(dm)
+    params = _top(root, layout, SERVED_DTYPE)
+    for stack, (first, depth, leaves) in _stacks(layout).items():
+        layers = jnp.arange(first, first + depth, dtype=jnp.uint32)
+        params[stack] = jax.vmap(lambda l, lv=leaves: _layer(root, l, lv, SERVED_DTYPE))(
+            layers)
+    return params
 
 
-def top_params(dm: Dims, seed: int, dtype=jnp.float32):
-    return _top(seed_key(seed), dm, dtype)
+def served_params(leaves_of: Leaves, dm, seed: int):
+    """The whole tree, in bfloat16 on the default device, in one call."""
+    return _all(seed_key(seed), leaves_of, dm)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def _one_layer(root, layer, leaves_of: Leaves, dm, stack: str, dtype):
+    return _layer(root, layer, leaves_of(dm)["stacks"][stack][1], dtype)
+
+
+def layer_params(leaves_of: Leaves, dm, seed: int, stack: str, layer: int,
+                 dtype=jnp.float32):
+    """Layer ``layer`` of ``stack``'s leaves (equal to that slice of the
+    served tree)."""
+    first, depth, _ = _stacks(leaves_of(dm))[stack]
+    if not 0 <= layer < depth:
+        raise IndexError(f"stack {stack!r} has {depth} layers, not {layer}")
+    return _one_layer(seed_key(seed), jnp.uint32(first + layer), leaves_of, dm, stack,
+                      dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _top_params(root, leaves_of: Leaves, dm, dtype):
+    return _top(root, leaves_of(dm), dtype)
+
+
+def top_params(leaves_of: Leaves, dm, seed: int, dtype=jnp.float32):
+    return _top_params(seed_key(seed), leaves_of, dm, dtype)

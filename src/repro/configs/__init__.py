@@ -14,6 +14,7 @@ from .whisper_small import CONFIG as _whisper
 from .starcoder2_3b import CONFIG as _sc2_3b
 from .starcoder2_7b import CONFIG as _sc2_7b
 from .llama4_scout_17b_a16e import CONFIG as _llama4
+from .kimi_vl_a3b import CONFIG as _kimi_vl
 
 CONFIGS: Dict[str, ModelConfig] = {
     c.name: c
@@ -28,6 +29,7 @@ CONFIGS: Dict[str, ModelConfig] = {
         _sc2_3b,
         _sc2_7b,
         _llama4,
+        _kimi_vl,
     )
 }
 

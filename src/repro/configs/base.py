@@ -58,6 +58,30 @@ class ModelConfig:
     # local to each shard instead of all-gathering the cache every layer.
     kv_replicate: int = 1
 
+    # latent attention (MLA): ``kv_lora_rank`` > 0 gives every layer one
+    # cache entry per token, the normed latent beside the rotated key shared
+    # by all heads (``qk_rope_head_dim`` wide); queries and keys split into
+    # a position-free part and a rotated part; values are ``v_head_dim``
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    norm_eps: float = 1e-6  # RMSNorm epsilon of the latent-attention family
+
+    # expert stacks with leading dense layers (DeepSeek-V2/V3 layout, the
+    # latent family): the first ``first_k_dense`` layers run a SwiGLU of
+    # ``dense_d_ff``; the rest route by noaux_tc (top-k of sigmoid scores
+    # plus a correction bias, weights the unbiased scores normalised over
+    # the chosen and times ``moe_routed_scale``). ``moe_router_experts`` is
+    # the router's width when a shard holds fewer experts (0: every expert
+    # is held); ``moe_n_shared`` shared experts of ``d_ff`` each run as one
+    # SwiGLU of their summed width.
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
+    moe_router_experts: int = 0
+    moe_routed_scale: float = 1.0
+    moe_n_shared: int = 0
+
     # distribution
     fsdp: bool = False  # additionally shard weights over the data axis
     remat: bool = True
@@ -95,6 +119,15 @@ class ModelConfig:
         return cfg
 
     @property
+    def is_latent(self) -> bool:
+        """Latent attention with leading dense layers and routed experts."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def router_width(self) -> int:
+        return self.moe_router_experts or self.n_experts
+
+    @property
     def is_encdec(self) -> bool:
         return self.arch_type == "audio"
 
@@ -111,6 +144,13 @@ class ModelConfig:
         while n_heads % n_kv:
             n_kv -= 1
         scale = d_model / self.d_model
+        if self.is_latent:
+            latent = dict(kv_lora_rank=64, qk_nope_head_dim=32, qk_rope_head_dim=16,
+                          v_head_dim=32, first_k_dense=min(self.first_k_dense, 1),
+                          dense_d_ff=max(64, int(self.dense_d_ff * scale)),
+                          moe_router_experts=min(self.router_width, 8))
+        else:
+            latent = {}
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",
@@ -132,6 +172,7 @@ class ModelConfig:
             frontend_tokens=min(self.frontend_tokens, 16) if self.frontend_tokens else 0,
             encoder_layers=min(self.encoder_layers, 2) if self.encoder_layers else 0,
             fsdp=False,
+            **latent,
         )
 
 

@@ -326,7 +326,7 @@ def pack_checksums(layers, names, block_rows: int = 8):
     return out
 
 
-SITE_KINDS = ("hidden_attn", "hidden_mlp", "ffn", "attn_out")
+SITE_KINDS = ("hidden_attn", "hidden_mlp", "ffn", "attn_out", "expert_ffn")
 
 
 def normalize_site_sparsity(sparsity) -> dict:
@@ -341,12 +341,29 @@ def normalize_site_sparsity(sparsity) -> dict:
 def decode_site_shapes(cfg):
     """[(site kind, input rows, output cols per sharing matrix)] for every
     sparsification site of one decoder layer (paper App. A: q/k/v share the
-    hidden mask, gate/up share theirs; MoE FFNs have no dense MLP sites).
+    hidden mask, gate/up share theirs; the capacity-dispatched MoE FFNs
+    have no planned site, the latent family's held experts one,
+    ``expert_ffn``; ``site_layers`` says which layers each site spans).
     The single source of truth for the site geometry, shared by
     SparseExecution (selection sites + latency tables) and
     ``ComputeModel.decode_layer_seconds`` (the overlap pipeline's compute
     lane) — the two must never drift apart."""
     d = cfg.d_model
+    if cfg.is_latent:
+        # latent attention: q and the latent share the hidden mask; the
+        # leading dense layers' MLP; each held routed expert's F rows of
+        # gate, up and down as one row space
+        sites = [
+            ("hidden_attn", d, (cfg.n_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim),
+                                cfg.kv_lora_rank + cfg.qk_rope_head_dim)),
+            ("attn_out", cfg.n_heads * cfg.v_head_dim, (d,)),
+        ]
+        if cfg.first_k_dense:
+            sites.append(("hidden_mlp", d, (cfg.dense_d_ff, cfg.dense_d_ff)))
+            sites.append(("ffn", cfg.dense_d_ff, (d,)))
+        if cfg.n_layers > cfg.first_k_dense:
+            sites.append(("expert_ffn", cfg.n_experts * cfg.d_ff, (d, d, d)))
+        return sites
     hd_all = cfg.n_heads * cfg.resolved_head_dim
     kv_all = cfg.n_kv_heads * cfg.resolved_head_dim
     sites = [
@@ -357,6 +374,17 @@ def decode_site_shapes(cfg):
         sites.append(("hidden_mlp", d, (cfg.d_ff, cfg.d_ff)))
         sites.append(("ffn", cfg.d_ff, (d,)))
     return sites
+
+
+def site_layers(cfg, kind: str) -> range:
+    """The layers a site spans: every layer, except in the latent family,
+    whose dense MLP sites span the leading dense layers and whose expert
+    site spans the rest."""
+    if cfg.is_latent and kind in ("hidden_mlp", "ffn"):
+        return range(cfg.first_k_dense)
+    if cfg.is_latent and kind == "expert_ffn":
+        return range(cfg.first_k_dense, cfg.n_layers)
+    return range(cfg.n_layers)
 
 
 @dataclasses.dataclass
@@ -396,11 +424,10 @@ class ComputeModel:
         make the model's notion of "hidden" match the kernel's; None keeps
         the uniform first-order vector."""
         sp = normalize_site_sparsity(sparsity)
-        sec = sum(
-            self.matmul_seconds((1.0 - sp.get(kind, 0.0)) * n, sum(cols), tokens)
-            for kind, n, cols in decode_site_shapes(cfg)
-        )
-        out = np.full((cfg.n_layers,), sec, np.float64)
+        out = np.zeros((cfg.n_layers,), np.float64)
+        for kind, n, cols in decode_site_shapes(cfg):
+            sec = self.matmul_seconds((1.0 - sp.get(kind, 0.0)) * n, sum(cols), tokens)
+            out[list(site_layers(cfg, kind))] += sec
         if layer_scale is not None:
             scale = np.asarray(layer_scale, np.float64).reshape(-1)
             if scale.shape != (cfg.n_layers,):

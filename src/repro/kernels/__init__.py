@@ -7,6 +7,8 @@ from .backend import (
     validate_backend,
 )
 from .chunk_gather_dma import (
+    chunk_gather_expert_down_dma,
+    chunk_gather_expert_up_dma,
     chunk_gather_matmul_dma,
     chunk_gather_mlp_dma,
     masks_to_block_tables,

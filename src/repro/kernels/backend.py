@@ -44,6 +44,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .chunk_gather_dma import (
+    chunk_gather_expert_down_dma,
+    chunk_gather_expert_up_dma,
     chunk_gather_matmul_dma,
     chunk_gather_mlp_dma,
     chunk_lanes,
@@ -66,6 +68,7 @@ def blocked_masked_matmul(
     w: jnp.ndarray,  # (N, D); int8 payload when scales is given
     block_rows: int = 8,
     scales: jnp.ndarray | None = None,  # (N // block_rows,) f32 per-block
+    operand_dtype=None,
 ) -> jnp.ndarray:
     """The DMA gather kernel's schedule twin: y = Σ_sb xm_sb @ w_sb over
     ascending super-blocks of ``superblock_rows(N)`` rows, f32
@@ -87,7 +90,9 @@ def blocked_masked_matmul(
     the twins stay bitwise equal at 8 bits.
 
     The contraction runs at ``Precision.HIGHEST``, as the kernel's does: on
-    TPU the default f32 matmul precision is a single bf16 pass."""
+    TPU the default f32 matmul precision is a single bf16 pass. With
+    ``operand_dtype`` (the expert down phase) both operands are cast to it
+    and contracted in one pass into f32 instead, as that kernel does."""
     b, n = xm.shape
     d = w.shape[1]
     if n % block_rows:
@@ -97,13 +102,15 @@ def blocked_masked_matmul(
     xm = xm.astype(jnp.float32)
     if scales is not None:
         xm = xm * jnp.repeat(scales.astype(jnp.float32), block_rows)[None, :]
+    dtype = jnp.float32 if operand_dtype is None else operand_dtype
     # (super-block, piece) pairs as one batch dimension
-    xb = jnp.repeat(xm.reshape(b, nsb, sb).transpose(1, 0, 2), nc, axis=0)
-    wb = (w.astype(jnp.float32).reshape(nsb, sb, nc, c)
+    xb = jnp.repeat(xm.astype(dtype).reshape(b, nsb, sb).transpose(1, 0, 2), nc, axis=0)
+    wb = (w.astype(dtype).reshape(nsb, sb, nc, c)
           .transpose(0, 2, 1, 3).reshape(nsb * nc, sb, c))
     parts = jax.lax.dot_general(
         xb, wb, (((2,), (1,)), ((0,), (0,))),
-        precision=jax.lax.Precision.HIGHEST,
+        precision=(jax.lax.Precision.HIGHEST if operand_dtype is None
+                   else jax.lax.Precision.DEFAULT),
         preferred_element_type=jnp.float32,
     ).reshape(nsb, nc, b, c).transpose(0, 2, 1, 3).reshape(nsb, b, d)
     return jax.lax.fori_loop(
@@ -309,3 +316,62 @@ class ExecutionBackend:
         h = g * (1.0 / (1.0 + jnp.exp(-g))) * u
         y = blocked_masked_matmul(h * fm[None, :], w_down, self.block_rows, sd)
         return jax.lax.optimization_barrier((y, h))
+
+    # -- routed experts, read in place in the stacked weights ----------------
+    @jax.named_scope("decode.gather")
+    def expert_up(
+        self,
+        w_gate: jnp.ndarray,  # (L, E, d, F) the whole stack
+        w_up: jnp.ndarray,  # (L, E, d, F)
+        layer: jnp.ndarray,  # () int32
+        ids: jnp.ndarray,  # (E,) routed held experts first
+        count: jnp.ndarray,  # () how many are routed
+        x: jnp.ndarray,  # (B, d)
+    ) -> jnp.ndarray:
+        """act (B, E·F) f32: silu(x · gate) * (x · up) in the columns of
+        the routed experts, zero in the others. The kernel reads each routed
+        expert's matrices in place (``chunk_gather_expert_up_dma``); the
+        twin computes every held expert of the layer with the kernel's
+        per-expert contraction and zeroes the unrouted."""
+        x = jax.lax.optimization_barrier(x)
+        if self.is_kernel:
+            act = chunk_gather_expert_up_dma(
+                w_gate, w_up, layer, ids, count, x,
+                prefetch_depth=self.prefetch_depth, interpret=self.interpret)
+        else:
+            wg, wu = w_gate[layer], w_up[layer]
+            e, d, f = wg.shape
+            xb = jnp.broadcast_to(x.astype(wg.dtype)[None], (e,) + x.shape)
+            dims = (((2,), (1,)), ((0,), (0,)))
+            g = jax.lax.dot_general(xb, wg, dims, preferred_element_type=jnp.float32)
+            u = jax.lax.dot_general(xb, wu, dims, preferred_element_type=jnp.float32)
+            on = jnp.zeros((e,), bool).at[ids].set(jnp.arange(e) < count)
+            act = jnp.where(on[:, None, None], g * (1.0 / (1.0 + jnp.exp(-g))) * u, 0.0)
+            act = act.transpose(1, 0, 2).reshape(x.shape[0], e * f)
+        return jax.lax.optimization_barrier(act)
+
+    @jax.named_scope("decode.gather")
+    def expert_down(
+        self,
+        w_down: jnp.ndarray,  # (L, E, F, d) the whole stack
+        layer: jnp.ndarray,  # () int32
+        x: jnp.ndarray,  # (B, E·F)
+        mask: jnp.ndarray,  # (E·F,) exact selected rows
+        starts: jnp.ndarray,  # (K,) block table over the E·F rows
+        sizes: jnp.ndarray,
+    ) -> jnp.ndarray:
+        """y (B, d) f32 = (x · mask) @ down[layer] rows, bf16 operands into
+        f32: the kernel gathers the table's rows in place
+        (``chunk_gather_expert_down_dma``), the twin contracts the same
+        super-blocks in the same order."""
+        x, mask = jax.lax.optimization_barrier((x, mask))
+        xm = (x * mask.astype(x.dtype)).astype(jnp.float32)
+        if self.is_kernel:
+            y = chunk_gather_expert_down_dma(
+                w_down, layer, xm, starts, sizes, block_rows=self.block_rows,
+                prefetch_depth=self.prefetch_depth, interpret=self.interpret)
+        else:
+            _, e, f, d = w_down.shape
+            y = blocked_masked_matmul(xm, w_down[layer].reshape(e * f, d),
+                                      self.block_rows, operand_dtype=w_down.dtype)
+        return jax.lax.optimization_barrier(y)

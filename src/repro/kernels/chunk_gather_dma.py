@@ -826,3 +826,228 @@ def chunk_gather_mlp_dma(
             vmem_limit_bytes=_vmem_limit(vmem_bytes)),
         interpret=interpret,
     )(*operands)
+
+
+# ---------------------------------------------------------------------------
+# routed experts, addressed in place in the stacked weights
+# ---------------------------------------------------------------------------
+#
+# An expert layer of the latent-attention family holds its experts stacked
+# over layers: gate and up (L, E, d, F), down (L, E, F, d). Both phases take
+# the whole stack as an HBM operand with the layer index (and, for gate/up,
+# the routed experts' ids) scalar-prefetched, so the DMAs read the bytes in
+# place: no layer's slice is ever copied out of the stack.
+#
+#   * up phase — one step per routed expert: its whole gate and up matrices
+#     (every row of a routed expert is a candidate, and the rows' importance
+#     is only known once both have run) as one DMA each, then
+#     act = silu(x · gate) * (x · up) into the expert's columns of the
+#     (B, E·F) output; unrouted experts stay zero and cost nothing;
+#   * down phase — the selected rows of down, (L, E·F, d) viewed as one
+#     row space, gathered by 128-row super-blocks off the block table
+#     exactly as ``chunk_gather_matmul_dma`` does, for the layer given.
+#
+# Both contract bf16 operands into f32 (the MXU's single pass); the
+# reference twin (``kernels/backend.py``) contracts the same pieces.
+
+
+def _expert_up_kernel(
+    ids_ref,  # scalar prefetch (E,): routed held experts first
+    count_ref,  # scalar prefetch (1,): how many are routed
+    layer_ref,  # scalar prefetch (1,): the layer within the stack
+    x_ref,  # (B, d) bf16 VMEM
+    wg_hbm,  # (L, E, d, F) ANY
+    wu_hbm,  # (L, E, d, F) ANY
+    out_ref,  # (B, E·F) f32 VMEM
+    gslots,  # (n_slots, d, F)
+    uslots,
+    sems,
+    *,
+    d_ff: int,
+    n_slots: int,
+):
+    layer = layer_ref[0]
+
+    def copies(step, slot, wait):
+        e = ids_ref[step]
+        for hbm, slots in ((wg_hbm, gslots), (wu_hbm, uslots)):
+            c = pltpu.make_async_copy(hbm.at[layer, e], slots.at[slot], sems.at[slot])
+            c.wait() if wait else c.start()
+
+    def wait_and_compute(step, slot):
+        copies(step, slot, True)
+        x = x_ref[...]
+        g = jnp.dot(x, gslots[slot], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, uslots[slot], preferred_element_type=jnp.float32)
+        col = pl.multiple_of(ids_ref[step] * d_ff, 128 if d_ff % 128 == 0 else 8)
+        out_ref[:, pl.ds(col, d_ff)] = g * (1.0 / (1.0 + jnp.exp(-g))) * u
+
+    out_ref[...] = jnp.zeros_like(out_ref)
+    _pipelined_steps(count_ref[0], n_slots,
+                     lambda step, slot: copies(step, slot, False), wait_and_compute)
+
+
+def _expert_down_kernel(
+    offs_ref,  # scalar prefetch (E·F // sb_rows,): covered super-blocks
+    bits_ref,  # scalar prefetch: their covered blocks
+    count_ref,  # scalar prefetch (1,)
+    layer_ref,  # scalar prefetch (1,)
+    x_ref,  # (B, E·F) f32 VMEM, pre-masked
+    w_hbm,  # (L, E·F, d) ANY
+    out_ref,  # (B, tile_d) f32
+    wslots,
+    sems,
+    *,
+    block_rows: int,
+    sb_rows: int,
+    tile_d: int,
+    chunk: int,
+    n_slots: int,
+):
+    col = pl.multiple_of(pl.program_id(0) * tile_d, tile_d)
+    lane_blk = _lane_blocks(sb_rows, block_rows)
+    streams = ((w_hbm.at[layer_ref[0]], wslots, sems),)
+
+    def step_args(step):
+        return pl.multiple_of(offs_ref[step], sb_rows), bits_ref[step]
+
+    def start_copy(step, slot, wait=False):
+        off, bits = step_args(step)
+        _superblock_copies(streams, off, bits, slot, col, tile_d, sb_rows,
+                           block_rows, wait)
+
+    def wait_and_compute(step, slot):
+        start_copy(step, slot, wait=True)
+        off, bits = step_args(step)
+        xb = jnp.where(_covered_lanes(bits, lane_blk),
+                       x_ref[:, pl.ds(off, sb_rows)], 0.0).astype(wslots.dtype)
+
+        def piece(c, carry):
+            lo = pl.multiple_of(c * chunk, chunk)
+            out_ref[:, pl.ds(lo, chunk)] += jnp.dot(
+                xb, wslots[slot, :, pl.ds(lo, chunk)],
+                preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, tile_d // chunk, piece, None)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        _zero_slots(wslots, chunk)
+
+    out_ref[...] = jnp.zeros_like(out_ref)
+    _pipelined_steps(count_ref[0], n_slots, start_copy, wait_and_compute)
+
+
+@functools.partial(jax.jit, static_argnames=("prefetch_depth", "interpret"))
+def chunk_gather_expert_up_dma(
+    w_gate: jnp.ndarray,  # (L, E, d, F) the whole stack
+    w_up: jnp.ndarray,  # (L, E, d, F)
+    layer: jnp.ndarray,  # () int32
+    ids: jnp.ndarray,  # (E,) int32: routed held experts first
+    count: jnp.ndarray,  # () int32: how many are routed
+    x: jnp.ndarray,  # (B, d)
+    *,
+    prefetch_depth: int = 1,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """The expert gather's up phase: act (B, E·F) f32, expert ``e``'s
+    columns silu(x · gate[layer, e]) * (x · up[layer, e]) for the first
+    ``count`` experts of ``ids``, zero for the rest. One pipeline step per
+    routed expert; its gate and up come in whole, read in place."""
+    n_layers, e, d, f = w_gate.shape
+    b = x.shape[0]
+    if w_up.shape != w_gate.shape:
+        raise ValueError("w_gate/w_up shape mismatch")
+    if prefetch_depth < 0:
+        raise ValueError(f"prefetch_depth must be >= 0, got {prefetch_depth}")
+    n_slots = prefetch_depth + 1
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(),
+        in_specs=[vmem, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=vmem,
+        scratch_shapes=[
+            pltpu.VMEM((n_slots, d, f), w_gate.dtype),
+            pltpu.VMEM((n_slots, d, f), w_up.dtype),
+            pltpu.SemaphoreType.DMA((n_slots,)),
+        ],
+    )
+    slot_bytes = 2 * n_slots * d * f * jnp.dtype(w_gate.dtype).itemsize
+    vmem_bytes = slot_bytes + 4 * _rows8(b) * (d + e * f + 2 * f)
+    return pl.pallas_call(
+        functools.partial(_expert_up_kernel, d_ff=f, n_slots=n_slots),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, e * f), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(vmem_bytes)),
+        interpret=interpret,
+        name="chunk_gather_expert_dma",
+    )(ids.astype(jnp.int32), count.reshape(1).astype(jnp.int32),
+      layer.reshape(1).astype(jnp.int32), x.astype(w_gate.dtype), w_gate, w_up)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_rows", "prefetch_depth", "interpret")
+)
+def chunk_gather_expert_down_dma(
+    w_down: jnp.ndarray,  # (L, E, F, d) the whole stack
+    layer: jnp.ndarray,  # () int32
+    x: jnp.ndarray,  # (B, E·F) pre-masked intermediate
+    starts: jnp.ndarray,  # (K,) block table over the E·F rows
+    sizes: jnp.ndarray,  # (K,)
+    *,
+    block_rows: int = 8,
+    prefetch_depth: int = 1,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """The expert gather's down phase: y (B, d) f32 = Σ over the covered
+    super-blocks of x_sb · down[layer] rows, the (E, F) rows of the layer
+    taken as one row space (a view of the stack, no copy), fetched as
+    ``chunk_gather_matmul_dma`` fetches and contracted in bf16 into f32."""
+    n_layers, e, f, d = w_down.shape
+    n = e * f
+    b = x.shape[0]
+    if prefetch_depth < 0:
+        raise ValueError(f"prefetch_depth must be >= 0, got {prefetch_depth}")
+    if f % block_rows:
+        raise ValueError(f"F={f} must be a multiple of block_rows={block_rows}")
+    n_slots = prefetch_depth + 1
+    sb = superblock_rows(n, block_rows)
+    if f % sb:
+        raise ValueError(f"a {sb}-row super-block must not straddle experts of {f} rows")
+    slot_col = n_slots * sb * jnp.dtype(w_down.dtype).itemsize
+    tile_d = column_tile(d, slot_col)
+    offs, bits, count = block_list(starts, sizes, n, sb, block_rows)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(d // tile_d,),
+        in_specs=[pl.BlockSpec((b, n), lambda dj, *_: (0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((b, tile_d), lambda dj, *_: (0, dj)),
+        scratch_shapes=[
+            pltpu.VMEM((n_slots, sb, tile_d), w_down.dtype),
+            pltpu.SemaphoreType.DMA((n_slots,)),
+        ],
+    )
+    vmem_bytes = slot_col * tile_d + 2 * 4 * _rows8(b) * (n + tile_d)
+    return pl.pallas_call(
+        functools.partial(
+            _expert_down_kernel,
+            block_rows=block_rows,
+            sb_rows=sb,
+            tile_d=tile_d,
+            chunk=chunk_lanes(d),
+            n_slots=n_slots,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit(vmem_bytes)),
+        interpret=interpret,
+        name="chunk_gather_expert_dma",
+    )(offs, bits, count, layer.reshape(1).astype(jnp.int32),
+      x.astype(jnp.float32), w_down.reshape(n_layers, n, d))

@@ -509,3 +509,108 @@ def project_kv_for_decode(
         pos = jnp.broadcast_to(jnp.reshape(length, (-1, 1)), (b, 1))
         k = apply_rope(k, pos, rope_theta)
     return k, v
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2): one cache entry per token
+# ---------------------------------------------------------------------------
+
+
+def latent_attention_param_defs(
+    d_model: int, n_heads: int, kv_rank: int, nope: int, rope: int, v_dim: int
+) -> Dict[str, ParamDef]:
+    """q straight from the hidden state (no q-LoRA); ``wkv_a`` gives the
+    latent and the shared rotated key, ``wkv_b`` maps the normed latent to
+    each head's position-free key and value."""
+    return {
+        "wq": ParamDef((d_model, n_heads * (nope + rope)), ("embed", "heads")),
+        "wkv_a": ParamDef((d_model, kv_rank + rope), ("embed", None)),
+        "kv_norm_w": ParamDef((kv_rank,), (None,), init="ones"),
+        "wkv_b": ParamDef((kv_rank, n_heads * (nope + v_dim)), (None, "heads")),
+        "wo": ParamDef((n_heads * v_dim, d_model), ("heads", "embed")),
+    }
+
+
+def latent_query(q: jnp.ndarray, positions: jnp.ndarray, n_heads: int, nope: int,
+                 rope: int, rope_theta: float) -> jnp.ndarray:
+    """(b, s, H·(nope+rope)) q projection → (b, s, H, nope+rope) with the
+    last ``rope`` dimensions of each head rotated."""
+    b, s, _ = q.shape
+    q = q.reshape(b, s, n_heads, nope + rope)
+    q_rot = apply_rope(q[..., nope:], positions, rope_theta)
+    return jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+
+
+def latent_entry(a: jnp.ndarray, positions: jnp.ndarray, norm_w: jnp.ndarray,
+                 kv_rank: int, rope_theta: float, eps: float) -> jnp.ndarray:
+    """The cache entry from the ``wkv_a`` projection ``a`` (b, s, R + rope):
+    the RMS-normed latent beside the rotated shared key, (b, s, R + rope)."""
+    from .common import rms_norm
+
+    c = rms_norm(a[..., :kv_rank], norm_w, eps)
+    k_rot = apply_rope(a[..., None, kv_rank:], positions, rope_theta)[..., 0, :]
+    return jnp.concatenate([c, k_rot.astype(c.dtype)], axis=-1)
+
+
+def latent_attention_expanded(q: jnp.ndarray, entries: jnp.ndarray,
+                              wkv_b: jnp.ndarray, n_heads: int, nope: int,
+                              v_dim: int) -> jnp.ndarray:
+    """Causal self-attention in the expanded form (prefill): each head's
+    key is its ``wkv_b`` key of the latent beside the shared rotated key,
+    its value the ``wkv_b`` value. q (b, s, H, nope+rope), entries
+    (b, s, R+rope) → (b, s, H·v_dim) in q's dtype."""
+    b, s, h, qk = q.shape
+    kv_rank = entries.shape[-1] - (qk - nope)
+    kv = (entries[..., :kv_rank] @ wkv_b).reshape(b, s, h, nope + v_dim)
+    k_rot = jnp.broadcast_to(entries[:, :, None, kv_rank:], (b, s, h, qk - nope))
+    k = jnp.concatenate([kv[..., :nope], k_rot.astype(kv.dtype)], axis=-1)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * qk**-0.5
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, kv[..., nope:],
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype).reshape(b, s, h * v_dim)
+
+
+@jax.named_scope("decode.latent_attention")
+def latent_attention_absorbed(q: jnp.ndarray, cache: jnp.ndarray, valid: jnp.ndarray,
+                              wkv_b: jnp.ndarray, nope: int,
+                              v_dim: int) -> jnp.ndarray:
+    """Attention over the latent cache alone (decode and append): the
+    position-free query is taken through ``wkv_b``'s key half into latent
+    space, scores are latent·latent plus rotated·rotated, and the weighted
+    latent is taken out through the value half. q (b, n, H, nope+rope),
+    cache (b, P, R+rope) holding this call's entries, valid (b, n, P) →
+    (b, n, H·v_dim) in q's dtype. Operands bf16, accumulation f32."""
+    b, n, h, qk = q.shape
+    kv_rank = cache.shape[-1] - (qk - nope)
+    w = wkv_b.reshape(kv_rank, h, nope + v_dim)
+
+    def mm(spec, x, y):
+        # bf16 values in f32 operands: on a TPU the default f32 matmul is the
+        # same single bf16 pass; XLA:CPU has no bf16 x bf16 = f32 kernel for
+        # some of these shapes
+        return jnp.einsum(spec, x.astype(jnp.float32), y.astype(jnp.float32))
+
+    q_lat = mm("bnhk,rhk->bnhr", q[..., :nope], w[..., :nope]).astype(q.dtype)
+    scores = (mm("bnhr,bpr->bhnp", q_lat, cache[..., :kv_rank])
+              + mm("bnhe,bpe->bhnp", q[..., nope:], cache[..., kv_rank:])) * qk**-0.5
+    scores = jnp.where(valid[:, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    o_lat = mm("bhnp,bpr->bnhr", probs, cache[..., :kv_rank]).astype(q.dtype)
+    out = mm("bnhr,rhv->bnhv", o_lat, w[..., nope:])
+    return out.astype(q.dtype).reshape(b, n, h * v_dim)
+
+
+def latent_cache_write(layer: jnp.ndarray, entries: jnp.ndarray,
+                       length: jnp.ndarray) -> jnp.ndarray:
+    """Write ``entries`` (b, n, R+rope) at positions ``length`` .. ``length +
+    n - 1`` of one layer's latent cache (b, P, R+rope); ``length`` a scalar
+    or, one token per row, (b,) (continuous batching)."""
+    if length.ndim == 0:
+        return jax.lax.dynamic_update_slice(layer, entries.astype(layer.dtype),
+                                            (0, length, 0))
+    rows = jnp.arange(layer.shape[0])
+    pos = jnp.minimum(length, layer.shape[1] - 1)
+    return layer.at[rows, pos].set(entries[:, 0].astype(layer.dtype))

@@ -42,10 +42,15 @@ from .ssm import (
     mamba2_state_init,
 )
 from .transformer import (
+    EXPERT_LEAVES,
     apply_norm,
     block_decode,
     block_forward,
     block_param_defs,
+    latent_block_append,
+    latent_block_decode,
+    latent_block_forward,
+    latent_block_param_defs,
     stack_decode,
     stack_forward,
     stack_prefill,
@@ -101,7 +106,9 @@ class Model:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
         self.family = cfg.arch_type
-        if self.family in ("dense", "moe", "vlm"):
+        if cfg.is_latent:
+            self._impl = _LatentLM(cfg)
+        elif self.family in ("dense", "moe", "vlm"):
             self._impl = _DecoderLM(cfg)
         elif self.family == "hybrid":
             self._impl = _Zamba(cfg)
@@ -340,6 +347,178 @@ class _DecoderLM:
         logits = (x[:, 0] @ head.astype(x.dtype)).astype(jnp.float32)
         logits = shard_act(logits, ("batch", "vocab"))
         return logits, cache, io, plan
+
+
+# ---------------------------------------------------------------------------
+# latent attention, leading dense layers, held routed experts (kimi-vl-a3b)
+# ---------------------------------------------------------------------------
+
+
+class _LatentLM:
+    """Two stacks, "dense" (the first ``first_k_dense`` layers) then "moe",
+    each scanned; the cache holds one latent entry per token per layer,
+    ``ckv`` (layers, b, P, kv_lora_rank + qk_rope_head_dim). The planned
+    decode plan's sites span their own layers (``core.offload.site_layers``):
+    each stack's scan gets its slice of every site it has."""
+
+    def __init__(self, cfg: ModelConfig):
+        self.cfg = cfg
+        self.text_offset = 0
+        n_dense = cfg.first_k_dense
+        # (stack, first layer, depth, routed experts?)
+        self.stacks = tuple(
+            (name, first, depth, moe)
+            for name, first, depth, moe in (
+                ("dense", 0, n_dense, False),
+                ("moe", n_dense, cfg.n_layers - n_dense, True))
+            if depth)
+        self.block_defs = {name: latent_block_param_defs(cfg, moe)
+                           for name, _, _, moe in self.stacks}
+
+    def _defs(self):
+        defs = dict(_embed_defs(self.cfg))
+        for name, _, depth, _ in self.stacks:
+            defs[name] = stack_layer_defs(self.block_defs[name], depth)
+        return defs
+
+    def init(self, key):
+        defs = self._defs()
+        keys = jax.random.split(key, len(self.stacks) + 1)
+        top = {k: v for k, v in defs.items() if not isinstance(v, dict)}
+        params, _ = init_params(top, keys[0], COMPUTE_DTYPE)
+        for (name, *_), k in zip(self.stacks, keys[1:]):
+            params[name], _ = init_params(defs[name], k, COMPUTE_DTYPE)
+        return params
+
+    def param_axes(self):
+        return {name: ({k: v.axes for k, v in d.items()} if isinstance(d, dict)
+                       else d.axes)
+                for name, d in self._defs().items()}
+
+    def _embed(self, params, tokens):
+        return jnp.take(params["embed"], tokens, axis=0).astype(COMPUTE_DTYPE)
+
+    def _final(self, params, x):
+        from .common import rms_norm
+
+        return rms_norm(x, params["final_norm_w"], self.cfg.norm_eps)
+
+    def _layers(self, params, x, positions, remat: bool):
+        """Every block over the whole sequence; returns (x, per-layer cache
+        entries (layers, b, s, R + rope))."""
+        cfg, entries = self.cfg, []
+        for name, _, _, moe in self.stacks:
+            def body(h, lp, moe=moe):
+                return latent_block_forward(lp, h, cfg, positions, moe)
+
+            x, ent = jax.lax.scan(jax.checkpoint(body) if remat else body, x,
+                                  params[name])
+            entries.append(ent)
+        return x, jnp.concatenate(entries, axis=0)
+
+    def forward(self, params, batch, remat: bool = True):
+        x = self._embed(params, batch["tokens"])
+        b, s, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+        x, _ = self._layers(params, x, positions, remat)
+        return self._final(params, x), jnp.float32(0.0)
+
+    def init_cache(self, batch_size: int, max_seq: int):
+        cfg = self.cfg
+        width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        return {"ckv": jnp.zeros((cfg.n_layers, batch_size, max_seq, width), COMPUTE_DTYPE),
+                "length": jnp.zeros((), jnp.int32)}
+
+    def cache_axes(self):
+        return {"ckv": ("layer", "batch", "cache_seq", None), "length": ()}
+
+    def prefill(self, params, batch, max_seq: int):
+        x = self._embed(params, batch["tokens"])
+        b, s, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+        x, entries = self._layers(params, x, positions, remat=False)
+        ckv = jnp.pad(entries, ((0, 0), (0, 0), (0, max_seq - s), (0, 0)))
+        x = self._final(params, x)
+        last = x[:, -1] @ params["head"].astype(x.dtype)
+        return last, {"ckv": ckv, "length": jnp.int32(s)}
+
+    def append_embeds(self, params, frame_embeds, cache, sparse_ctx=None):
+        """``frame_embeds`` (b, n, d) appended to the cache (no projector:
+        the frames arrive embedded). Returns (hidden, cache, io)."""
+        cfg, length = self.cfg, cache["length"]
+        x = frame_embeds.astype(COMPUTE_DTYPE)
+        out = []
+        for name, first, depth, moe in self.stacks:
+            def body(h, xs, moe=moe):
+                lp, lc = xs
+                return latent_block_append(lp, h, lc, length, cfg, moe)
+
+            x, lc = jax.lax.scan(body, x, (params[name],
+                                           cache["ckv"][first:first + depth]))
+            out.append(lc)
+        cache = {"ckv": jnp.concatenate(out, axis=0),
+                 "length": length + frame_embeds.shape[1]}
+        return self._final(params, x), cache, jnp.float32(0.0)
+
+    def decode_step(self, params, token, cache, sparse_ctx=None):
+        logits, cache, io, _ = self.decode_step_planned(params, token, cache, sparse_ctx)
+        return logits, cache, jnp.sum(io)
+
+    def _stack_plan(self, plan, first: int, depth: int):
+        """The sites of ``plan`` that span layers first .. first+depth-1,
+        each sliced to them."""
+        from ..core.offload import site_layers
+
+        out = {}
+        for kind, entry in (plan or {}).items():
+            span = site_layers(self.cfg, kind)
+            if first in span:
+                lo = first - span.start
+                out[kind] = jax.tree.map(lambda a, lo=lo: a[lo:lo + depth], entry)
+        return out
+
+    def decode_step_planned(self, params, token, cache, sparse_ctx=None, plan=None,
+                            refresh=None):
+        """One token per row through both stacks. Returns (logits, cache,
+        io (n_layers,), plan), the plan's sites reassembled over their
+        layers."""
+        cfg, length = self.cfg, cache["length"]
+        x = self._embed(params, token)
+        caches, ios, pieces = [], [], {}
+        for name, first, depth, moe in self.stacks:
+            sub = self._stack_plan(plan, first, depth)
+            planned = bool(sub)
+            in_place = moe and "expert_ffn" in sub
+            layer_params = params[name]
+            stacks = None
+            if in_place:
+                stacks = {k: layer_params[k] for k in EXPERT_LEAVES}
+                layer_params = {k: v for k, v in layer_params.items()
+                                if k not in EXPERT_LEAVES}
+
+            def body(h, xs, moe=moe, planned=planned, stacks=stacks):
+                lp, lc, lplan, i = xs
+                h2, lc2, io2, lplan2 = latent_block_decode(
+                    lp, h, lc, length, cfg, moe, sparse_ctx,
+                    plan=lplan if planned else None, refresh=refresh,
+                    experts=None if stacks is None else (stacks, i))
+                return h2, (lc2, io2, lplan2 if planned else lplan)
+
+            x, (lc, io, sub2) = jax.lax.scan(
+                body, x, (layer_params, cache["ckv"][first:first + depth], sub,
+                          jnp.arange(depth, dtype=jnp.int32)))
+            caches.append(lc)
+            ios.append(io)
+            for kind, entry in sub2.items():
+                pieces.setdefault(kind, []).append(entry)
+        new_plan = plan
+        if plan:
+            new_plan = {kind: jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *parts)
+                        for kind, parts in pieces.items()}
+        x = self._final(params, x)
+        logits = (x[:, 0] @ params["head"].astype(x.dtype)).astype(jnp.float32)
+        cache = {"ckv": jnp.concatenate(caches, axis=0), "length": length + 1}
+        return logits, cache, jnp.concatenate(ios), new_plan
 
 
 # ---------------------------------------------------------------------------

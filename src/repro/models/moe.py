@@ -7,9 +7,12 @@ tensor (≈10^12 elements at train_4k scale); the sort-based dispatch below
 replaces it with an argsort + two gathers, which GSPMD lowers to
 all-to-all/all-gather collectives over the same axes.
 
-Implements both assigned MoE architectures:
+Serves three MoE architectures:
   * olmoe-1b-7b:         64 experts, top-8, SwiGLU experts
   * llama4-scout-17b-a16e: 16 experts, top-1 + always-on shared expert
+  * kimi-vl-a3b:         64 experts behind a noaux_tc router, top-6, plus
+    2 shared experts, held as one expert-parallel shard of 8
+    (``held_expert_ffn`` below: no capacity, so no token is dropped)
 """
 from __future__ import annotations
 
@@ -325,3 +328,86 @@ def moe_ffn_ep(
         sh = swish(xt @ params[f"{p}ws_gate"]) * (xt @ params[f"{p}ws_up"])
         y = y + (sh @ params[f"{p}ws_down"]).reshape(b, s, d)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# an expert-parallel shard behind a sigmoid router (noaux_tc): the held part
+# ---------------------------------------------------------------------------
+
+
+def held_expert_param_defs(d_model: int, d_ff: int, n_held: int, router: int,
+                           n_shared: int) -> Dict[str, ParamDef]:
+    """The router over every expert of the layer (``router`` wide) with its
+    correction bias, the ``n_held`` experts this shard holds, and the shared
+    experts as one SwiGLU of ``n_shared * d_ff``."""
+    fs = n_shared * d_ff
+    defs = {
+        "router": ParamDef((d_model, router), ("embed", None), scale=0.02),
+        "router_bias": ParamDef((router,), (None,), init="zeros"),
+        "we_gate": ParamDef((n_held, d_model, d_ff), ("expert", "embed", "ffn")),
+        "we_up": ParamDef((n_held, d_model, d_ff), ("expert", "embed", "ffn")),
+        "we_down": ParamDef((n_held, d_ff, d_model), ("expert", "ffn", "embed")),
+    }
+    if fs:
+        defs.update(ws_gate=ParamDef((d_model, fs), ("embed", "ffn")),
+                    ws_up=ParamDef((d_model, fs), ("embed", "ffn")),
+                    ws_down=ParamDef((fs, d_model), ("ffn", "embed")))
+    return defs
+
+
+def route_sigmoid_bias(logits: jnp.ndarray, bias: jnp.ndarray, top_k: int,
+                       scale: float) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """noaux_tc routing over the whole router: scores s = sigmoid(logits)
+    in f32; the top ``top_k`` of s + ``bias`` are chosen; each weight is s
+    over the sum of the chosen s, times ``scale``. The bias changes which
+    experts are chosen, never their weights. (t, E) → (ids, weights) (t, k)."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, w / jnp.sum(w, axis=-1, keepdims=True) * scale
+
+
+def held_gates(idx: jnp.ndarray, w: jnp.ndarray, first: int, n_held: int) -> jnp.ndarray:
+    """(t, n_held) f32: each token's weight on held experts ``first`` ..
+    ``first + n_held - 1``, 0 where the router did not choose the expert."""
+    held = first + jnp.arange(n_held, dtype=idx.dtype)
+    hit = idx[..., None] == held  # (t, k, n_held)
+    return jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=-2)
+
+
+def router_gates(h: jnp.ndarray, params: Dict[str, jnp.ndarray], top_k: int,
+                 scale: float, n_held: int, first: int = 0) -> jnp.ndarray:
+    """(t, d) → (t, n_held) held-expert weights of ``route_sigmoid_bias``."""
+    logits = jnp.dot(h, params["router"], preferred_element_type=jnp.float32)
+    idx, w = route_sigmoid_bias(logits, params["router_bias"], top_k, scale)
+    return held_gates(idx, w, first, n_held)
+
+
+def shared_experts(h: jnp.ndarray, params: Dict[str, jnp.ndarray]) -> jnp.ndarray:
+    """The shared experts' SwiGLU (dense; (t, d) → (t, d) in h's dtype)."""
+    g = jnp.dot(h, params["ws_gate"], preferred_element_type=jnp.float32)
+    u = jnp.dot(h, params["ws_up"], preferred_element_type=jnp.float32)
+    mid = (swish(g) * u).astype(h.dtype)
+    return jnp.dot(mid, params["ws_down"], preferred_element_type=jnp.float32).astype(h.dtype)
+
+
+def held_expert_ffn(x: jnp.ndarray, params: Dict[str, jnp.ndarray], top_k: int,
+                    scale: float, first: int = 0, shared: bool = True) -> jnp.ndarray:
+    """The held shard's part of a routed layer, (b, s, d) → (b, s, d): the
+    router chooses over all of its experts; every held expert runs on every
+    token, weighted by that token's gate (0 if not chosen), so no token is
+    ever dropped; the shared experts are added when ``shared`` (one shard of
+    a deployment adds them, the others not). Prefill, frame append and the
+    unplanned decode path; the planned decode runs ``expert_ffn_planned``."""
+    b, s, d = x.shape
+    h = x.reshape(b * s, d)
+    gates = router_gates(h, params, top_k, scale, params["we_gate"].shape[0], first)
+    f32 = jnp.float32
+    g = jnp.einsum("td,edf->tef", h, params["we_gate"], preferred_element_type=f32)
+    u = jnp.einsum("td,edf->tef", h, params["we_up"], preferred_element_type=f32)
+    mid = (swish(g) * u * gates[..., None]).astype(x.dtype)
+    y = jnp.einsum("tef,efd->td", mid, params["we_down"], preferred_element_type=f32)
+    y = y.astype(x.dtype)
+    if shared and "ws_gate" in params:
+        y = y + shared_experts(h, params)
+    return y.reshape(b, s, d)

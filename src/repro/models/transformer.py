@@ -13,6 +13,7 @@ latency estimate. Dense training never pays for this path.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -31,6 +32,12 @@ from .attention import (
     cache_layer_update,
     decode_attention,
     gather_paged_kv,
+    latent_attention_absorbed,
+    latent_attention_expanded,
+    latent_attention_param_defs,
+    latent_cache_write,
+    latent_entry,
+    latent_query,
     multi_head_attention,
     project_kv_for_decode,
     scatter_paged_kv,
@@ -44,7 +51,15 @@ from .mlp import (
     swiglu_mlp,
     swiglu_mlp_planned,
 )
-from .moe import MoEConfig, moe_ffn, moe_param_defs
+from .moe import (
+    MoEConfig,
+    held_expert_ffn,
+    held_expert_param_defs,
+    moe_ffn,
+    moe_param_defs,
+    router_gates,
+    shared_experts,
+)
 
 
 def _norm_defs(cfg: ModelConfig, name: str) -> Dict[str, ParamDef]:
@@ -636,3 +651,154 @@ def stack_prefill(
     (x, aux), (ks, vs) = jax.lax.scan(body_fn, (x, jnp.float32(0.0)), stacked)
     cache = {"k": ks, "v": vs, "length": jnp.int32(x.shape[1])}
     return x, aux, cache
+
+
+# ---------------------------------------------------------------------------
+# the latent-attention family (kimi-vl-a3b): leading dense layers, then
+# routed-expert layers, each with latent attention and one cache entry
+# ---------------------------------------------------------------------------
+
+# the stacked expert leaves the planned decode reads in place (never a
+# scanned layer slice)
+EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+
+
+def latent_block_param_defs(cfg: ModelConfig, moe: bool) -> Dict[str, ParamDef]:
+    d = cfg.d_model
+    defs = {"ln1_w": ParamDef((d,), ("embed",), init="ones"),
+            "ln2_w": ParamDef((d,), ("embed",), init="ones")}
+    defs.update(latent_attention_param_defs(
+        d, cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+        cfg.qk_rope_head_dim, cfg.v_head_dim))
+    if moe:
+        defs.update(held_expert_param_defs(d, cfg.d_ff, cfg.n_experts,
+                                           cfg.router_width, cfg.moe_n_shared))
+    else:
+        defs.update(mlp_param_defs(d, cfg.dense_d_ff))
+    return defs
+
+
+def dense_mlp_cfg(cfg: ModelConfig) -> ModelConfig:
+    """The leading dense layers' MLP as the dense family sees it."""
+    return dataclasses.replace(cfg, d_ff=cfg.dense_d_ff, n_experts=0, mlp="swiglu",
+                               kv_lora_rank=0)
+
+
+def _latent_ffn(h, params, cfg: ModelConfig, moe: bool):
+    if moe:
+        return held_expert_ffn(h, params, cfg.moe_top_k, cfg.moe_routed_scale)
+    return swiglu_mlp(h, params)
+
+
+def _latent_qkv(h, params, cfg: ModelConfig, positions, q_lin=None, a=None):
+    """The latent block's query (b, s, H, nope+rope) and cache entries
+    (b, s, R+rope); ``q_lin``/``a``: the projections, when the planned
+    decode has computed them through the execution backend."""
+    q_lin = h @ params["wq"] if q_lin is None else q_lin
+    a = h @ params["wkv_a"] if a is None else a
+    q = latent_query(q_lin, positions, cfg.n_heads, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.rope_theta)
+    entries = latent_entry(a, positions, params["kv_norm_w"], cfg.kv_lora_rank,
+                           cfg.rope_theta, cfg.norm_eps)
+    return q, entries
+
+
+def latent_block_forward(params, x, cfg: ModelConfig, positions, moe: bool):
+    """A whole sequence through one block (forward, prefill), attention in
+    the expanded form. Returns (x_out, the layer's cache entries)."""
+    h = rms_norm(x, params["ln1_w"], cfg.norm_eps)
+    q, entries = _latent_qkv(h, params, cfg, positions)
+    attn = latent_attention_expanded(q, entries, params["wkv_b"], cfg.n_heads,
+                                     cfg.qk_nope_head_dim, cfg.v_head_dim)
+    x = x + attn @ params["wo"]
+    h = rms_norm(x, params["ln2_w"], cfg.norm_eps)
+    return x + _latent_ffn(h, params, cfg, moe), entries
+
+
+def latent_block_append(params, x, layer_cache, length, cfg: ModelConfig, moe: bool):
+    """``x`` (b, n, d) appended after ``length`` cached tokens (frame
+    append), attending over the latent cache. Returns (x_out, cache)."""
+    b, n, _ = x.shape
+    pos = jnp.broadcast_to(length + jnp.arange(n)[None, :], (b, n))
+    h = rms_norm(x, params["ln1_w"], cfg.norm_eps)
+    q, entries = _latent_qkv(h, params, cfg, pos)
+    layer_cache = latent_cache_write(layer_cache, entries, length)
+    valid = jnp.arange(layer_cache.shape[1])[None, None, :] <= pos[:, :, None]
+    attn = latent_attention_absorbed(q, layer_cache, valid, params["wkv_b"],
+                                     cfg.qk_nope_head_dim, cfg.v_head_dim)
+    x = x + attn @ params["wo"]
+    h = rms_norm(x, params["ln2_w"], cfg.norm_eps)
+    return x + _latent_ffn(h, params, cfg, moe), layer_cache
+
+
+def _lane_project(backend, w, x, mask, starts, sizes):
+    """``backend.project`` of a matrix whose width is off the 128-lane grid
+    (``wkv_a``: 512 + 64): its columns are padded with zeros to the grid,
+    inside the copy of the layer's slice the kernel takes anyway, and the
+    padding is cut from the output."""
+    pad = -w.shape[1] % 128
+    if not pad:
+        return backend.project(w, x, mask, starts, sizes)
+    y = backend.project(jnp.pad(w, ((0, 0), (0, pad))), x, mask, starts, sizes)
+    return y[:, : w.shape[1]]
+
+
+def latent_block_decode(params, x, layer_cache, length, cfg: ModelConfig, moe: bool,
+                        sparse_ctx: Any = None, plan=None, refresh=None,
+                        experts=None):
+    """One decode token per row through one block, attention in the
+    absorbed form over the latent cache. With a plan, the hidden_attn
+    (``wq``, ``wkv_a``) and attn_out (``wo``) projections, the dense
+    layer's MLP and the expert layer's experts run through the execution
+    backend off the plan; ``experts`` = (the whole stacked expert leaves,
+    this layer's index in them) for the expert gather. Returns (x_out,
+    cache, io_latency, plan)."""
+    io = jnp.float32(0.0)
+    if sparse_ctx is not None and plan:
+        plan, sel_lat = sparse_ctx.refresh_layer(plan, refresh)
+        io += sel_lat
+    b = x.shape[0]
+    backend = sparse_ctx.backend if sparse_ctx is not None else None
+    h = rms_norm(x, params["ln1_w"], cfg.norm_eps)
+    mask_q, lat, plan = _site_mask(sparse_ctx, "hidden_attn", h, plan)
+    io += lat
+    q_lin = a = None
+    if plan is not None and "hidden_attn" in plan:
+        hs, hz = sparse_ctx.kernel_tables(plan, "hidden_attn")
+        q_lin, a = (_lane_project(backend, params[name], h.reshape(b, -1), mask_q, hs, hz)
+                    .astype(h.dtype).reshape(b, 1, -1) for name in ("wq", "wkv_a"))
+    pos = jnp.broadcast_to(jnp.reshape(length, (-1, 1)), (b, 1))
+    q, entry = _latent_qkv(_apply_mask(h, mask_q), params, cfg, pos, q_lin, a)
+    layer_cache = latent_cache_write(layer_cache, entry, length)
+    valid = (jnp.arange(layer_cache.shape[1])[None, :]
+             < jnp.reshape(length + 1, (-1, 1)))[:, None, :]
+    attn = latent_attention_absorbed(q, layer_cache, jnp.broadcast_to(
+        valid, (b,) + valid.shape[1:]), params["wkv_b"], cfg.qk_nope_head_dim,
+        cfg.v_head_dim)
+    mask_o, lat, plan = _site_mask(sparse_ctx, "attn_out", attn, plan)
+    io += lat
+    if plan is not None and "attn_out" in plan:
+        y_o = backend.project(params["wo"], attn.reshape(b, -1), mask_o,
+                              *sparse_ctx.kernel_tables(plan, "attn_out"))
+        attn = y_o.astype(attn.dtype).reshape(b, 1, -1)
+    else:
+        attn = _apply_mask(attn, mask_o) @ params["wo"]
+    x = x + attn
+    h = rms_norm(x, params["ln2_w"], cfg.norm_eps)
+    if not moe:
+        y, lat, plan = _mlp_maybe_sparse(h, params, dense_mlp_cfg(cfg), sparse_ctx, plan)
+        io += lat
+    elif plan is not None and "expert_ffn" in plan:
+        ht = h.reshape(b, -1)
+        with jax.named_scope("decode.route"):
+            gates = router_gates(ht, params, cfg.moe_top_k, cfg.moe_routed_scale,
+                                 cfg.n_experts)
+        with jax.named_scope("decode.experts"):
+            stacks, layer = experts
+            y, plan, lat = sparse_ctx.run_experts(plan, ht, gates, stacks, layer)
+            y = y.astype(h.dtype) + shared_experts(ht, params)
+        y = y.reshape(b, 1, -1)
+        io += lat
+    else:
+        y = _latent_ffn(h, params, cfg, moe)
+    return x + y, layer_cache, io, plan

@@ -76,12 +76,14 @@ from .degrade import DegradationController
 from .kv_pool import KVPagePool, KVPoolExhausted, prompt_prefix_hashes
 from .sparse_exec import (
     INTEGRITY_COUNTER_KEYS,
+    MOE_COUNTER_KEYS,
     KERNEL_BLOCK_ROWS,
     WBITS_CHOICES,
     SparseExecution,
     plan_hit_miss,
     plan_integrity_counters,
     plan_kernel_counters,
+    plan_moe_counters,
     plan_transfer_bytes,
     reset_plan_counters,
     set_plan_budget_scale,
@@ -119,6 +121,12 @@ class StepStats:
 # super-blocks moved whole, super-blocks moved by blocks, block DMAs
 KERNEL_TRACE_COUNTERS = ("kernel.superblocks_whole",
                          "kernel.superblocks_partial", "kernel.block_dmas")
+
+# the trace counters of the expert site (MOE_COUNTER_KEYS order): held
+# experts whose rows the expert gather streamed, token-expert picks that
+# landed on held experts, and expert layer steps
+MOE_TRACE_COUNTERS = ("moe.experts_streamed", "moe.routed_pairs_held",
+                      "moe.layer_steps")
 
 
 # the exact key set io_summary() returns — the docstring table and
@@ -294,6 +302,11 @@ class ServeEngine:
             )
         if plan_refresh_interval < 1:
             raise ValueError("plan_refresh_interval must be >= 1")
+        if model.cfg.is_latent and (wbits != 16 or kv_page_tokens is not None
+                                    or (mesh is not None and mesh.is_sharded)):
+            raise NotImplementedError(
+                "the latent-attention family serves at wbits 16 on one device "
+                "with the dense latent cache (no int8 chunks, paged KV or mesh)")
         self.mesh = mesh if mesh is not None else ServeMesh.single()
         if self.mesh.is_sharded:
             validate_serve_mesh(
@@ -487,7 +500,9 @@ class ServeEngine:
             dci = (plan_integrity_counters(new_plan)
                    - plan_integrity_counters(plan))
             dk = plan_kernel_counters(new_plan) - plan_kernel_counters(plan)
-            return logits, cache, io, new_plan, h1 - h0, m1 - m0, db, dsb, dci, dk
+            dmoe = plan_moe_counters(new_plan) - plan_moe_counters(plan)
+            return (logits, cache, io, new_plan, h1 - h0, m1 - m0, db, dsb, dci, dk,
+                    dmoe)
 
         self._decode_one = jax.jit(_decode_one_impl)
         self._append = jax.jit(
@@ -526,7 +541,8 @@ class ServeEngine:
 
         Returns (tokens (b, n), final cache, final plan, io (n, n_layers),
         hits (n,), misses (n,), bytes (n,), shard_bytes (n, n_shards),
-        integrity (n, 6), kernel DMAs (n, 3)) — per-step per-layer I/O
+        integrity (n, 6), kernel DMAs (n, 3), expert counters (n, 3)) —
+        per-step per-layer I/O
         estimates plus residency-cache row/byte counters, per-model-shard
         byte splits, integrity-counter deltas (INTEGRITY_COUNTER_KEYS order;
         zeros with corruption off) and the gather kernels' DMA-plan deltas
@@ -551,18 +567,19 @@ class ServeEngine:
             dci = (plan_integrity_counters(new_plan)
                    - plan_integrity_counters(plan))
             dk = plan_kernel_counters(new_plan) - plan_kernel_counters(plan)
+            dmoe = plan_moe_counters(new_plan) - plan_moe_counters(plan)
             with jax.named_scope("decode.sample"):
                 nxt = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
             return (nxt, cache, new_plan), (
-                nxt[:, 0], io, h1 - h0, m1 - m0, db, dsb, dci, dk
+                nxt[:, 0], io, h1 - h0, m1 - m0, db, dsb, dci, dk, dmoe
             )
 
         (_, cache, plan), (toks, ios, hits, misses, byts, sbyts,
-                           civ, kdma) = jax.lax.scan(
+                           civ, kdma, moe) = jax.lax.scan(
             step, (token, cache, plan), jnp.arange(n_tokens)
         )
         # toks: (n, b) -> (b, n)
-        return toks.T, cache, plan, ios, hits, misses, byts, sbyts, civ, kdma
+        return toks.T, cache, plan, ios, hits, misses, byts, sbyts, civ, kdma, moe
 
     def _dense_step_bytes(self) -> float:
         """Per-decode-step transfer volume of the dense streaming policy
@@ -591,7 +608,7 @@ class ServeEngine:
         tokens = self.mesh.put_batch(tokens)
         t0 = time.perf_counter()
         (toks, self.cache, self._plan, ios, hits, misses, byts,
-         sbyts, civ, kdma) = self._decode_scan(
+         sbyts, civ, kdma, moe) = self._decode_scan(
             self.params, tokens, self.cache, n_tokens, self._plan
         )
         # ONE blocking host transfer for the whole scan (per-layer estimates
@@ -599,11 +616,12 @@ class ServeEngine:
         # are ready so the wall below covers the whole decode
         with trace.span("engine.decode.wait"):
             jax.block_until_ready((toks, self.cache))
-            ios, hits, misses, byts, sbyts, civ, kdma = jax.device_get(
-                (ios, hits, misses, byts, sbyts, civ, kdma)
+            ios, hits, misses, byts, sbyts, civ, kdma, moe = jax.device_get(
+                (ios, hits, misses, byts, sbyts, civ, kdma, moe)
             )
         with trace.span("engine.decode.account"):
             self._count_kernel_dmas(kdma)
+            self._count_moe(moe)
             ios = np.asarray(ios, np.float64)  # (n, n_layers)
             hits, misses = np.asarray(hits, np.float64), np.asarray(misses, np.float64)
             byts = np.asarray(byts, np.float64)
@@ -652,6 +670,16 @@ class ServeEngine:
         total = np.asarray(kdma, np.float64).reshape(-1, 3).sum(axis=0)
         if total.any():
             for name, v in zip(KERNEL_TRACE_COUNTERS, total):
+                trace.count(name, int(round(v)))
+
+    @staticmethod
+    def _count_moe(moe) -> None:
+        """Add a decode call's expert-site counter deltas ((..., 3), ordered
+        like MOE_COUNTER_KEYS, on the host) to the trace's counters;
+        nothing for a model without routed experts on the planned path."""
+        total = np.asarray(moe, np.float64).reshape(-1, len(MOE_COUNTER_KEYS)).sum(axis=0)
+        if total[-1] > 0:
+            for name, v in zip(MOE_TRACE_COUNTERS, total):
                 trace.count(name, int(round(v)))
 
     def _decode_lift(self) -> float:
@@ -739,11 +767,12 @@ class ServeEngine:
         for i in range(n_tokens):
             t0 = time.perf_counter()
             (logits, self.cache, io_vec, self._plan, dh, dm, db,
-             dsb, dci, dk) = self._decode_one(
+             dsb, dci, dk, dmoe) = self._decode_one(
                 self.params, token, self.cache, self._plan, jnp.int32(i)
             )
             io_vec = np.asarray(io_vec, np.float64)  # the per-token host sync
             self._count_kernel_dmas(dk)
+            self._count_moe(dmoe)
             dci = np.asarray(dci, np.float64)
             self._integrity_totals += dci
             det_call += float(dci[0])
@@ -944,7 +973,9 @@ class ServeEngine:
             self.cache["page_table"] = self._push_table()
             self.cache["length"] = self.cache["length"].at[slot].set(seq_len)
         else:
-            for key in ("k", "v"):
+            for key in cache1:
+                if key == "length":
+                    continue
                 self.cache[key] = jax.lax.dynamic_update_slice_in_dim(
                     self.cache[key], cache1[key], slot, axis=1
                 )

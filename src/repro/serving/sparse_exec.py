@@ -53,7 +53,7 @@ from ..core.latency_model import (
     profile_table,
     row_stream_bytes,
 )
-from ..core.offload import decode_site_shapes, normalize_site_sparsity
+from ..core.offload import decode_site_shapes, normalize_site_sparsity, site_layers
 from ..core.reorder import Reordering
 from ..sharding.serve import ServeMesh
 
@@ -206,6 +206,27 @@ def plan_integrity_counters(plan) -> jnp.ndarray:
 KERNEL_COUNTER_KEYS = ("sb_whole", "sb_partial", "block_dmas")
 
 
+# sites selected inside their own step rather than by the batched refresh:
+# the expert site's rows are ranked once the router has chosen this step's
+# experts and they have run (``SparseExecution.run_experts``)
+IN_STEP_SITES = ("expert_ffn",)
+
+# per-layer lanes of the expert site, added every decode step: held experts
+# whose rows the kernel streamed, token-expert picks that landed on held
+# experts, and layer steps
+MOE_COUNTER_KEYS = ("experts", "pairs", "steps")
+
+
+def plan_moe_counters(plan) -> jnp.ndarray:
+    """Totals of the expert site's lanes, (3,) float32 ordered like
+    ``MOE_COUNTER_KEYS``; zeros for a plan without an expert site."""
+    out = jnp.zeros((len(MOE_COUNTER_KEYS),), jnp.float32)
+    for state in (plan or {}).values():
+        if isinstance(state, dict) and MOE_COUNTER_KEYS[0] in state:
+            out = out + jnp.stack([jnp.sum(state[k]) for k in MOE_COUNTER_KEYS])
+    return out
+
+
 def plan_kernel_counters(plan) -> jnp.ndarray:
     """Totals of the kernel DMA-plan lanes in a decode plan pytree, (3,)
     float32 ordered like ``KERNEL_COUNTER_KEYS``; zeros when the plan
@@ -230,7 +251,8 @@ def reset_plan_counters(plan):
         if isinstance(state, dict):
             state = dict(state)
             for key in (("hit", "miss", "bytes", "hit_shard", "miss_shard")
-                        + INTEGRITY_COUNTER_KEYS + KERNEL_COUNTER_KEYS):
+                        + INTEGRITY_COUNTER_KEYS + KERNEL_COUNTER_KEYS
+                        + MOE_COUNTER_KEYS):
                 if key in state:
                     state[key] = jnp.zeros_like(state[key])
         out[kind] = state
@@ -396,6 +418,10 @@ class SparseExecution:
                 max_reread=max_reread, recover=corruption_recover,
             )
             if cm.enabled:
+                if cfg.is_latent:
+                    raise ValueError(
+                        "corruption injection does not cover the latent-"
+                        "attention family: its sites stream other matrices")
                 if method not in ("chunk", "topk"):
                     raise ValueError(
                         "corruption injection needs a selecting method "
@@ -466,6 +492,19 @@ class SparseExecution:
         self._budgets = jnp.asarray(
             [int(self.sites[k].budget()) for k in self.site_order], jnp.int32
         )
+        # the layers each site spans, and one batched selector per set of
+        # sites a layer refreshes together (one set, all sites, except in
+        # the latent family: dense layers and expert layers differ, and the
+        # expert site selects inside its step)
+        self.site_span = {kind: site_layers(cfg, kind) for kind in self.sites}
+        self._groups = {self.site_order: (self.batched, self._budgets)}
+        for layer in range(cfg.n_layers):
+            order = tuple(k for k in self.site_order
+                          if layer in self.site_span[k] and k not in IN_STEP_SITES)
+            if order not in self._groups:
+                self._groups[order] = (
+                    BatchedChunkSelector.build([self.sites[k].selector for k in order]),
+                    jnp.asarray([int(self.sites[k].budget()) for k in order], jnp.int32))
         # padded kernel chunk-table length: worst case every block its own
         # chunk (masks_to_block_tables pads every site's table to this)
         self.kernel_k = -(-self.batched.n_max // KERNEL_BLOCK_ROWS)
@@ -503,7 +542,7 @@ class SparseExecution:
         # q/k/v and attn_out's wo via chunk_gather_matmul_dma, the MLP
         # matrices via the fused chunk_gather_mlp_dma (or matmul_dma for the
         # non-gated gelu family) — so all site geometries are constrained.
-        kernel_sites = ("hidden_attn", "attn_out", "hidden_mlp", "ffn")
+        kernel_sites = ("hidden_attn", "attn_out", "hidden_mlp", "ffn", "expert_ffn")
         for kind, n, cols in decode_site_shapes(cfg):
             if kind not in kernel_sites:
                 continue
@@ -635,15 +674,17 @@ class SparseExecution:
         """
         if not plan:
             return plan, jnp.float32(0.0)
-        # lanes of the batched problem are indexed by site_order position —
-        # a partial plan would silently misalign budgets/schedules/tables,
-        # so require exactly the full site set (init_plan always builds it)
-        if set(plan) != set(self.site_order):
+        # lanes of the batched problem are indexed by position in the site
+        # set — a partial plan would silently misalign budgets/schedules/
+        # tables, so require exactly one of the sets a layer refreshes
+        order = tuple(k for k in self.site_order
+                      if k in plan and k not in IN_STEP_SITES)
+        if set(plan) - set(IN_STEP_SITES) != set(order) or order not in self._groups:
             raise ValueError(
-                f"refresh_layer needs a plan entry per site {self.site_order}, "
-                f"got {tuple(plan)}"
+                f"refresh_layer needs a plan entry per site of one of "
+                f"{tuple(self._groups)}, got {tuple(plan)}"
             )
-        order = self.site_order
+        batched, group_budgets = self._groups[order]
         cache = self.cache_enabled
         integ = self.integrity_enabled
         if integ:
@@ -663,7 +704,7 @@ class SparseExecution:
                     )
 
         def _refresh(_):
-            vs = jnp.zeros((self.batched.n_sites, self.batched.n_max), jnp.float32)
+            vs = jnp.zeros((batched.n_sites, batched.n_max), jnp.float32)
             residents = []
             for i, kind in enumerate(order):
                 site = self.sites[kind]
@@ -681,9 +722,7 @@ class SparseExecution:
                         residency_from_score(plan[kind]["score"], self._cap(kind))
                     )
             if cache:
-                res_pad = jnp.zeros(
-                    (self.batched.n_sites, self.batched.n_max), bool
-                )
+                res_pad = jnp.zeros((batched.n_sites, batched.n_max), bool)
                 for i, kind in enumerate(order):
                     res_pad = res_pad.at[i, : self.sites[kind].n].set(residents[i])
             else:
@@ -695,22 +734,22 @@ class SparseExecution:
             # budgets; the clip keeps at least one row selected per site.
             bscale = plan[order[0]].get("bscale")
             if bscale is None:
-                budgets = self._budgets
+                budgets = group_budgets
             else:
                 budgets = jnp.clip(
                     jnp.floor(
-                        self._budgets.astype(jnp.float32) * bscale
+                        group_budgets.astype(jnp.float32) * bscale
                     ).astype(jnp.int32),
-                    jnp.minimum(self._budgets, 1),
-                    self._budgets,
+                    jnp.minimum(group_budgets, 1),
+                    group_budgets,
                 )
             if self.method == "topk":
                 # LLM-in-a-flash-style baseline: selection ignores residency
                 # (pure importance rank); only the I/O charge sees the cache.
                 masks = jax.vmap(topk_mask)(vs, budgets)
-                masks = masks & self.batched.row_valid
+                masks = masks & batched.row_valid
             else:
-                masks, _ = self.batched.select(vs, budgets, res_pad)
+                masks, _ = batched.select(vs, budgets, res_pad)
 
             # -- chunk integrity (PR 9): draw → verify → recovery ladder ----
             # Runs between selection and the chunk-table build so rung 2/3
@@ -841,6 +880,10 @@ class SparseExecution:
                     tbl_masks = tbl_masks.at[i, :site_n].set(
                         tbl_masks[i, :site_n] | pinned
                     )
+            if batched.n_max < self.batched.n_max:
+                # every site's table has the plan's one length
+                tbl_masks = jnp.pad(
+                    tbl_masks, ((0, 0), (0, self.batched.n_max - batched.n_max)))
             kstarts, ksizes = masks_to_block_tables(
                 tbl_masks, KERNEL_BLOCK_ROWS, KERNEL_MAX_CHUNK_ROWS
             )
@@ -1019,14 +1062,84 @@ class SparseExecution:
         for kind in ("hidden_mlp", "ffn"):
             if kind not in plan:
                 raise KeyError(
-                    f"plan has no {kind!r} site (MoE FFNs have no dense MLP "
-                    "sites — the fused MLP kernel does not apply)"
+                    f"plan has no {kind!r} site: this layer has no dense MLP "
+                    "(routed experts run through run_experts)"
                 )
         hs, hz = self.kernel_tables(plan, "hidden_mlp")
         fs, fz = self.kernel_tables(plan, "ffn")
         if layer is not None:
             hs, hz, fs, fz = hs[layer], hz[layer], fs[layer], fz[layer]
         return jnp.stack([hs, fs]), jnp.stack([hz, fz])
+
+    # -- routed experts: selected at two levels inside the step ---------------
+    def run_experts(self, plan, h, gates, stacks, layer):
+        """One expert layer of the planned decode: the held experts some
+        token of the step was routed to run, the others cost nothing; the
+        importance of their rows then keeps the top (1 − sparsity) share of
+        them for the down projection.
+
+        ``h`` (B, d) the layer's expert input, ``gates`` (B, E) each token's
+        weight on each held expert (0 if not chosen), ``stacks`` the whole
+        stacked expert weights (``we_gate``/``we_up`` (L, E, d, F),
+        ``we_down`` (L, E, F, d)), ``layer`` the index into them. The up
+        phase reads each routed expert's gate and up whole: a row's
+        importance, mean over the batch of |silu(gate)·up·weight|, needs
+        them; a row of an unrouted expert has importance exactly 0. The
+        budget keeps ``budget · routed // E`` rows among the routed experts'
+        (top-k, ties to the lower row), and the down phase gathers those.
+        The entry's mask also marks every row of an unrouted expert: none
+        is read (its weight is 0), and none is dropped.
+
+        Returns (y (B, d) f32, the plan with this layer's expert entry:
+        the mask, the tables of the rows gathered, and the counters)."""
+        from ..core.importance import importance
+
+        kind = "expert_ffn"
+        site = self.sites[kind]
+        b = h.shape[0]
+        n_held = gates.shape[1]
+        f = site.n // n_held
+        routed = jnp.any(gates > 0.0, axis=0)
+        n_routed = jnp.sum(routed, dtype=jnp.int32)
+        ids = jnp.argsort(~routed, stable=True).astype(jnp.int32)
+        act = self.backend.expert_up(stacks["we_gate"], stacks["we_up"], layer,
+                                     ids, n_routed, h)
+        mid = (act.reshape(b, n_held, f) * gates[:, :, None]).reshape(b, site.n)
+        v = importance(self.mesh.replicate(mid))
+        rows = jnp.repeat(routed, f)
+        k = site.budget() * n_routed // n_held
+        if self.method == "topk":
+            m = topk_mask(jnp.where(rows, v, -1.0), k)
+        else:
+            m, _, _ = site.selector.select(v, k)
+            m = m & rows
+        starts, sizes = masks_to_block_tables(
+            m[None], KERNEL_BLOCK_ROWS, KERNEL_MAX_CHUNK_ROWS)
+        starts, sizes = starts[0], sizes[0]
+        y = self.backend.expert_down(stacks["we_down"], layer, mid, m, starts, sizes)
+        # the site's mask marks an unrouted expert's rows kept: their input
+        # is 0 here, so nothing is read or computed for them, and a replay
+        # under this mask whose router chooses the expert computes it whole
+        # instead of dropping it
+        keep = m | ~rows
+        n_sel = jnp.sum(m).astype(jnp.float32)
+        gate_up, down = site.tables[0], site.tables[2]
+        lat = 2.0 * gate_up.mask_latency(rows) + down.mask_latency(m)
+        entry = dict(plan[kind])
+        entry.update(
+            mask=keep.astype(jnp.float32), kstarts=starts, ksizes=sizes,
+            miss=entry["miss"] + n_sel,
+            bytes=entry["bytes"] + (n_sel + 2.0 * f * n_routed) * jnp.float32(
+                gate_up.row_bytes),
+            experts=entry["experts"] + n_routed.astype(jnp.float32),
+            pairs=entry["pairs"] + jnp.sum(gates > 0.0).astype(jnp.float32),
+            steps=entry["steps"] + 1.0)
+        if KERNEL_COUNTER_KEYS[0] in entry:
+            counts = superblock_counts(starts, sizes, site.n,
+                                       KERNEL_BLOCK_ROWS).astype(jnp.float32)
+            for j, key in enumerate(KERNEL_COUNTER_KEYS):
+                entry[key] = entry[key] + counts[j]
+        return y, {**plan, kind: entry}, lat
 
     # -- residency-tier capacity ---------------------------------------------
     @property
@@ -1041,8 +1154,18 @@ class SparseExecution:
         overhead amortized over KERNEL_BLOCK_ROWS rows)."""
         return float(sum(t.row_bytes for t in self.sites[kind].tables))
 
+    def _depth(self, kind: str, n_layers: int) -> int:
+        """How many of ``n_layers`` layers a site spans: all of them, except
+        in the latent family (``site_span``)."""
+        return len(self.site_span[kind]) if self.cfg.is_latent else n_layers
+
     def sparsifiable_bytes(self, n_layers: int) -> float:
         """Total offloaded-weight footprint governed by sparsification."""
+        if self.cfg.is_latent:
+            return sum(
+                self._depth(kind, n_layers) * site.n * self.site_row_bytes(kind)
+                for kind, site in self.sites.items()
+            )
         return n_layers * sum(
             site.n * self.site_row_bytes(kind) for kind, site in self.sites.items()
         )
@@ -1101,7 +1224,23 @@ class SparseExecution:
         if self.cache_enabled:
             self._resolve_cache(n_layers)
         plan: Dict[str, Any] = {}
+        full = n_layers
         for kind, site in self.sites.items():
+            n_layers = self._depth(kind, full)
+            if kind in IN_STEP_SITES:
+                entry = {
+                    "mask": jnp.zeros((n_layers, site.n), jnp.float32),
+                    "hit": jnp.zeros((n_layers,), jnp.float32),
+                    "miss": jnp.zeros((n_layers,), jnp.float32),
+                    "bytes": jnp.zeros((n_layers,), jnp.float32),
+                    "kstarts": jnp.zeros((n_layers, self.kernel_k), jnp.int32),
+                    "ksizes": jnp.zeros((n_layers, self.kernel_k), jnp.int32),
+                }
+                for key in MOE_COUNTER_KEYS + (
+                        KERNEL_COUNTER_KEYS if self.backend.is_kernel else ()):
+                    entry[key] = jnp.zeros((n_layers,), jnp.float32)
+                plan[kind] = entry
+                continue
             entry = {
                 "mask": jnp.zeros((n_layers, site.n), jnp.float32),
                 "pending": jnp.ones((n_layers, site.n), jnp.float32),

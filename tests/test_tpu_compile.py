@@ -6,8 +6,9 @@ one-word DMAs below an HBM array's tiling, more VMEM than a kernel may use.
 These tests compile both decode-path kernels at the served models' real
 widths (TinyLlama-1.1B, InternVL2-76B, whose gate/up split into column
 tiles, and the benchmark cells' Granite-3.0-2B and StarCoder2-7B at their
-batch of 16), bf16 and int8 with scales, plus the checksum lane — a chip is
-described, not attached, so nothing runs.
+batch of 16), bf16 and int8 with scales, plus the checksum lane, and
+Kimi-VL-A3B's expert gather over its whole stacked experts and its latent
+projection — a chip is described, not attached, so nothing runs.
 
 The topology is described inside module fixtures, never at import: only
 one process may hold the TPU library, and pytest-xdist workers all import
@@ -20,7 +21,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import chunk_gather_matmul_dma, chunk_gather_mlp_dma
+from repro.kernels import (
+    chunk_gather_expert_down_dma,
+    chunk_gather_expert_up_dma,
+    chunk_gather_matmul_dma,
+    chunk_gather_mlp_dma,
+)
 
 # (name, d_model, d_ff, batch) at published widths: the serve CLI's
 # default 2 slots, the benchmark cells' 16
@@ -118,3 +124,38 @@ def test_mlp_dma_compiles_for_v5e(one_chip, case):
     )
     fn = jax.jit(lambda *a: chunk_gather_mlp_dma(*a, return_h=True))
     _assert_kernel(fn.lower(*args).compile())
+
+
+# Kimi-VL-A3B at published widths: 26 expert layers of 8 held experts,
+# 2048 wide, 1408 per expert, the benchmark cell's batch of 16
+KIMI_EXPERTS = (26, 8, 2048, 1408, 16)
+
+
+@pytest.mark.parametrize("phase", ["up", "down"])
+def test_expert_gather_compiles_for_v5e_over_the_whole_stack(one_chip, phase):
+    n_layers, e, d, f, batch = KIMI_EXPERTS
+    i32 = jnp.int32
+    if phase == "up":
+        args = (_spec(one_chip, (n_layers, e, d, f), jnp.bfloat16),
+                _spec(one_chip, (n_layers, e, d, f), jnp.bfloat16),
+                _spec(one_chip, (), i32), _spec(one_chip, (e,), i32),
+                _spec(one_chip, (), i32), _spec(one_chip, (batch, d), jnp.bfloat16))
+        fn = jax.jit(chunk_gather_expert_up_dma)
+    else:
+        k = e * f // 8
+        args = (_spec(one_chip, (n_layers, e, f, d), jnp.bfloat16),
+                _spec(one_chip, (), i32), _spec(one_chip, (batch, e * f), jnp.float32),
+                _spec(one_chip, (k,), i32), _spec(one_chip, (k,), i32))
+        fn = jax.jit(chunk_gather_expert_down_dma)
+    _assert_kernel(fn.lower(*args).compile())
+
+
+@pytest.mark.parametrize("cols", [3072, 640], ids=["wq", "wkv_a_lane_padded"])
+def test_latent_projections_compile_for_v5e(one_chip, cols):
+    """``wq`` and ``wkv_a`` (576 wide, padded to 640 on the lane grid) off
+    the hidden_attn table; the plan's tables are 11264 / 8 long."""
+    k = 8 * 1408 // 8
+    args = (_spec(one_chip, (2048, cols), jnp.bfloat16),
+            _spec(one_chip, (16, 2048), jnp.float32),
+            _spec(one_chip, (k,), jnp.int32), _spec(one_chip, (k,), jnp.int32))
+    _assert_kernel(jax.jit(chunk_gather_matmul_dma).lower(*args).compile())

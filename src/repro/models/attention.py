@@ -404,15 +404,16 @@ def decode_attention(
     if rope_theta is not None:
         pos = jnp.broadcast_to(jnp.reshape(length - 1, (-1, 1)), (b, 1))
         q = apply_rope(q, pos, rope_theta)
-    q = shard_act(q, ("batch", None, "heads", "head_dim"))
-
+    # grouped-query form: each group of n_rep query heads contracts against
+    # its own KV head as the cache stores it, so K and V are read once, in
+    # their own dtype; the products and sums stay float32
     n_rep = n_heads // n_kv_heads
-    k = repeat_kv(layer_k, n_rep)  # (b, P, h, hd)
-    v = repeat_kv(layer_v, n_rep)
+    q = q.reshape(b, n_kv_heads, n_rep, head_dim).astype(jnp.float32)
+    q = shard_act(q, ("batch", "cache_kv_heads", None, "head_dim"))
+    f32_dot = dict(preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
     scale = head_dim**-0.5
-    scores = jnp.einsum(
-        "bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
-    ) * scale
+    scores = jnp.einsum("bgrd,bkgd->bgrk", q, layer_k, **f32_dot) * scale
     # valid slots: < length (linear) — rotation makes all slots valid once full
     slot_idx = jnp.arange(phys)[None, :]  # (1, P)
     len_col = jnp.reshape(length, (-1, 1))  # (1, 1) or (b, 1)
@@ -422,7 +423,7 @@ def decode_attention(
         valid = slot_idx < jnp.minimum(len_col, phys)
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32)).astype(x.dtype)
+    out = jnp.einsum("bgrk,bkgd->bgrd", probs, layer_v, **f32_dot).astype(x.dtype)
     out = out.reshape(b, 1, n_heads * head_dim)
     if not project_out:
         return out

@@ -1,12 +1,16 @@
-"""Attention mechanics: blockwise==direct, GQA, sliding window, append, RoPE."""
+"""Attention mechanics: blockwise==direct, GQA, sliding window, append, RoPE,
+and grouped-query decode against the repeat-then-float32 form."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.models.attention import (
+    NEG_INF,
     _blockwise_attention,
     _direct_attention,
     append_attention,
+    decode_attention,
     multi_head_attention,
     repeat_kv,
 )
@@ -104,3 +108,76 @@ def test_append_attention_matches_fused_prefill(rng):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(full[:, s0:]), atol=1e-4, rtol=1e-4
     )
+
+
+def _repeat_then_f32_decode(x, params, layer_k, layer_v, length, n_heads,
+                            n_kv_heads, head_dim, rope_theta, window,
+                            project_out):
+    """Decode attention as it was written before the grouped form: every KV
+    head repeated to the query heads and the whole cache upcast to float32."""
+    b = x.shape[0]
+    phys = layer_k.shape[1]
+    q = (x @ params["wq"]).reshape(b, 1, n_heads, head_dim)
+    pos = jnp.broadcast_to(jnp.reshape(length - 1, (-1, 1)), (b, 1))
+    q = apply_rope(q, pos, rope_theta)
+    n_rep = n_heads // n_kv_heads
+    k = repeat_kv(layer_k, n_rep)
+    v = repeat_kv(layer_v, n_rep)
+    scores = jnp.einsum(
+        "bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
+    ) * head_dim**-0.5
+    slot_idx = jnp.arange(phys)[None, :]
+    len_col = jnp.reshape(length, (-1, 1))
+    valid = slot_idx < (jnp.minimum(len_col, phys) if window else len_col)
+    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
+    out = out.astype(x.dtype).reshape(b, 1, n_heads * head_dim)
+    return out @ params["wo"] if project_out else out
+
+
+# (n_rep, kv_replicate): query heads per stored KV head, and the cache's
+# replication of the model's KV heads (the sharded cache's layout)
+_GROUPINGS = [(1, 1), (4, 1), (9, 1), (4, 2)]
+
+
+@pytest.mark.parametrize("project_out", [True, False], ids=["wo", "raw"])
+@pytest.mark.parametrize("lengths", ["scalar", "per_slot", "window"])
+@pytest.mark.parametrize("n_rep,kv_replicate", _GROUPINGS,
+                         ids=[f"rep{r}-kvx{k}" for r, k in _GROUPINGS])
+def test_grouped_decode_attention_equals_repeat_then_f32(
+        rng, n_rep, kv_replicate, lengths, project_out):
+    """Each query-head group contracted against its own stored KV head gives
+    the repeated float32 form: to float32 roundoff before the output cast,
+    and the same bf16 outputs in almost every element after it."""
+    b, phys, n_kv, hd = 3, 40, 2, 16
+    n_heads = n_kv * n_rep
+    d = n_heads * hd
+    cache_kv = n_kv * kv_replicate
+    params = {
+        "wq": jnp.asarray(rng.normal(0, d**-0.5, (d, d)), jnp.float32),
+        "wo": jnp.asarray(rng.normal(0, d**-0.5, (d, d)), jnp.float32),
+    }
+    x = jnp.asarray(rng.normal(0, 1, (b, 1, d)), jnp.float32)
+    k, v = (repeat_kv(jnp.asarray(rng.normal(0, 1, (b, phys, n_kv, hd)),
+                                  jnp.bfloat16), kv_replicate)
+            for _ in range(2))
+    window = phys if lengths == "window" else None
+    length = {"scalar": jnp.int32(29),
+              "per_slot": jnp.asarray([1, 17, phys], jnp.int32),
+              "window": jnp.asarray([5, phys + 7, 3 * phys], jnp.int32)}[lengths]
+    args = (length, n_heads, cache_kv, hd, 10000.0, window)
+
+    def both(x, params):
+        new = decode_attention(x, params, k, v, *args, project_out=project_out)
+        old = _repeat_then_f32_decode(x, params, k, v, *args, project_out)
+        return np.asarray(new.astype(jnp.float32)), np.asarray(old.astype(jnp.float32))
+
+    new, old = both(x, params)  # float32 activations: no output rounding
+    np.testing.assert_allclose(new, old, rtol=2e-6, atol=2e-6)
+
+    as_bf16 = {n: w.astype(jnp.bfloat16) for n, w in params.items()}
+    new, old = both(x.astype(jnp.bfloat16), as_bf16)
+    equal = np.mean(new == old)
+    assert equal >= 0.99, f"only {equal:.4f} of the bf16 outputs agree"
+    np.testing.assert_allclose(new, old, rtol=2**-7, atol=1e-6)

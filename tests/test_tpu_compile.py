@@ -8,13 +8,17 @@ widths (TinyLlama-1.1B, InternVL2-76B, whose gate/up split into column
 tiles, and the benchmark cells' Granite-3.0-2B and StarCoder2-7B at their
 batch of 16), bf16 and int8 with scales, plus the checksum lane, and
 Kimi-VL-A3B's expert gather over its whole stacked experts and its latent
-projection — a chip is described, not attached, so nothing runs.
+projection — a chip is described, not attached, so nothing runs. Decode
+attention is compiled at the cells' cache shapes too, to hold it to the
+grouped-query form: no float32 copy of the cache repeated to every head.
 
 The topology is described inside module fixtures, never at import: only
 one process may hold the TPU library, and pytest-xdist workers all import
 this file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +31,7 @@ from repro.kernels import (
     chunk_gather_matmul_dma,
     chunk_gather_mlp_dma,
 )
+from repro.models.attention import decode_attention
 
 # (name, d_model, d_ff, batch) at published widths: the serve CLI's
 # default 2 slots, the benchmark cells' 16
@@ -159,3 +164,28 @@ def test_latent_projections_compile_for_v5e(one_chip, cols):
             _spec(one_chip, (16, 2048), jnp.float32),
             _spec(one_chip, (k,), jnp.int32), _spec(one_chip, (k,), jnp.int32))
     _assert_kernel(jax.jit(chunk_gather_matmul_dma).lower(*args).compile())
+
+
+# (batch, cache slots, query heads, KV heads, head_dim): the chat cell's
+# Granite-3.0-2B and the topk cell's StarCoder2-7B
+DECODE_ATTENTION = {"granite-chat": (16, 1216, 32, 8, 64),
+                    "starcoder2-topk": (16, 352, 36, 4, 128)}
+
+
+@pytest.mark.parametrize("shape", DECODE_ATTENTION.values(), ids=DECODE_ATTENTION)
+def test_decode_attention_reads_the_cache_in_place(one_chip, shape):
+    """Each query-head group contracts against its stored KV head: the
+    optimised program holds no float32 array as large as the cache repeated
+    to every query head, inside a fusion or out of one."""
+    b, phys, h, kv, hd = shape
+    d = h * hd
+    bf16 = jnp.bfloat16
+    params = {"wq": _spec(one_chip, (d, d), bf16), "wo": _spec(one_chip, (d, d), bf16)}
+    cache = _spec(one_chip, (b, phys, kv, hd), bf16)
+    fn = jax.jit(lambda x, p, k, v, n: decode_attention(x, p, k, v, n, h, kv, hd,
+                                                        10000.0, None))
+    text = fn.lower(_spec(one_chip, (b, 1, d), bf16), params, cache, cache,
+                    _spec(one_chip, (b,), jnp.int32)).compile().as_text()
+    sizes = [math.prod(int(n) for n in dims.split(",") if n)
+             for dims in re.findall(r"\bf32\[([\d,]*)\]", text)]
+    assert sizes and max(sizes) < b * phys * h * hd, max(sizes)
